@@ -20,8 +20,12 @@ bytes, but a full queue DROPS the block instead of blocking the upload
 worker — the index is advisory and `gc --dedup` backfills missing rows
 (cmd/gc.py), so a slow hash backend (e.g. tpu over a thin host link) must
 never throttle foreground write throughput. Drops are counted in
-stats()["dropped"] and exported as juicefs_index_dropped_blocks. This is
-the same role split as the reference's fire-and-forget upload hook
+stats()["dropped"] and exported as juicefs_index_dropped_blocks; batches
+that FAILED (hash or meta write raised) are counted in stats()["errors"]
+and exported as juicefs_index_errors; juicefs_index_blocks counts what
+was persisted, so the three account for every block submitted. The gauges
+sum over the live indexers of the process. This is the same role split as
+the reference's fire-and-forget upload hook
 (pkg/chunk/cached_store.go:371-413): the data path never waits for an
 auxiliary consumer.
 """
@@ -65,12 +69,27 @@ global_registry().gauge(
     "Blocks queued for content-index hashing",
 ).set_function(_queued_blocks)
 
+
+def _sum_live(attr: str) -> int:
+    return sum(getattr(ix, attr) for ix in list(_LIVE_INDEXERS))
+
+
+global_registry().gauge(
+    "juicefs_index_blocks",
+    "Blocks fingerprinted AND persisted to the meta content index",
+).set_function(lambda: _sum_live("blocks"))
+global_registry().gauge(
+    "juicefs_index_dropped_blocks",
+    "Blocks skipped by the content indexer under overload "
+    "(advisory index; gc --dedup backfills)",
+).set_function(lambda: _sum_live("dropped"))
+global_registry().gauge(
+    "juicefs_index_errors",
+    "Blocks whose index batch failed (hash or meta write raised); the "
+    "write path carries on and gc --dedup backfills",
+).set_function(lambda: _sum_live("errors"))
+
 _STOP = object()
-
-
-def pipeline_backend(hash_backend: str) -> str:
-    """Map a Format.hash_backend value to a HashPipeline backend."""
-    return {"tpu": "xla", "": "cpu"}.get(hash_backend, hash_backend)
 
 
 class BlockIndexer:
@@ -90,7 +109,9 @@ class BlockIndexer:
         from ..tpu.pipeline import HashPipeline, PipelineConfig
 
         self.meta = meta
-        self.backend = backend
+        # `backend` is the volume's or the flag's name; the pipeline
+        # resolves it (tpu/device.py) and raises when the device it names
+        # is not there — an indexer never hashes somewhere else instead
         self._pipe = HashPipeline(
             PipelineConfig(
                 backend=backend,
@@ -98,6 +119,7 @@ class BlockIndexer:
                 pad_lanes=max(1, block_size // 65536),
             )
         )
+        self.backend = self._pipe.config.backend
         self._batch_blocks = batch_blocks
         self._q: queue.Queue = queue.Queue(maxsize=queue_blocks)
         self._cond = threading.Condition()

@@ -333,7 +333,6 @@ class IngestPipeline:
 
         self.store = store
         self.refs = refs
-        self.backend = backend
         # adaptive elision bypass (ISSUE 8): skip hash+lookup entirely
         # while the sampled dup density stays below the low-water mark
         self.governor = governor if governor is not None else (
@@ -352,6 +351,8 @@ class IngestPipeline:
             queue_blocks=queue_blocks,
             flush_timeout=flush_timeout,
         )
+        # the pipeline resolved the requested name (tpu/device.py)
+        self.backend = self._batcher.pipe.config.backend
         self._lock = threading.Lock()
         self._outstanding: set[Future] = set()
         self._closed = False
@@ -542,12 +543,11 @@ class IngestPipeline:
             # separate jitted fns would transfer the batch twice.
             from ..tpu.jth256 import pack_blocks
 
-            packed = pack_blocks(raws, pad_lanes=pipe.config.pad_lanes)
-            try:
-                packed = pipe.shard_packed(packed)
-            except Exception as e:
-                # host arrays still work, just without the shared H2D
-                logger.debug("sharded placement degraded: %s", e)
+            # A placement failure raises like any other hash failure of
+            # this batch (its blocks upload un-deduplicated): it is never
+            # absorbed into a second, unsharded transfer.
+            packed = pipe.shard_packed(
+                pack_blocks(raws, pad_lanes=pipe.config.pad_lanes))
         if raws:
             with _TR.span("chunk", "ingest", stage="hash",
                           hist=_H_HASH) as sp:

@@ -113,7 +113,11 @@ def build_store(fmt: Format, args=None, meta=None,
     the mount flag. Read-only admin commands (fsck/gc/warmup) pass
     with_indexer=False: they need alias resolution but never upload, so
     spinning up the fingerprint worker (and possibly an accelerator
-    backend) for them would be pure startup cost."""
+    backend) for them would be pure startup cost.
+
+    The volume's hash_backend name goes to the pipelines unmapped; they
+    resolve it through tpu/device.py, so a `tpu` volume opened for writing
+    on a host without a TPU fails here instead of hashing elsewhere."""
     conf = chunk_conf(fmt, args)
     store = CachedStore(storage_for(fmt), conf)
     # bulk commands (gc/warmup --threads) run at BACKGROUND class; widen
@@ -126,7 +130,6 @@ def build_store(fmt: Format, args=None, meta=None,
         store.scheduler.widen("download", threads)
         store.scheduler.widen("bulk", threads)
     if meta is not None:
-        from ..chunk.indexer import pipeline_backend
         from ..chunk.ingest import ContentRefs, IngestPipeline
 
         store.content_refs = ContentRefs(meta)
@@ -135,7 +138,7 @@ def build_store(fmt: Format, args=None, meta=None,
 
             store.indexer = BlockIndexer(
                 meta=meta,
-                backend=pipeline_backend(fmt.hash_backend),
+                backend=fmt.hash_backend,
                 block_size=conf.block_size,
             )
             conf.fingerprint = store.indexer.submit
@@ -146,7 +149,7 @@ def build_store(fmt: Format, args=None, meta=None,
             store.ingest = IngestPipeline(
                 store,
                 store.content_refs,
-                backend=pipeline_backend(fmt.hash_backend),
+                backend=fmt.hash_backend,
                 flush_timeout=max(0.0, float(flush_ms)) / 1e3,
                 bypass=conf.dedup_bypass,
             )

@@ -12,6 +12,7 @@ from __future__ import annotations
 import json
 
 from ..chunk.cached_store import block_key
+from ..tpu.device import HASH_BACKENDS
 from ..utils import get_logger
 
 logger = get_logger("cmd.fsck")
@@ -24,7 +25,10 @@ def add_parser(sub):
                    help="GET + decompress every block")
     p.add_argument("--hash-index", default="",
                    help="also hash every block; write content index JSON here")
-    p.add_argument("--hash-backend", default=None, help="cpu|xla|pallas")
+    p.add_argument("--hash-backend", default=None,
+                   choices=HASH_BACKENDS,
+                   help="hash backend (default: the volume's; `tpu` fails "
+                        "unless JAX finds a TPU)")
     p.set_defaults(func=run)
 
 
@@ -78,11 +82,10 @@ def run(args) -> int:
             broken.append(str(ino))
 
     if args.verify_data or args.hash_index:
-        from ..chunk.indexer import pipeline_backend
         from ..tpu.jth256 import digest_hex
         from ..tpu.pipeline import HashPipeline, PipelineConfig
 
-        backend = args.hash_backend or pipeline_backend(fmt.hash_backend)
+        backend = args.hash_backend or fmt.hash_backend
         pipe = HashPipeline(
             PipelineConfig(backend=backend, pad_lanes=max(1, bs // 65536))
         )
@@ -117,9 +120,12 @@ def run(args) -> int:
             with open(args.hash_index, "w") as f:
                 json.dump(index, f, indent=1)
         print(
-            f"verified {len(index)} blocks ({backend}); "
+            f"verified {len(index)} blocks ({pipe.config.backend}); "
             f"{len(recorded)} indexed, {bitrot} digest mismatches"
         )
+        # where the digests came from (tpu/device.py) — the line a reader
+        # needs to tell a chip run from a host run
+        print("device: " + json.dumps(pipe.device_report()))
 
     print(f"checked {checked} files / {blocks} blocks; {len(broken)} broken")
     return 1 if broken else 0

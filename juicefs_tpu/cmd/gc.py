@@ -15,6 +15,7 @@ import json
 
 from ..chunk.cached_store import block_key, parse_block_key
 from ..qos import IOClass
+from ..tpu.device import HASH_BACKENDS
 from ..utils import get_logger
 
 logger = get_logger("cmd.gc")
@@ -27,7 +28,9 @@ def add_parser(sub):
     p.add_argument("--compact", action="store_true", help="compact fragmented chunks")
     p.add_argument("--dedup", action="store_true", help="content-addressed dedup scan")
     p.add_argument("--hash-backend", default=None,
-                   help="cpu|xla|pallas (default: volume format hash_backend)")
+                   choices=HASH_BACKENDS,
+                   help="hash backend for --dedup (default: the volume's; "
+                        "`tpu` fails unless JAX finds a TPU)")
     p.add_argument("--threads", type=int, default=10)
     p.add_argument("--age", type=float, default=3600.0,
                    help="only treat objects older than this (seconds) as leaked")
@@ -39,6 +42,14 @@ def run(args) -> int:
     from . import build_store, open_meta
 
     m, fmt = open_meta(args.meta_url)
+    # the flag's name and the volume's take the same road: tpu/device.py
+    # resolves either, and `tpu` without a TPU fails here — before the
+    # name diff has listed a single object
+    backend = args.hash_backend or fmt.hash_backend
+    if args.dedup:
+        from ..tpu.device import resolve_backend
+
+        resolve_backend(backend)
     # meta-attached store: dedup-scan reads of PUT-elided blocks resolve
     # through the content-ref plane (ISSUE 5). No indexer: gc backfills
     # digest rows itself through dedup_scan's own pipeline.
@@ -116,9 +127,6 @@ def run(args) -> int:
         print(f"deleted {len(leaked)} leaked objects")
 
     if args.dedup:
-        from ..chunk.indexer import pipeline_backend
-
-        backend = args.hash_backend or pipeline_backend(fmt.hash_backend)
         stats = dedup_scan(m, store, live, backend, args.dedup_index, bs,
                            threads=args.threads)
         # offline complement of the inline ingest stage: repair refcounts
@@ -230,7 +238,8 @@ def dedup_scan(meta, store, live: dict[str, int], backend: str,
         "duplicate_blocks": int(dup_mask.sum()),
         "duplicate_bytes": int(dup_bytes),
         "dedup_groups": len(groups),
-        "backend": backend,
+        # the backend that RAN (requested name is in device.requested)
+        "backend": pipe.config.backend,
         "fetch_window": window,
         # stage breakdown (VERDICT r3 #2: the bottleneck must be explicit).
         # `get` is WALL time the fetch stage had GETs in flight;
@@ -252,10 +261,11 @@ def dedup_scan(meta, store, live: dict[str, int], backend: str,
         # through object/resilient.py): a scan that paid for fault
         # handling must say so next to its throughput numbers
         "resilience": resilience_snapshot(),
-        # sharding-plane geometry the hash batches ran on (ISSUE 20):
-        # device count, mesh axes, and whether the plane degraded to
-        # single-device jit
-        "shard": pipe.shard_snapshot(),
+        # where the digests came from (tpu/device.py): platform,
+        # device_kind, device counts, mesh axes, whether the plane
+        # degraded to single-device jit, Pallas mode, peak device memory,
+        # and the first batch's wall time (compilation: set-up, not rate)
+        "device": pipe.device_report(),
     }
 
 

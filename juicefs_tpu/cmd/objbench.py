@@ -125,10 +125,10 @@ def run(args) -> int:
         compressor = new_compressor(args.compress)
     indexer = None
     if args.hash_backend:
-        from ..chunk.indexer import BlockIndexer, pipeline_backend
+        from ..chunk.indexer import BlockIndexer
 
         indexer = BlockIndexer(
-            meta=None, backend=pipeline_backend(args.hash_backend), block_size=bs
+            meta=None, backend=args.hash_backend, block_size=bs
         )
 
     def put_one(item):

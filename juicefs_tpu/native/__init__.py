@@ -3,8 +3,12 @@
 The reference's hot data plane is native (cgo zstd/lz4, hardware CRC32C);
 this package is the rebuild's equivalent. The shared library builds on
 demand from jfscore.cpp with the system toolchain and is cached next to
-the source; every entry point has a pure-Python fallback so the framework
-degrades gracefully on hosts without a compiler.
+the source under a name keyed on the source's SHA-256, so a library that
+was not built from THIS jfscore.cpp (a stale copy, a checkout with newer
+mtimes) is never loaded. Every entry point has a pure-Python fallback so
+the framework still runs on hosts without a compiler — orders of
+magnitude slower, which is why `available()` is what chip_smoke.py and
+the device report print.
 
 Exports:
     crc32c(data, crc=0)            hardware CRC32C (SSE4.2 when available)
@@ -16,6 +20,8 @@ Exports:
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
 import subprocess
 import threading
@@ -25,17 +31,26 @@ from ..utils import get_logger
 
 logger = get_logger("native")
 
-_SRC = os.path.join(os.path.dirname(__file__), "jfscore.cpp")
-_SO = os.path.join(os.path.dirname(__file__), "libjfscore.so")
+_DIR = os.path.dirname(__file__)
+_SRC = os.path.join(_DIR, "jfscore.cpp")
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
 
 
-def _build() -> bool:
+def _so_path() -> str:
+    """The library path for the jfscore.cpp on disk: keyed on its content,
+    not on mtimes (a copied tree keeps neither order nor meaning of
+    those)."""
+    with open(_SRC, "rb") as f:
+        key = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_DIR, f"libjfscore-{key}.so")
+
+
+def _build(so: str) -> bool:
     # Build to a per-pid temp name and atomically rename: concurrent
     # processes may both compile, but no one ever loads a half-written .so.
-    tmp = f"{_SO}.{os.getpid()}.tmp"
+    tmp = f"{so}.{os.getpid()}.tmp"
     cmd = [
         "g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-pthread",
         _SRC, "-o", tmp,
@@ -49,10 +64,16 @@ def _build() -> bool:
         logger.warning("native build failed: %s", proc.stderr.decode()[:500])
         return False
     try:
-        os.replace(tmp, _SO)
+        os.replace(tmp, so)
     except OSError as e:
         logger.warning("native build install failed: %s", e)
         return False
+    for old in glob.glob(os.path.join(_DIR, "libjfscore*.so")):
+        if old != so:  # libraries of other sources: never loadable again
+            try:
+                os.unlink(old)
+            except OSError:
+                pass
     return True
 
 
@@ -64,14 +85,15 @@ def _load() -> Optional[ctypes.CDLL]:
         if _lib is not None or _tried:
             return _lib
         _tried = True
-        if not os.path.exists(_SO) or (
-            os.path.exists(_SRC)
-            and os.path.getmtime(_SRC) > os.path.getmtime(_SO)
-        ):
-            if not _build():
-                return None
         try:
-            lib = ctypes.CDLL(_SO)
+            so = _so_path()
+        except OSError as e:
+            logger.warning("native source unreadable: %s", e)
+            return None
+        if not os.path.exists(so) and not _build(so):
+            return None
+        try:
+            lib = ctypes.CDLL(so)
             lib.jfs_crc32c.restype = ctypes.c_uint32
             lib.jfs_crc32c.argtypes = [
                 ctypes.c_char_p, ctypes.c_size_t, ctypes.c_uint32,

@@ -15,7 +15,15 @@ Modules:
   sharding  — the multichip plane: (data x lane) mesh over all local
               devices, sharded placement + hash/dedup/estimator programs,
               single-device-jit degrade ladder (ISSUE 20)
+  device    — the one resolver: requested backend -> what runs, the device
+              report every device-path output prints, the compile cache
 """
+
+from .device import configure_compile_cache
+
+# The ONE place the persistent compile cache is set: importing any module
+# of this package runs this before that process's first compilation.
+configure_compile_cache()
 
 from .jth256 import (
     BLOCK_BYTES,

@@ -8,7 +8,10 @@ k+1 on the host overlaps hashing batch k on the TPU; results are only
 blocked on one batch behind.
 
 Backend selection mirrors the reference's Compressor registry pattern
-(pkg/compress/compress.go:31-49): "cpu" (vectorized numpy), "xla", "pallas".
+(pkg/compress/compress.go:31-49): "cpu" (C++/numpy host hash), "xla",
+"pallas", and "tpu" (the xla program, on a TPU or not at all). Names are
+resolved by tpu/device.py; a device backend that cannot initialise raises
+— the host hash is reached by asking for `cpu`, never by falling back.
 """
 
 from __future__ import annotations
@@ -44,6 +47,18 @@ _BATCH_BLOCKS = _reg.histogram(
     "juicefs_tpu_batch_blocks", "Blocks per dispatched hash batch",
     buckets=(1, 2, 4, 8, 16, 32, 64, 128, 256),
 )
+_DEVICE_INFO = _reg.gauge(
+    "juicefs_tpu_device_info",
+    "Device the hash pipeline resolved to (value 1; the labels are the "
+    "device report of tpu/device.py)",
+    ("platform", "device_kind", "devices", "visible_devices", "backend",
+     "pallas_mode", "degraded"),
+)
+_FIRST_BATCH = _reg.gauge(
+    "juicefs_tpu_first_batch_seconds",
+    "Dispatch-to-digests wall time of this process's first device hash "
+    "batch (compilation included; set-up, not rate)",
+)
 _TR = global_tracer()
 _H_DISPATCH = stage_hist("tpu", "hash", "dispatch")
 _H_DRAIN = stage_hist("tpu", "hash", "drain")
@@ -51,7 +66,7 @@ _H_DRAIN = stage_hist("tpu", "hash", "drain")
 
 @dataclass
 class PipelineConfig:
-    backend: str = "xla"  # cpu | xla | pallas
+    backend: str = "xla"  # cpu | xla | pallas | tpu (tpu/device.py)
     batch_blocks: int = 32
     # Pad every batch to this many lanes so one compiled program serves the
     # whole stream (4 MiB default block = 64 lanes).
@@ -68,42 +83,46 @@ class HashPipeline:
     """hash_stream(iter[(key, bytes)]) -> iter[(key, 32-byte digest)]."""
 
     def __init__(self, config: Optional[PipelineConfig] = None):
+        from .device import resolve_backend
+
         self.config = config or PipelineConfig()
+        self.requested = self.config.backend
+        # "tpu" -> xla on a TPU, or DeviceUnavailable; a failed backend
+        # init raises here too (tpu/device.py): never a silent host hash
+        self.config.backend = resolve_backend(self.requested)
         self._fn = None
         self._plane = None
-        if self.config.backend != "cpu":
-            try:
-                import jax
+        # wall time of the first device batch, dispatch to digests: it
+        # carries the compilation, so consumers report it apart from rate
+        self.first_batch_seconds: float | None = None
+        if self.config.backend == "xla":
+            # xla rides the sharding plane (ISSUE 20): mesh over all
+            # local devices, single-device jit on the degrade rung —
+            # byte-identical either way.
+            from .sharding import get_plane
 
-                jax.devices()  # force backend init; may raise
-                if self.config.backend == "xla":
-                    # xla rides the sharding plane (ISSUE 20): mesh over
-                    # all local devices, single-device jit on the degrade
-                    # rung — byte-identical either way.
-                    from .sharding import get_plane
+            self._plane = get_plane()
+            self._fn = self._plane.hash_async
+        elif self.config.backend == "pallas":
+            from .hash_jax import make_hash_fn
 
-                    self._plane = get_plane()
-                    self._fn = self._plane.hash_async
-                else:
-                    from .hash_jax import make_hash_fn
+            self._fn = make_hash_fn("pallas")
+        # initialises the backend for xla/pallas (raising if it cannot)
+        # and says which mode Pallas will run in
+        report = self.device_report()
+        _DEVICE_INFO.labels(*(
+            str(report[k]) for k in _DEVICE_INFO.label_names)).set(1)
 
-                    self._fn = make_hash_fn(self.config.backend)
-            except Exception as e:  # no usable accelerator: digests must
-                # still flow, so degrade to the byte-identical CPU path.
-                from ..utils import get_logger
-
-                get_logger("tpu.pipeline").warning(
-                    "backend %r unavailable (%s); falling back to cpu",
-                    self.config.backend, e,
-                )
-                self.config.backend = "cpu"
-                self._plane = None
+    def _note_first_batch(self, t0: float) -> None:
+        if self.first_batch_seconds is None:
+            self.first_batch_seconds = time.perf_counter() - t0
+            _FIRST_BATCH.set(self.first_batch_seconds)
 
     def hash_stream(
         self, items: Iterable[tuple[str, bytes]]
     ) -> Iterator[tuple[str, bytes]]:
         cfg = self.config
-        pending: list[tuple[list[str], object]] = []
+        pending: list[tuple[list[str], object, float]] = []
         keys: list[str] = []
         blocks: list[bytes] = []
 
@@ -112,6 +131,7 @@ class HashPipeline:
             if not blocks:
                 return
             nbytes = sum(len(b) for b in blocks)
+            t0 = time.perf_counter()
             with _TR.span("tpu", "hash", stage="dispatch",
                           hist=_H_DISPATCH) as sp:
                 if sp.active:
@@ -123,18 +143,19 @@ class HashPipeline:
                     # and no device transfer (h2d counter stays untouched).
                     from .. import native
 
-                    pending.append((keys, native.jth256_batch(blocks)))
+                    pending.append((keys, native.jth256_batch(blocks), t0))
                 else:
                     words, counts, lengths = pack_blocks(blocks, pad_lanes=cfg.pad_lanes)
                     _H2D_BYTES.inc(words.nbytes)
-                    pending.append((keys, self._fn(words, counts, lengths)))
+                    pending.append(
+                        (keys, self._fn(words, counts, lengths), t0))
             _BATCH_BLOCKS.observe(len(blocks))
             _BLOCKS_HASHED.inc(len(blocks))
             _HASH_BYTES.inc(nbytes)
             keys, blocks = [], []
 
         def drain(batch) -> Iterator[tuple[str, bytes]]:
-            bkeys, out = batch
+            bkeys, out, t0 = batch
             if isinstance(out, list):
                 digests = out
             else:
@@ -146,6 +167,7 @@ class HashPipeline:
                         sp.set(batch=len(bkeys),
                                backend=self.config.backend)
                     digests = digests_to_bytes(np.asarray(out))
+                self._note_first_batch(t0)
             return zip(bkeys, digests[: len(bkeys)])
 
         for key, data in items:
@@ -169,7 +191,7 @@ class HashPipeline:
 
     @property
     def device_backend(self) -> bool:
-        """True when digests come off an accelerator (post-degrade)."""
+        """True when digests come off a JAX device program."""
         return self._fn is not None
 
     def shard_packed(self, packed):
@@ -177,27 +199,29 @@ class HashPipeline:
         (ISSUE 8/20): ONE (sharded, on the plane) device transfer feeds
         both the hash and the estimator jits. This is the sharding-plane
         seam chunk/ consumers enter through — no bare device_put above
-        tpu/. cpu backend: no-op (host arrays hash in numpy)."""
+        tpu/. A placement failure raises (the device is unusable; hiding
+        it would double the transfer silently). cpu backend: no-op (host
+        arrays hash on the host)."""
         if self._plane is not None:
             return self._plane.put_packed(*packed)
         if self._fn is not None:  # single-device backend (pallas)
-            try:
-                import jax
+            import jax
 
-                return tuple(jax.device_put(a) for a in packed)
-            except Exception:
-                return packed
+            return tuple(jax.device_put(a) for a in packed)
         return packed
 
-    def shard_snapshot(self) -> dict:
-        """Advisory sharding-plane stats (gc --dedup, bench output)."""
-        if self._plane is not None:
-            return self._plane.snapshot()
+    def device_report(self) -> dict:
+        """Which device this pipeline's digests come from (tpu/device.py):
+        platform, device_kind, device counts, mesh, degraded + reason,
+        resolved backend, Pallas mode, peak device memory so far — plus
+        the first device batch's wall time, which carries the compilation
+        and so is reported apart from any rate."""
+        from .device import device_report
+
+        first = self.first_batch_seconds
         return {
-            "devices": 1 if self._fn is not None else 0,
-            "mesh": None,
-            "degraded": False,
-            "reason": f"{self.config.backend} backend",
+            **device_report(self.config.backend, self.requested),
+            "first_batch_seconds": None if first is None else round(first, 3),
         }
 
     def hash_packed(self, words, counts, lengths,
@@ -216,6 +240,7 @@ class HashPipeline:
             if sp.active:
                 sp.set(batch=n, bytes=nbytes,
                        backend=self.config.backend)
+            t0 = time.perf_counter()
             if self._fn is None:
                 out = hash_packed_np(words, counts, lengths)
             else:
@@ -227,7 +252,10 @@ class HashPipeline:
         with _TR.span("tpu", "hash", stage="drain", hist=_H_DRAIN) as sp:
             if sp.active:
                 sp.set(batch=n, backend=self.config.backend)
-            return digests_to_bytes(np.asarray(out))[:n]
+            digests = digests_to_bytes(np.asarray(out))[:n]
+        if self._fn is not None:
+            self._note_first_batch(t0)
+        return digests
 
 
 _FLUSH = object()  # kick(): hash whatever is buffered NOW (commit barrier)

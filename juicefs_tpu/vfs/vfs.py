@@ -119,13 +119,6 @@ class VFS:
         reg.gauge(
             "juicefs_blockcache_blocks", "Blocks in the local block cache"
         ).set_function(lambda: self.store.cache.stats()[0])
-        reg.gauge(
-            "juicefs_index_dropped_blocks",
-            "Blocks skipped by the content indexer under overload "
-            "(advisory index; gc --dedup backfills)",
-        ).set_function(
-            lambda: self.store.indexer.dropped if self.store.indexer else 0
-        )
         self._instrument()
 
     def _instrument(self) -> None:

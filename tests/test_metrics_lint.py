@@ -181,6 +181,21 @@ def test_compress_registry_pinned():
     assert "rogue" in text                    # stray under prefix
 
 
+def test_index_registry_pinned():
+    """The juicefs_index_* series (ISSUE 21: persisted / dropped / failed
+    account for every submitted block) must all exist; nothing squats
+    under the prefix."""
+    lint = _load_lint()
+    assert lint.lint_index() == []
+    from juicefs_tpu.metric import Registry
+
+    reg = Registry()
+    reg.counter("juicefs_index_rogue", "unreviewed")
+    text = "\n".join(lint.lint_index(registry=reg))
+    assert "juicefs_index_errors" in text  # missing expected
+    assert "rogue" in text                  # stray under prefix
+
+
 def test_compress_seam_lint():
     """Write-path compression in chunk/ must route through the batched
     plane: passes on the real tree, bites on a synthetic chunk module

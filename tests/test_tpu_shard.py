@@ -331,6 +331,10 @@ print("OK devices=%d mesh=%s" % (n, snap["mesh"]))
 
 
 def _run_forced(n: int) -> str:
+    """Run the worker on n forced-host CPU devices. The parent (pytest)
+    has usually initialised a JAX backend already; spawning from it is
+    right only because the child is pinned to `JAX_PLATFORMS=cpu` and so
+    can never need a chip the parent holds."""
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n}"

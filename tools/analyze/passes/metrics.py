@@ -145,6 +145,17 @@ TPU_SHARD_EXPECTED = {
     "juicefs_tpu_shard_h2d_batches",
     "juicefs_tpu_shard_degraded",
 }
+INDEX_PREFIX = "juicefs_index_"
+INDEX_EXPECTED = {
+    # write-path content indexer (chunk/indexer.py): backlog, and the
+    # three series that account for every submitted block — persisted,
+    # dropped under overload, failed. chip_smoke.py (ISSUE 21) asserts
+    # errors == 0 and persisted + dropped == blocks written.
+    "juicefs_index_queue_blocks",
+    "juicefs_index_blocks",
+    "juicefs_index_dropped_blocks",
+    "juicefs_index_errors",
+}
 META_WBATCH_PREFIX = "juicefs_meta_wbatch_"
 META_WBATCH_EXPECTED = {
     # checkpoint write plane (ISSUE 13, meta/wbatch.py): the
@@ -166,6 +177,7 @@ def populate_registry() -> None:
     import juicefs_tpu.chunk.bypass         # noqa: F401  elision-bypass counters
     import juicefs_tpu.chunk.cached_store   # noqa: F401  staging gauges
     import juicefs_tpu.chunk.disk_cache     # noqa: F401  disk tier counters
+    import juicefs_tpu.chunk.indexer        # noqa: F401  content-index gauges
     import juicefs_tpu.chunk.ingest         # noqa: F401  inline-dedup counters
     import juicefs_tpu.chunk.mem_cache      # noqa: F401  cache hit/miss/evict
     import juicefs_tpu.chunk.parallel       # noqa: F401  fetch_inflight gauge
@@ -250,6 +262,7 @@ def run(files: list[SourceFile]) -> list[Finding]:
         + lint_pinned(META_WBATCH_PREFIX, META_WBATCH_EXPECTED,
                       "meta-wbatch")
         + lint_pinned(TPU_SHARD_PREFIX, TPU_SHARD_EXPECTED, "tpu-shard")
+        + lint_pinned(INDEX_PREFIX, INDEX_EXPECTED, "index")
         + lint_pinned(PREFETCH_PREFIX, PREFETCH_EXPECTED, "prefetch")
         + lint_pinned(READAHEAD_PREFIX, READAHEAD_EXPECTED, "readahead")
         + lint_pinned(GATEWAY_PREFIX, GATEWAY_EXPECTED, "gateway")

@@ -34,6 +34,8 @@ COMPRESS_PREFIX = _metrics.COMPRESS_PREFIX
 COMPRESS_EXPECTED = _metrics.COMPRESS_EXPECTED
 GATEWAY_PREFIX = _metrics.GATEWAY_PREFIX
 GATEWAY_EXPECTED = _metrics.GATEWAY_EXPECTED
+INDEX_PREFIX = _metrics.INDEX_PREFIX
+INDEX_EXPECTED = _metrics.INDEX_EXPECTED
 
 _PKG_ROOT = os.path.join(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "juicefs_tpu"
@@ -76,6 +78,11 @@ def lint_gateway(registry=None) -> list[str]:
                                 "gateway", registry)
 
 
+def lint_index(registry=None) -> list[str]:
+    return _metrics.lint_pinned(INDEX_PREFIX, INDEX_EXPECTED,
+                                "index", registry)
+
+
 def lint_compress_seam(root: str | None = None) -> list[str]:
     """No-bare-compress check (ISSUE 8), framework-backed."""
     files = load_files(root or _PKG_ROOT)
@@ -107,7 +114,7 @@ def main() -> int:
                 + lint_ingest_seam() + lint_resilience()
                 + lint_qos() + lint_qos_seam()
                 + lint_compress() + lint_compress_seam()
-                + lint_wbatch() + lint_gateway())
+                + lint_wbatch() + lint_gateway() + lint_index())
     if problems:
         for p in problems:
             print(f"lint_metrics: {p}", file=sys.stderr)
