@@ -21,8 +21,10 @@ file:// volumes under a scratch directory it creates and removes.
 
 It passes only on a TPU: any step that fails, times out or ran on another
 platform makes it exit 1 with the step named on stderr and NO result on
-stdout. The last stdout line of a pass is one JSON object
-{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": N}, ...}.
+stdout. A pass prints two stdout lines: `SUMMARY {...}` with every step's
+seconds, counters, device report and compilations, then last the verdict,
+{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": N}} with
+exactly those keys.
 
 One process for each chip: this parent never imports JAX (asserted before
 exit) and its children run strictly one after another, each reaped before
@@ -783,8 +785,19 @@ def main(argv=None) -> int:
         print(f"chip_smoke: FAIL at step {failure.step}: {failure.message}",
               file=sys.stderr)
         return 1
-    print(json.dumps(summary))
+    print("SUMMARY " + json.dumps(summary))  # everything measured
+    print(json.dumps(verdict(summary)))  # the last line, parsed strictly
     return 0
+
+
+def verdict(summary: dict) -> dict:
+    """The last stdout line of a pass: exactly `ok` and `device`, the
+    device exactly as JAX reported it to the format child. Everything else
+    the run measured is on the SUMMARY line before it."""
+    d = summary["device"]
+    return {"ok": summary["ok"],
+            "device": {"platform": d["platform"], "kind": d["kind"],
+                       "count": int(d["count"])}}
 
 
 if __name__ == "__main__":

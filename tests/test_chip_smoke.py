@@ -94,6 +94,14 @@ def test_compile_counts_collapse_doubled_log_records():
     }
 
 
+def test_verdict_line_holds_exactly_ok_and_device():
+    summary = {"ok": True, "jax": "0.9.0", "seed": 21, "steps": {"cold": {}},
+               "device": {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}}
+    assert chip_smoke.verdict(summary) == {
+        "ok": True,
+        "device": {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}}
+
+
 def test_chip_smoke_fails_on_cpu_and_prints_no_result(tmp_path):
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     p = subprocess.run(
