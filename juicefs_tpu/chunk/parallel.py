@@ -33,6 +33,7 @@ from concurrent.futures import Future
 from typing import Callable, Iterable, Iterator, Optional, TypeVar
 
 from ..metric import global_registry
+from ..metric.trace import global_tracer, stage_hist
 from ..object.interface import NotFoundError
 from ..object.resilient import BreakerOpenError
 from ..utils import get_logger
@@ -48,6 +49,19 @@ _INFLIGHT = global_registry().gauge(
     "juicefs_fetch_inflight",
     "Block fetches currently in flight in ordered parallel-fetch stages",
 )
+# Is the fetch hidden behind the consumer's own work? Each time the
+# consumer takes the oldest block: was it there already (ready="1") or did
+# the consumer have to wait for its GET (ready="0").
+_WAITS = global_registry().counter(
+    "juicefs_fetch_waits",
+    "Consumer waits of ordered parallel-fetch stages, by whether the "
+    "block had already arrived",
+    ("ready",),
+)
+_WAIT_READY = _WAITS.labels("1")
+_WAIT_BLOCKED = _WAITS.labels("0")
+_TR = global_tracer()
+_H_WAIT = stage_hist("chunk", "fetch", "wait")
 
 
 class FetchStats:
@@ -127,13 +141,16 @@ def fetch_ordered(
         raise ValueError(f"on_error: {on_error!r}")
     window = max(1, int(window))
 
-    def timed(item: T) -> R:
+    def timed(item: T, ref) -> R:
         _INFLIGHT.inc()
         start = time.perf_counter()
         if stats is not None:
             stats._begin(start)
         try:
-            out = fn(item)
+            # spans `fn` opens on the pool thread hang off the span the
+            # consumer was in when it submitted the item
+            with _TR.carried(ref):
+                out = fn(item)
         except BaseException:
             if stats is not None:
                 stats._record_error()
@@ -150,8 +167,13 @@ def fetch_ordered(
 
     def drain_one() -> Iterator[tuple[T, R]]:
         item, fut = inflight.popleft()
+        ready = fut.done()
+        (_WAIT_READY if ready else _WAIT_BLOCKED).inc()
         try:
-            yield item, fut.result()
+            with _TR.span("chunk", "fetch", stage="wait", hist=_H_WAIT) as sp:
+                if sp.active:
+                    sp.set(ready=ready)
+                out = fut.result()
         except Exception as e:
             if on_error == "raise" or isinstance(e, BreakerOpenError):
                 raise
@@ -159,10 +181,13 @@ def fetch_ordered(
                 logger.debug("fetch %s: %s", item, e)
             else:
                 logger.warning("fetch %s: %s", item, e)
+            return
+        yield item, out
 
     try:
         for item in it:
-            inflight.append((item, pool.submit(timed, item)))
+            inflight.append(
+                (item, pool.submit(timed, item, _TR.current_ref())))
             if len(inflight) >= window:
                 yield from drain_one()
         while inflight:

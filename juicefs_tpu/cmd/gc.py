@@ -7,18 +7,38 @@ New TPU-first capability (BASELINE.md north star): `--dedup` streams every
 live block through the batched JTH-256 pipeline and reports duplicate
 content groups and reclaimable bytes — content addressing the reference
 does not have (its gc diffs block *names* only, cmd/gc.go:253-296).
+
+One invocation is one trace (metric/trace.py): the root span `cmd.gc`, its
+stages `open`, `list`, `index_load`, `readhash`, `backfill`, `group`,
+`write_index`, `reconcile` below it, and below `readhash` the fetch stage's
+and the hash pipeline's own spans. Every stage feeds
+`juicefs_tpu_stage_seconds{layer="cmd",op="gc"}` whether anyone listens or
+not; `--trace DIR` attaches a reader and writes what it heard.
 """
 
 from __future__ import annotations
 
+import contextlib
 import json
 
 from ..chunk.cached_store import block_key, parse_block_key
+from ..metric.trace import global_tracer, span_summary, stage_hist
 from ..qos import IOClass
 from ..tpu.device import HASH_BACKENDS
 from ..utils import get_logger
 
 logger = get_logger("cmd.gc")
+
+_TR = global_tracer()
+_H_GC = stage_hist("cmd", "gc")
+_H_OPEN = stage_hist("cmd", "gc", "open")
+_H_LIST = stage_hist("cmd", "gc", "list")
+_H_INDEX_LOAD = stage_hist("cmd", "gc", "index_load")
+_H_READHASH = stage_hist("cmd", "gc", "readhash")
+_H_BACKFILL = stage_hist("cmd", "gc", "backfill")
+_H_GROUP = stage_hist("cmd", "gc", "group")
+_H_WRITE_INDEX = stage_hist("cmd", "gc", "write_index")
+_H_RECONCILE = stage_hist("cmd", "gc", "reconcile")
 
 
 def add_parser(sub):
@@ -35,26 +55,101 @@ def add_parser(sub):
     p.add_argument("--age", type=float, default=3600.0,
                    help="only treat objects older than this (seconds) as leaked")
     p.add_argument("--dedup-index", default="", help="write content index JSON here")
+    p.add_argument("--trace", default="", metavar="DIR",
+                   help="record this invocation's spans: a chrome://tracing-"
+                        "loadable juicefs-trace.json in DIR, per-span self "
+                        "times in the --dedup stats line and, with a device "
+                        "hash backend, a JAX profiler trace of the scan in "
+                        "DIR with the same spans on the device's clock")
     p.set_defaults(func=run)
 
 
 def run(args) -> int:
+    trace_dir = getattr(args, "trace", "")
+    with (_ScanTrace(trace_dir) if trace_dir
+          else contextlib.nullcontext()) as trace:
+        with _TR.span("cmd", "gc", hist=_H_GC) as root:
+            stats = _gc(args, trace, root)
+    # after the root has closed: its own row belongs in the table
+    if stats is not None:
+        if trace is not None:
+            stats["spans"] = span_summary(trace.events)
+        print(json.dumps(stats))
+    return 0
+
+
+class _ScanTrace:
+    """`gc --trace DIR`: `profile --trace DIR` for the process that has no
+    mount, hence no `.trace` file anyone could hold open. An in-process
+    reader hears every span of the invocation; around a scan that hashes
+    on a device the JAX profiler runs as well, and for as long as it does
+    the tracer opens each span as a `jax.profiler.TraceAnnotation` too
+    (`Tracer.annotate`), so DIR gets the device's planes with the
+    program's spans beside them."""
+
+    def __init__(self, out_dir: str):
+        self.out_dir = out_dir
+        self.events: list[dict] = []
+        self._profiling = False
+
+    def __enter__(self):
+        _TR.open_reader(self, max_events=None)
+        return self
+
+    def start_profiler(self) -> None:
+        """Called once the backend is known to be a device: starting the
+        profiler initialises JAX's backend, which a host-hash scan must
+        never do."""
+        import jax.profiler
+
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0  # spans, not every Python call
+        options.host_tracer_level = 2
+        jax.profiler.start_trace(self.out_dir, profiler_options=options)
+        self._profiling = True
+        _TR.annotate = jax.profiler.TraceAnnotation
+
+    def __exit__(self, *exc):
+        from .stats import write_chrome_trace
+
+        try:
+            if self._profiling:
+                import jax.profiler
+
+                _TR.annotate = None
+                jax.profiler.stop_trace()
+        finally:
+            lines = _TR.read(self, 1 << 62).splitlines()
+            _TR.close_reader(self)
+        self.events = [json.loads(line) for line in lines]
+        path = write_chrome_trace(self.out_dir, self.events)
+        logger.info("%d spans -> %s", len(self.events), path)
+        return False
+
+
+def _gc(args, trace: "_ScanTrace | None", root) -> dict | None:
+    """The invocation below its root span; returns the --dedup stats
+    (which `run` prints), else None."""
     from . import build_store, open_meta
 
-    m, fmt = open_meta(args.meta_url)
-    # the flag's name and the volume's take the same road: tpu/device.py
-    # resolves either, and `tpu` without a TPU fails here — before the
-    # name diff has listed a single object
-    backend = args.hash_backend or fmt.hash_backend
-    if args.dedup:
-        from ..tpu.device import resolve_backend
+    with _TR.span("cmd", "gc", stage="open", hist=_H_OPEN):
+        m, fmt = open_meta(args.meta_url)
+        # the flag's name and the volume's take the same road:
+        # tpu/device.py resolves either, and `tpu` without a TPU fails
+        # here — before the name diff has listed a single object
+        backend = args.hash_backend or fmt.hash_backend
+        on_device = False
+        if args.dedup:
+            from ..tpu.device import resolve_backend
 
-        resolve_backend(backend)
-    # meta-attached store: dedup-scan reads of PUT-elided blocks resolve
-    # through the content-ref plane (ISSUE 5). No indexer: gc backfills
-    # digest rows itself through dedup_scan's own pipeline.
-    store = build_store(fmt, args, meta=m, with_indexer=False)
+            on_device = resolve_backend(backend) != "cpu"
+        # meta-attached store: dedup-scan reads of PUT-elided blocks
+        # resolve through the content-ref plane (ISSUE 5). No indexer: gc
+        # backfills digest rows itself through dedup_scan's own pipeline.
+        store = build_store(fmt, args, meta=m, with_indexer=False)
     bs = fmt.block_size * 1024
+    if trace is not None and on_device:
+        trace.start_profiler()
 
     if args.compact:
         from ..vfs.compact import compact_all
@@ -62,44 +157,45 @@ def run(args) -> int:
         n = compact_all(m, store)
         print(f"compacted {n} chunks")
 
-    # live slice -> expected blocks
-    slices = m.list_slices()
-    live: dict[str, int] = {}
-    for ino, slcs in slices.items():
-        for s in slcs:
-            if s.id == 0 or s.size == 0:
-                continue
-            n_blocks = (s.size + bs - 1) // bs
-            for i in range(n_blocks):
-                bsize = min(bs, s.size - i * bs)
-                live[block_key(s.id, i, bsize)] = bsize
-
-    # stored objects under chunks/
     import time as _time
 
-    cutoff = _time.time() - args.age
-    stored = {}
-    recent = set()
-    for obj in store.storage.list_all("chunks/"):
-        parsed = parse_block_key(obj.key)
-        if parsed is not None:
-            stored[obj.key] = obj.size
-            if obj.mtime > cutoff:
-                recent.add(obj.key)
+    with _TR.span("cmd", "gc", stage="list", hist=_H_LIST):
+        # live slice -> expected blocks
+        slices = m.list_slices()
+        live: dict[str, int] = {}
+        for ino, slcs in slices.items():
+            for s in slcs:
+                if s.id == 0 or s.size == 0:
+                    continue
+                n_blocks = (s.size + bs - 1) // bs
+                for i in range(n_blocks):
+                    bsize = min(bs, s.size - i * bs)
+                    live[block_key(s.id, i, bsize)] = bsize
 
-    # Inline dedup (ISSUE 5): an elided block has no object of its own —
-    # its bytes live under the canonical block of its content ref. The
-    # name diff must translate through the alias plane: aliased live
-    # blocks are not "missing", and a canonical object is not "leaked"
-    # while any live alias still references it.
-    try:
-        from ..chunk.ingest import alias_map
+        # stored objects under chunks/
+        cutoff = _time.time() - args.age
+        stored = {}
+        recent = set()
+        for obj in store.storage.list_all("chunks/"):
+            parsed = parse_block_key(obj.key)
+            if parsed is not None:
+                stored[obj.key] = obj.size
+                if obj.mtime > cutoff:
+                    recent.add(obj.key)
 
-        aliases = alias_map(m)
-        protected = set(aliases.values())
-    except Exception as e:
-        logger.warning("content-ref scan unavailable: %s", e)
-        aliases, protected = {}, set()
+        # Inline dedup (ISSUE 5): an elided block has no object of its own
+        # — its bytes live under the canonical block of its content ref.
+        # The name diff must translate through the alias plane: aliased
+        # live blocks are not "missing", and a canonical object is not
+        # "leaked" while any live alias still references it.
+        try:
+            from ..chunk.ingest import alias_map
+
+            aliases = alias_map(m)
+            protected = set(aliases.values())
+        except Exception as e:
+            logger.warning("content-ref scan unavailable: %s", e)
+            aliases, protected = {}, set()
 
     # An object can be uploaded before its slice commits to meta (the write
     # pipeline is async), so fresh objects are never "leaked" (reference gc
@@ -126,18 +222,22 @@ def run(args) -> int:
             list(pool.map(store.storage.delete, leaked))
         print(f"deleted {len(leaked)} leaked objects")
 
-    if args.dedup:
-        stats = dedup_scan(m, store, live, backend, args.dedup_index, bs,
-                           threads=args.threads)
-        # offline complement of the inline ingest stage: repair refcounts
-        # left by crash windows, register existing content so future
-        # writes elide, and (with --delete) collapse duplicate objects
-        # already in the store into aliases
+    if not args.dedup:
+        return None
+    stats = dedup_scan(m, store, live, backend, args.dedup_index, bs,
+                       threads=args.threads)
+    # offline complement of the inline ingest stage: repair refcounts
+    # left by crash windows, register existing content so future
+    # writes elide, and (with --delete) collapse duplicate objects
+    # already in the store into aliases
+    with _TR.span("cmd", "gc", stage="reconcile", hist=_H_RECONCILE):
         stats["content_refs"] = reconcile_content_refs(
             m, store, live, stored, collapse=args.delete, age=args.age
         )
-        print(json.dumps(stats))
-    return 0
+    if root.active:
+        root.set(backend=stats["backend"], blocks=stats["blocks"],
+                 hashed_now=stats["hashed_now"])
+    return stats
 
 
 def dedup_scan(meta, store, live: dict[str, int], backend: str,
@@ -164,18 +264,19 @@ def dedup_scan(meta, store, live: dict[str, int], backend: str,
 
     t0 = _time.perf_counter()
     # 1. load the persistent index; prune rows for dead slices
-    digest_by_key: dict[str, bytes] = {}
-    stale: list[tuple[int, int]] = []
-    for sid, indx, bsize, digest in meta.scan_block_digests():
-        key = block_key(sid, indx, bsize)
-        if key in live:
-            digest_by_key[key] = digest
-        else:
-            stale.append((sid, indx))
-    if stale:
-        meta.delete_block_digests(stale)
-    indexed = len(digest_by_key)
-    t_index = _time.perf_counter() - t0
+    with _TR.span("cmd", "gc", stage="index_load",
+                  hist=_H_INDEX_LOAD) as sp_index:
+        digest_by_key: dict[str, bytes] = {}
+        stale: list[tuple[int, int]] = []
+        for sid, indx, bsize, digest in meta.scan_block_digests():
+            key = block_key(sid, indx, bsize)
+            if key in live:
+                digest_by_key[key] = digest
+            else:
+                stale.append((sid, indx))
+        if stale:
+            meta.delete_block_digests(stale)
+        indexed = len(digest_by_key)
 
     # 2. hash only blocks the write path didn't index; backfill their rows
     missing = [k for k in live if k not in digest_by_key]
@@ -195,36 +296,40 @@ def dedup_scan(meta, store, live: dict[str, int], backend: str,
             store._bulk_pool, window, on_error="skip", stats=fstats,
         )
 
-    t1 = _time.perf_counter()
     backfill = []
-    for key, digest in pipe.hash_stream(blocks()):
-        digest_by_key[key] = digest
-        sid, indx, bsize = parse_block_key(key)
-        backfill.append((sid, indx, bsize, digest))
-    t_readhash = _time.perf_counter() - t1
-    t2 = _time.perf_counter()
-    if backfill:
-        meta.set_block_digests(backfill)
-    t_meta = _time.perf_counter() - t2
+    with _TR.span("cmd", "gc", stage="readhash",
+                  hist=_H_READHASH) as sp_readhash:
+        for key, digest in pipe.hash_stream(blocks()):
+            digest_by_key[key] = digest
+            sid, indx, bsize = parse_block_key(key)
+            backfill.append((sid, indx, bsize, digest))
+        if sp_readhash.active:
+            sp_readhash.set(blocks=len(backfill), window=window)
+    with _TR.span("cmd", "gc", stage="backfill",
+                  hist=_H_BACKFILL) as sp_backfill:
+        if backfill:
+            meta.set_block_digests(backfill)
 
     # 3. duplicate grouping over the full digest set
-    t3 = _time.perf_counter()
-    keys = list(digest_by_key)
-    digests = [digest_by_key[k] for k in keys]
-    dup_mask, first_idx = dedup_digests(digests)
-    dup_bytes = sum(live[keys[i]] for i, d in enumerate(dup_mask) if d)
-    groups: dict[str, list[str]] = {}
-    for i, d in enumerate(dup_mask):
-        if d:
-            groups.setdefault(keys[first_idx[i]], []).append(keys[i])
-    t_group = _time.perf_counter() - t3
+    with _TR.span("cmd", "gc", stage="group", hist=_H_GROUP) as sp_group:
+        keys = list(digest_by_key)
+        digests = [digest_by_key[k] for k in keys]
+        dup_mask, first_idx = dedup_digests(digests)
+        dup_bytes = sum(live[keys[i]] for i, d in enumerate(dup_mask) if d)
+        groups: dict[str, list[str]] = {}
+        for i, d in enumerate(dup_mask):
+            if d:
+                groups.setdefault(keys[first_idx[i]], []).append(keys[i])
     if index_path:
-        with open(index_path, "w") as f:
-            json.dump(
-                {keys[i]: digest_hex(digests[i]) for i in range(len(keys))},
-                f,
-                indent=1,
-            )
+        with _TR.span("cmd", "gc", stage="write_index",
+                      hist=_H_WRITE_INDEX):
+            with open(index_path, "w") as f:
+                json.dump(
+                    {keys[i]: digest_hex(digests[i])
+                     for i in range(len(keys))},
+                    f,
+                    indent=1,
+                )
     total = _time.perf_counter() - t0
     nbytes = sum(live.values())
     from ..object.resilient import resilience_snapshot
@@ -245,17 +350,20 @@ def dedup_scan(meta, store, live: dict[str, int], backend: str,
         # `get` is WALL time the fetch stage had GETs in flight;
         # `get_threads` is aggregate per-thread GET seconds — their ratio
         # is the achieved I/O overlap factor (ISSUE 2), and `hash` is the
-        # read+hash wall not hidden behind the fetch window.
+        # read+hash wall (`readhash`) not hidden behind the fetch window.
+        # The stages are their spans' durations, to the microsecond;
+        # `seconds` is the scan's own wall, stages and what lies between.
         "seconds": round(total, 3),
         "gibs": round(nbytes / (1 << 30) / total, 3) if total > 0 else 0.0,
         "blocks_per_s": round(len(keys) / total, 1) if total > 0 else 0.0,
         "stage_seconds": {
-            "index_load": round(t_index, 3),
-            "get": round(fstats.wall, 3),
-            "get_threads": round(fstats.seconds, 3),
-            "hash": round(max(t_readhash - fstats.wall, 0.0), 3),
-            "meta_backfill": round(t_meta, 3),
-            "dup_group": round(t_group, 3),
+            "index_load": round(sp_index.dur, 6),
+            "get": round(fstats.wall, 6),
+            "get_threads": round(fstats.seconds, 6),
+            "hash": round(max(sp_readhash.dur - fstats.wall, 0.0), 6),
+            "meta_backfill": round(sp_backfill.dur, 6),
+            "dup_group": round(sp_group.dur, 6),
+            "readhash": round(sp_readhash.dur, 6),
         },
         # retry/hedge/breaker activity during the scan (the GETs run
         # through object/resilient.py): a scan that paid for fault

@@ -100,7 +100,8 @@ def open_stream(path: str) -> int:
 
 # event keys that are structure, not user attrs, when converting to the
 # Chrome trace_event format
-_SPAN_FIELDS = ("ts", "dur", "trace", "id", "parent", "layer", "op", "stage")
+_SPAN_FIELDS = ("ts", "dur", "trace", "id", "parent", "tid", "layer", "op",
+                "stage")
 
 
 def _chrome_event(ev: dict) -> dict:
@@ -112,6 +113,7 @@ def _chrome_event(ev: dict) -> dict:
     args = {k: v for k, v in ev.items() if k not in _SPAN_FIELDS}
     args["span_id"] = ev.get("id", 0)
     args["parent_id"] = ev.get("parent", 0)
+    args["trace_id"] = ev.get("trace", 0)
     return {
         "name": name,
         "cat": str(ev.get("layer", "?")),
@@ -119,9 +121,28 @@ def _chrome_event(ev: dict) -> dict:
         "ts": float(ev.get("ts", 0.0)) * 1e6,
         "dur": max(float(ev.get("dur", 0.0)) * 1e6, 0.1),
         "pid": 1,
-        "tid": int(ev.get("trace", 0)),
+        # one lane a thread, so that spans nest as they ran and pool
+        # threads get lanes of their own (events from before `tid` existed
+        # fall back to a lane a trace)
+        "tid": int(ev.get("tid", ev.get("trace", 0))),
         "args": args,
     }
+
+
+def write_chrome_trace(out_dir: str, events: list[dict]) -> str:
+    """Span events -> `<out_dir>/juicefs-trace.json` (`profile --trace`
+    from a mount's stream, `gc --trace` from its own process)."""
+    os.makedirs(out_dir, exist_ok=True)
+    path = os.path.join(out_dir, "juicefs-trace.json")
+    with open(path, "w") as out:
+        json.dump(
+            {
+                "traceEvents": [_chrome_event(ev) for ev in events],
+                "displayTimeUnit": "ms",
+            },
+            out,
+        )
+    return path
 
 
 def run_trace_profile(args) -> int:
@@ -150,16 +171,7 @@ def run_trace_profile(args) -> int:
                     events.append(ev)
     finally:
         os.close(fd)
-    os.makedirs(args.trace, exist_ok=True)
-    path = os.path.join(args.trace, "juicefs-trace.json")
-    with open(path, "w") as out:
-        json.dump(
-            {
-                "traceEvents": [_chrome_event(ev) for ev in events],
-                "displayTimeUnit": "ms",
-            },
-            out,
-        )
+    path = write_chrome_trace(args.trace, events)
     per_layer: dict[str, int] = defaultdict(int)
     for ev in events:
         per_layer[str(ev.get("layer", "?"))] += 1

@@ -12,6 +12,8 @@ histograms. Three exposures:
     (fuse → vfs → chunk → object → tpu);
   - `juicefs profile --trace DIR`: samples the stream and writes a Chrome
     `trace_event` JSON loadable in chrome://tracing / Perfetto;
+    `juicefs gc --trace DIR` does the same for the process that has no
+    mount, through an in-process reader (cmd/gc.py);
   - `juicefs_tpu_stage_seconds{layer,op,stage}`: always-on histogram
     rollup in the global registry, the per-stage attribution substrate
     for perf work (ROADMAP north star; round-4 cold-scan postmortem).
@@ -19,7 +21,12 @@ histograms. Three exposures:
 Cross-thread propagation: span context rides a per-thread stack, so the
 synchronous read path links automatically; pool crossings (upload pool,
 download fan-out, slice fan-out) capture `current_ref()` at submit time
-and pass it as `parent=`.
+and pass it as `parent=`, or run the worker under `carried(ref)`.
+
+`Tracer.annotate` is a hook for whoever runs a profiler session beside a
+reader (cmd/gc.py `--trace` on a device backend): while it is set, every
+`Span` also opens `annotate("jfs.<layer>.<op>[.<stage>]")`, which puts the
+program's spans on the device trace's clock. This module knows no profiler.
 """
 
 from __future__ import annotations
@@ -34,8 +41,8 @@ from typing import Optional
 
 from . import global_registry
 
-__all__ = ["NULL_SPAN", "Tracer", "global_tracer", "stage_hist",
-           "stage_metrics_snapshot"]
+__all__ = ["NULL_SPAN", "Tracer", "global_tracer", "span_name",
+           "span_summary", "stage_hist", "stage_metrics_snapshot"]
 
 MAX_BUFFERED_EVENTS = 10240
 
@@ -80,6 +87,42 @@ def stage_metrics_snapshot() -> dict:
     return out
 
 
+def span_name(layer, op, stage="") -> str:
+    """`jfs.<layer>.<op>[.<stage>]`: a span's name on the profiler's clock
+    (the benchmark's hook on `Tracer.span` gives the same)."""
+    return "jfs." + ".".join(str(x) for x in (layer, op, stage) if x)
+
+
+def span_summary(events: list[dict]) -> dict[str, dict]:
+    """Span events -> {name: {"n", "total_s", "self_s"}}. A span's self
+    time is its duration less the union of its children's intervals
+    (children by `parent`, whatever thread they ran on, clipped to the
+    parent: a pool thread's child may outlive its parent's wait)."""
+    children: dict[int, list[tuple[float, float]]] = {}
+    for ev in events:
+        children.setdefault(ev.get("parent", 0), []).append(
+            (ev["ts"], ev["ts"] + ev["dur"]))
+    out: dict[str, dict] = {}
+    for ev in events:
+        lo, hi = ev["ts"], ev["ts"] + ev["dur"]
+        covered, at = 0.0, lo
+        for s, e in sorted(children.get(ev["id"], ())):
+            s, e = max(s, at), min(e, hi)
+            if e > s:
+                covered += e - s
+                at = e
+        row = out.setdefault(
+            span_name(ev.get("layer"), ev.get("op"), ev.get("stage", "")),
+            {"n": 0, "total_s": 0.0, "self_s": 0.0})
+        row["n"] += 1
+        row["total_s"] += ev["dur"]
+        row["self_s"] += ev["dur"] - covered
+    for row in out.values():
+        row["total_s"] = round(row["total_s"], 6)
+        row["self_s"] = round(row["self_s"], 6)
+    return out
+
+
 class _NullSpan:
     """Shared no-op span: the zero-cost path when no consumer is attached
     and the call site carries no stage histogram."""
@@ -117,7 +160,7 @@ class _TimedSpan:
     """No consumer attached but a stage histogram bound: time the region
     and observe — nothing else (the <5% no-reader overhead budget)."""
 
-    __slots__ = ("_hist", "_t0")
+    __slots__ = ("_hist", "_t0", "dur")
     active = False
 
     def __init__(self, hist):
@@ -128,7 +171,10 @@ class _TimedSpan:
         return self
 
     def __exit__(self, *a):
-        self._hist.observe(time.perf_counter() - self._t0)
+        # kept for the caller that reports its stages (gc --dedup's
+        # stage_seconds): the same number the histogram took
+        self.dur = time.perf_counter() - self._t0
+        self._hist.observe(self.dur)
         return False
 
     def set(self, **kw) -> None:
@@ -142,7 +188,8 @@ class Span:
     """One traced region; emitted as a JSON event line on exit."""
 
     __slots__ = ("tracer", "layer", "op", "stage", "hist", "attrs",
-                 "trace_id", "span_id", "parent_id", "_t0", "_ts")
+                 "trace_id", "span_id", "parent_id", "_t0", "_ts", "_ann",
+                 "dur")
     active = True
 
     def __init__(self, tracer: "Tracer", layer: str, op: str, stage: str,
@@ -169,12 +216,20 @@ class Span:
             else:  # root: the trace is named after its root span
                 self.trace_id, self.parent_id = self.span_id, 0
         stack.append(self)
+        annotate = tr.annotate
+        if annotate is None:
+            self._ann = None
+        else:
+            self._ann = annotate(span_name(self.layer, self.op, self.stage))
+            self._ann.__enter__()
         self._ts = time.time()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, et, ev, tb):
-        dur = time.perf_counter() - self._t0
+        self.dur = dur = time.perf_counter() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(et, ev, tb)
         if self.hist is not None:
             self.hist.observe(dur)
         stack = self.tracer._local.__dict__.get("stack")
@@ -204,6 +259,9 @@ class Tracer:
         self._active = False
         self._local = threading.local()
         self._ids = itertools.count(1)
+        # name -> context manager, or None: set by the owner of a
+        # profiler session for as long as it runs (module docstring)
+        self.annotate = None
 
     @property
     def active(self) -> bool:
@@ -254,6 +312,7 @@ class Tracer:
             "trace": span.trace_id,
             "id": span.span_id,
             "parent": span.parent_id,
+            "tid": threading.get_native_id(),
             "layer": span.layer,
             "op": span.op,
         }
@@ -270,17 +329,21 @@ class Tracer:
                 buf.append(line)
 
     # -- reader lifecycle (one ring buffer per .trace open) ----------------
-    def open_reader(self, fh: int) -> None:
+    def open_reader(self, fh,
+                    max_events: Optional[int] = MAX_BUFFERED_EVENTS) -> None:
+        """Attach a consumer. `max_events=None` keeps every event until it
+        is read: for an in-process reader that drains once, at the end of
+        a command (gc --trace); a ring drops its oldest."""
         with self._lock:
-            self._readers[fh] = deque(maxlen=MAX_BUFFERED_EVENTS)
+            self._readers[fh] = deque(maxlen=max_events)
             self._active = True
 
-    def close_reader(self, fh: int) -> None:
+    def close_reader(self, fh) -> None:
         with self._lock:
             self._readers.pop(fh, None)
             self._active = bool(self._readers)
 
-    def read(self, fh: int, max_bytes: int = 1 << 16) -> bytes:
+    def read(self, fh, max_bytes: int = 1 << 16) -> bytes:
         """Drain buffered events for one reader (blocking up to 1s so
         `tail -f` style consumers don't spin; same shape as accesslog)."""
         deadline = time.time() + 1.0

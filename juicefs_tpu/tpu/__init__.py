@@ -19,11 +19,13 @@ Modules:
               report every device-path output prints, the compile cache
 """
 
-from .device import configure_compile_cache
+from .device import configure_compile_cache, count_compiles
 
 # The ONE place the persistent compile cache is set: importing any module
-# of this package runs this before that process's first compilation.
+# of this package runs this before that process's first compilation, and
+# counts every program compiled or loaded from then on.
 configure_compile_cache()
+count_compiles()
 
 from .jth256 import (
     BLOCK_BYTES,

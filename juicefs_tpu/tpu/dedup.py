@@ -14,15 +14,14 @@ duplicate (reclaimable). first_idx maps every block to its representative.
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from .hash_jax import hash_packed_jax, named_jit
 
-@jax.jit
+
 def dedup_scan_jax(digests: jax.Array):
     """digests (N, 8) uint32 -> (dup_mask (N,) bool, first_idx (N,) int32).
 
@@ -54,18 +53,21 @@ def dedup_scan_jax(digests: jax.Array):
     return dup, first_idx
 
 
-@functools.partial(jax.jit)
 def scan_step_jax(words, lane_counts, lengths):
     """Full single-device scan step: hash the packed batch, dedup it.
 
     Returns (digests (B,8) uint32, dup_mask (B,), first_idx (B,)). This is
     the flagship jittable forward step exposed by __graft_entry__.entry().
     """
-    from .hash_jax import hash_packed_jax
-
     digests = hash_packed_jax(words, lane_counts, lengths)
     dup, first = dedup_scan_jax(digests)
     return digests, dup, first
+
+
+# fixed program names (tpu/hash_jax.py:named_jit): `jit_dedup_scan`,
+# `jit_jth256_scan` in the profiler's XLA Modules line
+dedup_scan_jax = named_jit("dedup_scan", dedup_scan_jax)
+scan_step_jax = named_jit("jth256_scan", scan_step_jax)
 
 
 def dedup_digests(digests: list[bytes]):

@@ -13,6 +13,8 @@ the report built here, so a CPU run can never be read as a chip run:
                           degraded + reason, resolved backend, Pallas mode
   configure_compile_cache the persistent XLA compile cache, placed from
                           outside by JAX_COMPILATION_CACHE_DIR
+  count_compiles          juicefs_tpu_compiles / _compile_seconds: every
+                          program JAX built or loaded from that cache
 
 Policy for `--hash-backend tpu` without a TPU (README "Hash backends"): the
 command fails and names the platform JAX found. That includes a chip-less
@@ -25,6 +27,11 @@ byte-identical across backends, so the index stays valid).
 from __future__ import annotations
 
 import os
+import threading
+
+# No import of the package at module level: chip_smoke.py loads this file by
+# path, outside `juicefs_tpu`, for `default_compile_cache_dir` alone. What
+# needs the metrics registry or the tracer imports it where it runs.
 
 # Names a user may give; "tpu" is a requirement on the platform, the rest
 # name an implementation and say which platform they ran on.
@@ -32,6 +39,15 @@ HASH_BACKENDS = ("cpu", "xla", "pallas", "tpu")
 
 _CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
 _MIN_COMPILE_ENV = "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"
+
+
+# JAX 0.9.0 (jax/_src/compiler.py, pxla.py): one duration event for every
+# program made ready, built or loaded. On the way, on the same thread and
+# before the duration: a plain event when the request goes to the persistent
+# cache at all, and another if the cache had the program
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_REQUEST_EVENT = "/jax/compilation_cache/compile_requests_use_cache"
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 
 
 class DeviceUnavailable(RuntimeError):
@@ -70,6 +86,80 @@ def configure_compile_cache(environ=None, update=None) -> str:
     return path
 
 
+def count_compiles(monitoring=None) -> None:
+    """Feed `juicefs_tpu_compiles{source}` and `_compile_seconds{source}`
+    from JAX's monitoring events. Called once, beside the compile cache's
+    configuration (`juicefs_tpu/tpu/__init__.py`); `monitoring` is an
+    injection point for tests."""
+    from ..metric import global_registry
+
+    if monitoring is None:
+        import jax.monitoring as monitoring
+    reg = global_registry()
+    compiles = reg.counter(
+        "juicefs_tpu_compiles",
+        "Programs JAX made ready to run, by where they came from: built "
+        "by the compiler, or loaded from the persistent compile cache",
+        ("source",),
+    )
+    compile_seconds = reg.histogram(
+        "juicefs_tpu_compile_seconds",
+        "Wall time of making one program ready (compile, or cache load)",
+        ("source",),
+        buckets=(0.01, 0.05, 0.1, 0.5, 1, 2, 5, 10, 30, 60, 300),
+    )
+    local = threading.local()
+    series = {source: (compiles.labels(source),
+                       compile_seconds.labels(source))
+              for source in ("built", "cache")}
+
+    def on_event(event: str, **kw) -> None:
+        if event == _CACHE_REQUEST_EVENT:
+            # every request starts as a build: a hit that no duration
+            # followed on this thread must not mark the next program
+            local.hit = False
+        elif event == _CACHE_HIT_EVENT:
+            local.hit = True
+
+    def on_duration(event: str, duration: float, **kw) -> None:
+        if event == _COMPILE_EVENT:
+            hit, local.hit = getattr(local, "hit", False), False
+            count, seconds = series["cache" if hit else "built"]
+            count.inc()
+            seconds.observe(duration)
+
+    monitoring.register_event_listener(on_event)
+    monitoring.register_event_duration_secs_listener(on_duration)
+
+
+def init_span():
+    """The `tpu.device.init` span: what a process pays once to have its
+    devices — the backend coming up here, the plane in tpu/sharding.py."""
+    from ..metric.trace import global_tracer, stage_hist
+
+    return global_tracer().span("tpu", "device", stage="init",
+                                hist=stage_hist("tpu", "device", "init"))
+
+
+_backend_up = False
+
+
+def _devices():
+    """`jax.devices()`; the call that brings the backend up (the first
+    one this module makes) runs under the `tpu.device.init` span."""
+    global _backend_up
+    import jax
+
+    if _backend_up:
+        return jax.devices()
+    with init_span() as sp:
+        devs = jax.devices()
+        if sp.active:
+            sp.set(platform=devs[0].platform, devices=len(devs))
+    _backend_up = True
+    return devs
+
+
 def resolve_backend(requested: str) -> str:
     """Map a requested hash backend (volume Format value or command-line
     flag) to the HashPipeline backend that will run: cpu | xla | pallas.
@@ -87,10 +177,8 @@ def resolve_backend(requested: str) -> str:
         raise ValueError(
             f"unknown hash backend {requested!r} "
             f"(want {'|'.join(HASH_BACKENDS)})")
-    import jax
-
     try:
-        platform = jax.devices()[0].platform
+        platform = _devices()[0].platform
     except Exception as e:
         raise DeviceUnavailable(
             f"hash backend 'tpu' needs a TPU, but JAX could not "

@@ -19,8 +19,6 @@ compile cache, tpu/device.py).
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -55,6 +53,14 @@ def _u32(c: int):
     return jnp.uint32(c)
 
 
+def named_jit(name: str, fn, **jit_kwargs):
+    """`jax.jit(fn)` under a fixed program name: the profiler's `XLA
+    Modules` events read `jit_<name>(...)` whatever the Python around the
+    program is called, so a reduction can find it after a refactor."""
+    fn.__name__ = fn.__qualname__ = name
+    return jax.jit(fn, **jit_kwargs)
+
+
 def _rotl(x, k: int):
     return (x << jnp.uint32(k)) | (x >> jnp.uint32(32 - k))
 
@@ -76,7 +82,8 @@ def _row_chain_scan(words: jax.Array, s0: jax.Array) -> jax.Array:
         s = s ^ (s >> jnp.uint32(15))
         return s, None
 
-    s, _ = lax.scan(step, s0, jnp.moveaxis(words, 2, 0))
+    with jax.named_scope("row_chain"):
+        s, _ = lax.scan(step, s0, jnp.moveaxis(words, 2, 0))
     return s
 
 
@@ -93,15 +100,16 @@ def _lane_states(words: jax.Array, lane_offset=0) -> jax.Array:
 def _lane_accs(s: jax.Array, lane_offset=0) -> jax.Array:
     """Fold lane states (B,M,128) -> per-lane digests (B,M,8)."""
     b, m = s.shape[0], s.shape[1]
-    lanes = jnp.arange(m, dtype=jnp.uint32) + jnp.uint32(lane_offset)
-    k8 = jnp.arange(8, dtype=jnp.uint32)
-    g = s.reshape(b, m, 16, 8)
-    acc = jnp.broadcast_to(
-        _u32(_P4) ^ (lanes * _u32(_P2))[None, :, None] ^ (k8 * _u32(_P1))[None, None, :],
-        (b, m, 8),
-    )
-    for gi in range(16):
-        acc = _rotl((acc ^ g[:, :, gi, :]) * _u32(_P3), 11) + jnp.uint32(gi) * _u32(_P5)
+    with jax.named_scope("lane_fold"):
+        lanes = jnp.arange(m, dtype=jnp.uint32) + jnp.uint32(lane_offset)
+        k8 = jnp.arange(8, dtype=jnp.uint32)
+        g = s.reshape(b, m, 16, 8)
+        acc = jnp.broadcast_to(
+            _u32(_P4) ^ (lanes * _u32(_P2))[None, :, None] ^ (k8 * _u32(_P1))[None, None, :],
+            (b, m, 8),
+        )
+        for gi in range(16):
+            acc = _rotl((acc ^ g[:, :, gi, :]) * _u32(_P3), 11) + jnp.uint32(gi) * _u32(_P5)
     return acc
 
 
@@ -121,9 +129,11 @@ def _combine_accs(
         live = (counts > li)[:, None]
         return jnp.where(live, hn, h), None
 
-    h, _ = lax.scan(lane_step, h0, (jnp.moveaxis(acc, 1, 0), lanes))
-    h = h ^ (lengths.astype(jnp.uint32)[:, None] + k8[None, :] * _u32(_P4))
-    return _fmix(h)
+    with jax.named_scope("lane_combine"):
+        h, _ = lax.scan(lane_step, h0, (jnp.moveaxis(acc, 1, 0), lanes))
+    with jax.named_scope("finish"):
+        h = h ^ (lengths.astype(jnp.uint32)[:, None] + k8[None, :] * _u32(_P4))
+        return _fmix(h)
 
 
 def _finish(
@@ -133,12 +143,14 @@ def _finish(
     return _combine_accs(_lane_accs(s), lane_counts, lengths)
 
 
-@functools.partial(jax.jit, static_argnames=())
 def hash_packed_jax(
     words: jax.Array, lane_counts: jax.Array, lengths: jax.Array
 ) -> jax.Array:
     """XLA path: (B, M, 128, 128) uint32 -> (B, 8) uint32 digests."""
     return _finish(_row_chain_scan(words, _lane_states(words)), lane_counts, lengths)
+
+
+hash_packed_jax = named_jit("jth256_hash", hash_packed_jax)
 
 
 # ---------------------------------------------------------------------------
@@ -251,7 +263,6 @@ def _pallas_row_chain(
     return out[:n_lanes]
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "lane_group"))
 def _hash_packed_pallas_impl(
     words: jax.Array, lane_counts: jax.Array, lengths: jax.Array,
     tweak: jax.Array, interpret: bool, lane_group: int | None = None,
@@ -262,6 +273,11 @@ def _hash_packed_pallas_impl(
         lane_group=lane_group,
     ).reshape(b, m, COLS)
     return _finish(s, lane_counts, lengths)
+
+
+_hash_packed_pallas_impl = named_jit(
+    "jth256_hash_pallas", _hash_packed_pallas_impl,
+    static_argnames=("interpret", "lane_group"))
 
 
 def hash_packed_pallas(

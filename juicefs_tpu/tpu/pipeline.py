@@ -7,6 +7,10 @@ a double-buffered device pipeline: JAX dispatch is async, so packing batch
 k+1 on the host overlaps hashing batch k on the TPU; results are only
 blocked on one batch behind.
 
+Each batch is one `tpu.hash.dispatch` span with the children `pack` (here),
+`h2d` and `enqueue` (tpu/sharding.py, where the transfer and the jitted
+call live), then one `tpu.hash.drain` when its digests are read back.
+
 Backend selection mirrors the reference's Compressor registry pattern
 (pkg/compress/compress.go:31-49): "cpu" (C++/numpy host hash), "xla",
 "pallas", and "tpu" (the xla program, on a TPU or not at all). Names are
@@ -61,6 +65,7 @@ _FIRST_BATCH = _reg.gauge(
 )
 _TR = global_tracer()
 _H_DISPATCH = stage_hist("tpu", "hash", "dispatch")
+_H_PACK = stage_hist("tpu", "hash", "pack")
 _H_DRAIN = stage_hist("tpu", "hash", "drain")
 
 
@@ -145,7 +150,13 @@ class HashPipeline:
 
                     pending.append((keys, native.jth256_batch(blocks), t0))
                 else:
-                    words, counts, lengths = pack_blocks(blocks, pad_lanes=cfg.pad_lanes)
+                    with _TR.span("tpu", "hash", stage="pack",
+                                  hist=_H_PACK) as psp:
+                        words, counts, lengths = pack_blocks(
+                            blocks, pad_lanes=cfg.pad_lanes)
+                        if psp.active:
+                            psp.set(batch=len(blocks), bytes=nbytes,
+                                    padded_bytes=words.nbytes)
                     _H2D_BYTES.inc(words.nbytes)
                     pending.append(
                         (keys, self._fn(words, counts, lengths), t0))

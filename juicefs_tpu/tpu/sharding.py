@@ -44,14 +44,17 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..metric import global_registry
+from ..metric.trace import global_tracer, stage_hist
 from ..utils import get_logger
 from .dedup import dedup_scan_jax
+from .device import init_span
 from .hash_jax import (
     _combine_accs,
     _lane_accs,
     _lane_states,
     _row_chain_scan,
     make_hash_fn,
+    named_jit,
 )
 
 logger = get_logger("tpu.shard")
@@ -71,6 +74,9 @@ _DEGRADED = _reg.counter(
     "Sharding-plane degrades to single-device jit (odd device count, "
     "mesh-init failure, or an indivisible batch at call time)",
 )
+_TR = global_tracer()
+_H_H2D = stage_hist("tpu", "hash", "h2d")
+_H_ENQUEUE = stage_hist("tpu", "hash", "enqueue")
 
 
 # Every shard_map below passes check_vma=False: the per-device bodies
@@ -97,14 +103,24 @@ def _scan_body(words, lane_counts, lengths):
     fused benchmark loop — one definition, no drift): row chains on local
     lanes, gather tiny per-lane digests across the lane axis, combine,
     gather 32 B/block digests across data, dedup."""
+    all_digests = _hash_body(words, lane_counts, lengths)
+    dup, first = dedup_scan_jax(all_digests)
+    return all_digests, dup, first
+
+
+def _hash_body(words, lane_counts, lengths):
+    """Per-device hash: row chains on the local lanes, the tiny per-lane
+    digests gathered across the lane axis, the combine every device
+    replays, the 32 B/block digests gathered across data."""
     local_m = words.shape[1]
     loff = lax.axis_index("lane") * local_m
     s = _row_chain_scan(words, _lane_states(words, loff))
-    acc = lax.all_gather(_lane_accs(s, loff), "lane", axis=1, tiled=True)
+    accs = _lane_accs(s, loff)
+    with jax.named_scope("lane_all_gather"):
+        acc = lax.all_gather(accs, "lane", axis=1, tiled=True)
     digests = _combine_accs(acc, lane_counts, lengths)
-    all_digests = lax.all_gather(digests, "data", axis=0, tiled=True)
-    dup, first = dedup_scan_jax(all_digests)
-    return all_digests, dup, first
+    with jax.named_scope("data_all_gather"):
+        return lax.all_gather(digests, "data", axis=0, tiled=True)
 
 
 def sharded_scan_step(mesh: Mesh):
@@ -115,17 +131,14 @@ def sharded_scan_step(mesh: Mesh):
     data axis and M by the lane axis. Outputs are fully replicated.
     """
 
-    def step(words, lane_counts, lengths):
-        return _scan_body(words, lane_counts, lengths)
-
     mapped = jax.shard_map(
-        step,
+        _scan_body,
         mesh=mesh,
         in_specs=(P("data", "lane", None, None), P("data"), P("data")),
         out_specs=(P(), P(), P()),
         check_vma=False,
     )
-    return jax.jit(mapped)
+    return named_jit("jth256_scan_sharded", mapped)
 
 
 def sharded_scan_many(mesh: Mesh):
@@ -214,22 +227,14 @@ def sharded_hash_step(mesh: Mesh):
     (B, 8), fully replicated. Same body as `sharded_scan_step` minus the
     dedup tail — the pipeline dedups on host against the meta index."""
 
-    def step(words, lane_counts, lengths):
-        local_m = words.shape[1]
-        loff = lax.axis_index("lane") * local_m
-        s = _row_chain_scan(words, _lane_states(words, loff))
-        acc = lax.all_gather(_lane_accs(s, loff), "lane", axis=1, tiled=True)
-        digests = _combine_accs(acc, lane_counts, lengths)
-        return lax.all_gather(digests, "data", axis=0, tiled=True)
-
     mapped = jax.shard_map(
-        step,
+        _hash_body,
         mesh=mesh,
         in_specs=(P("data", "lane", None, None), P("data"), P("data")),
         out_specs=P(),
         check_vma=False,
     )
-    return jax.jit(mapped)
+    return named_jit("jth256_hash_sharded", mapped)
 
 
 def sharded_estimate_step(mesh: Mesh):
@@ -271,7 +276,7 @@ def sharded_estimate_step(mesh: Mesh):
         out_specs=P(),
         check_vma=False,
     )
-    return jax.jit(mapped)
+    return named_jit("compress_estimate_sharded", mapped)
 
 
 class ShardPlane:
@@ -358,13 +363,17 @@ class ShardPlane:
         still exactly one transfer, still counted.
         """
         b = int(words.shape[0])
-        if not self._shardable(words):
-            if self.mesh is not None and b > 0:
-                _DEGRADED.inc()  # sharded plane active but batch can't split
-            arrays = tuple(
-                jax.device_put(a) for a in (words, lane_counts, lengths))
-        else:
-            arrays = shard_batch(self.mesh, words, lane_counts, lengths)
+        sharded = self._shardable(words)
+        with _TR.span("tpu", "hash", stage="h2d", hist=_H_H2D) as sp:
+            if sp.active:
+                sp.set(bytes=int(words.nbytes), sharded=sharded)
+            if not sharded:
+                if self.mesh is not None and b > 0:
+                    _DEGRADED.inc()  # sharded plane active, batch can't split
+                arrays = tuple(
+                    jax.device_put(a) for a in (words, lane_counts, lengths))
+            else:
+                arrays = shard_batch(self.mesh, words, lane_counts, lengths)
         _H2D_BATCHES.inc()
         return ShardedPack(arrays, b)
 
@@ -384,10 +393,14 @@ class ShardPlane:
         ):
             if self._hash_sharded is None:
                 self._hash_sharded = sharded_hash_step(self.mesh)
-            return self._hash_sharded(words, lane_counts, lengths)
-        if self._hash_single is None:
-            self._hash_single = make_hash_fn("xla")
-        return self._hash_single(words, lane_counts, lengths)
+            program = self._hash_sharded
+        else:
+            if self._hash_single is None:
+                self._hash_single = make_hash_fn("xla")
+            program = self._hash_single
+        # the async call alone: a retrace or a recompile lands here
+        with _TR.span("tpu", "hash", stage="enqueue", hist=_H_ENQUEUE):
+            return program(words, lane_counts, lengths)
 
     def hash_packed(self, words, lane_counts, lengths, n: int | None = None):
         """(B, M, 128, 128) -> (n, 8) uint32 digests, byte-identical to
@@ -461,7 +474,12 @@ def get_plane() -> ShardPlane:
     global _plane
     with _plane_lock:
         if _plane is None:
-            _plane = ShardPlane()
+            # once a process: jax.devices() (backend init, unless
+            # tpu/device.py's resolver paid it already) and the mesh
+            with init_span() as sp:
+                _plane = ShardPlane()
+                if sp.active:
+                    sp.set(**_plane.snapshot())
         return _plane
 
 

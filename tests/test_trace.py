@@ -8,6 +8,7 @@ over a real FUSE mount, `profile --trace` Chrome JSON output, the
 """
 
 import errno
+import contextlib
 import json
 import os
 import threading
@@ -333,6 +334,565 @@ def test_stage_metrics_snapshot_shape(vfs):
     assert snap["chunk.load.fetch"]["count"] >= 1
     assert snap["chunk.load.fetch"]["sum_seconds"] >= 0.0
     assert "chunk.read.total" in snap
+
+
+# -- the scan path times itself (ISSUE 25) -----------------------------------
+
+def _scan_volume(tmp_path, blocks=9, block_kib=64):
+    """A small file:// volume with `blocks` live blocks, two of them equal."""
+    from juicefs_tpu.cmd import main
+
+    from test_cmd import _open_vfs, _write_file
+
+    meta_url = f"sqlite3://{tmp_path}/meta.db"
+    assert main(["format", meta_url, "scanvol", "--storage", "file",
+                 "--bucket", str(tmp_path / "blobs"),
+                 "--block-size", str(block_kib)]) == 0
+    v = _open_vfs(meta_url, tmp_path)
+    bs = block_kib << 10
+    _write_file(v, b"a.bin", os.urandom(bs * (blocks - 2) - 7))
+    _write_file(v, b"b.bin", b"z" * (2 * bs))
+    v.close()
+    return meta_url
+
+
+def _gc(capsys, meta_url, *extra):
+    from juicefs_tpu.cmd import main
+
+    capsys.readouterr()
+    assert main(["gc", meta_url, "--dedup", "--hash-backend", "xla",
+                 "--threads", "4", *extra]) == 0
+    return json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+
+
+def _names(evs):
+    return [".".join(x for x in (e["layer"], e["op"], e.get("stage", "")) if x)
+            for e in evs]
+
+
+def test_gc_dedup_scan_is_one_trace_tree(tmp_path, capsys):
+    """cmd.gc -> {open, list, index_load, readhash -> {chunk.fetch.wait,
+    chunk.load.fetch -> object.get, tpu.hash.dispatch -> {pack, h2d,
+    enqueue}, tpu.hash.drain}, backfill, group, reconcile}: one trace, the
+    pool threads' spans included."""
+    meta_url = _scan_volume(tmp_path)
+    with _reader() as r:
+        stats = _gc(capsys, meta_url)
+        evs = r.drain()
+    assert stats["hashed_now"] == 9
+    by_id = {e["id"]: e for e in evs}
+    name_of = dict(zip((e["id"] for e in evs), _names(evs)))
+
+    def parent(e):
+        return name_of.get(e["parent"])
+
+    root = next(e for e in evs if name_of[e["id"]] == "cmd.gc")
+    assert root["parent"] == 0 and root["backend"] == "xla"
+    assert (root["blocks"], root["hashed_now"]) == (9, 9)
+    assert {e["trace"] for e in evs} == {root["trace"]}
+    stages = {name_of[e["id"]] for e in evs if e["parent"] == root["id"]}
+    assert {"cmd.gc.open", "cmd.gc.list", "cmd.gc.index_load",
+            "cmd.gc.readhash", "cmd.gc.backfill", "cmd.gc.group",
+            "cmd.gc.reconcile"} <= stages
+    for e in evs:
+        name = name_of[e["id"]]
+        if name in ("chunk.fetch.wait", "chunk.load.fetch",
+                    "tpu.hash.dispatch", "tpu.hash.drain"):
+            assert parent(e) == "cmd.gc.readhash", name
+        elif name in ("tpu.hash.pack", "tpu.hash.h2d", "tpu.hash.enqueue"):
+            assert parent(e) == "tpu.hash.dispatch", name
+        elif name == "object.get":
+            assert parent(e) == "chunk.load.fetch"
+    counts = {n: _names(evs).count(n) for n in set(_names(evs))}
+    assert counts["chunk.fetch.wait"] == counts["chunk.load.fetch"] == 9
+    assert counts["object.get"] == 9
+    assert (counts["tpu.hash.pack"] == counts["tpu.hash.h2d"]
+            == counts["tpu.hash.enqueue"] == counts["tpu.hash.dispatch"] == 1)
+    # the GETs ran on pool threads and still carry the scan's trace id
+    fetch_tids = {e["tid"] for e in evs
+                  if name_of[e["id"]] == "chunk.load.fetch"}
+    assert root["tid"] not in fetch_tids
+    pack = next(e for e in evs if name_of[e["id"]] == "tpu.hash.pack")
+    assert pack["batch"] == 9 and pack["padded_bytes"] >= pack["bytes"] > 0
+    h2d = next(e for e in evs if name_of[e["id"]] == "tpu.hash.h2d")
+    assert h2d["bytes"] == pack["padded_bytes"] and "sharded" in h2d
+    assert all("ready" in e for e in evs
+               if name_of[e["id"]] == "chunk.fetch.wait")
+    assert by_id[pack["parent"]]["batch"] == 9
+
+
+def test_stage_seconds_are_the_spans_durations(tmp_path, capsys):
+    meta_url = _scan_volume(tmp_path)
+    with _reader() as r:
+        stats = _gc(capsys, meta_url)
+        evs = r.drain()
+    dur = dict(zip(_names(evs), (e["dur"] for e in evs)))
+    ss = stats["stage_seconds"]
+    assert set(ss) == {"index_load", "get", "get_threads", "hash",
+                       "meta_backfill", "dup_group", "readhash"}
+    assert ss["index_load"] == dur["cmd.gc.index_load"]
+    assert ss["readhash"] == dur["cmd.gc.readhash"]
+    assert ss["meta_backfill"] == dur["cmd.gc.backfill"]
+    assert ss["dup_group"] == dur["cmd.gc.group"]
+    assert ss["hash"] == pytest.approx(ss["readhash"] - ss["get"], abs=2e-6)
+    # to the microsecond, not the millisecond: a sub-millisecond stage shows
+    assert 0 < ss["index_load"] < 0.5 and ss["index_load"] != round(
+        ss["index_load"], 3)
+    # and with nobody listening the same keys come from the timing-only shim
+    assert not global_tracer().active
+    quiet = _gc(capsys, meta_url)["stage_seconds"]
+    assert set(quiet) == set(ss) and quiet["index_load"] > 0
+    assert "spans" not in stats
+
+
+def test_fetch_waits_ready_plus_blocked_is_blocks_fetched(tmp_path, capsys):
+    ready, blocked = (counter("juicefs_fetch_waits", "1"),
+                      counter("juicefs_fetch_waits", "0"))
+    waits = hist_count("juicefs_tpu_stage_seconds", "chunk", "fetch", "wait")
+    r0, b0 = ready.value, blocked.value
+    stats = _gc(capsys, _scan_volume(tmp_path, blocks=13))
+    assert stats["hashed_now"] == 13
+    assert (ready.value - r0) + (blocked.value - b0) == 13
+    assert hist_count("juicefs_tpu_stage_seconds",
+                      "chunk", "fetch", "wait") == waits + 13
+
+
+def test_fetch_wait_says_whether_the_block_was_there():
+    from concurrent.futures import ThreadPoolExecutor
+
+    from juicefs_tpu.chunk.parallel import fetch_ordered
+
+    ready, blocked = (counter("juicefs_fetch_waits", "1"),
+                      counter("juicefs_fetch_waits", "0"))
+    r0, b0 = ready.value, blocked.value
+    gate = threading.Event()
+
+    def fn(i):
+        if i == 0:
+            assert gate.wait(5.0)
+        return i
+
+    with ThreadPoolExecutor(4) as pool, _reader() as r:
+        with global_tracer().span("test", "consumer") as outer:
+            gen = fetch_ordered(range(3), fn, pool, window=3)
+            threading.Timer(0.05, gate.set).start()
+            assert [i for i, _ in gen] == [0, 1, 2]
+        evs = r.drain()
+    # the head was not there (the consumer waited for its gate); the two
+    # behind it had long finished when the consumer came for them
+    assert (blocked.value - b0, ready.value - r0) == (1, 2)
+    waits = [e for e in evs if e["layer"] == "chunk" and e["op"] == "fetch"]
+    assert [e["ready"] for e in waits] == [False, True, True]
+    assert all(e["parent"] == outer.span_id for e in waits)
+
+
+def test_pool_thread_spans_hang_off_the_submitting_span():
+    """fetch_ordered carries the consumer's span ref onto the pool thread:
+    spans the worker opens there join the consumer's trace."""
+    from concurrent.futures import ThreadPoolExecutor
+
+    from juicefs_tpu.chunk.parallel import fetch_ordered
+
+    tr = global_tracer()
+
+    def fn(i):
+        with tr.span("object", "get"):
+            return threading.get_native_id()
+
+    with ThreadPoolExecutor(2) as pool, _reader() as r:
+        with tr.span("cmd", "gc", stage="readhash") as outer:
+            tids = {t for _, t in fetch_ordered(range(6), fn, pool, 2)}
+        evs = r.drain()
+    gets = [e for e in evs if e["layer"] == "object"]
+    assert len(gets) == 6 and threading.get_native_id() not in tids
+    assert all(e["trace"] == outer.trace_id and e["parent"] == outer.span_id
+               for e in gets)
+    assert {e["tid"] for e in gets} == tids
+
+
+def test_span_summary_self_time_with_overlapping_children_on_two_threads():
+    from juicefs_tpu.metric.trace import span_summary
+
+    def ev(id_, parent, ts, dur, op, tid=1):
+        return {"id": id_, "parent": parent, "trace": 1, "tid": tid,
+                "ts": ts, "dur": dur, "layer": "t", "op": op}
+
+    evs = [
+        ev(1, 0, 100.0, 10.0, "root"),
+        ev(2, 1, 101.0, 4.0, "a"),           # [101, 105] on the root's thread
+        ev(3, 1, 103.0, 4.0, "b", tid=2),    # [103, 107] on a pool thread
+        ev(4, 1, 108.0, 5.0, "b", tid=2),    # [108, 113]: outlives the root
+        ev(5, 2, 102.0, 1.0, "leaf"),
+    ]
+    got = span_summary(evs)
+    # children cover [101, 107] and [108, 110] of [100, 110]: 8 of 10
+    assert got["jfs.t.root"] == {"n": 1, "total_s": 10.0, "self_s": 2.0}
+    assert got["jfs.t.a"] == {"n": 1, "total_s": 4.0, "self_s": 3.0}
+    assert got["jfs.t.b"] == {"n": 2, "total_s": 9.0, "self_s": 9.0}
+    assert got["jfs.t.leaf"]["self_s"] == 1.0
+
+
+def test_gc_trace_flag_writes_a_loadable_chrome_trace(tmp_path, capsys):
+    meta_url = _scan_volume(tmp_path)
+    out = tmp_path / "tr"
+    stats = _gc(capsys, meta_url, "--trace", str(out))
+    assert not global_tracer().active  # the reader went with the command
+    chrome = json.load(open(out / "juicefs-trace.json"))
+    evs = chrome["traceEvents"]
+    names = {f"{e['cat']}.{e['name']}" for e in evs}
+    assert {"cmd.gc", "cmd.gc:readhash", "tpu.hash:pack", "tpu.hash:h2d",
+            "chunk.fetch:wait", "object.get"} <= names
+    for e in evs:
+        assert e["ph"] == "X" and e["dur"] > 0 and e["pid"] == 1
+    root = next(e for e in evs if e["cat"] == "cmd" and e["name"] == "gc")
+    # a lane a thread: the stages nest inside the root on its lane, the
+    # GETs have lanes of their own
+    assert {e["tid"] for e in evs if e["cat"] == "cmd"} == {root["tid"]}
+    assert any(e["tid"] != root["tid"] for e in evs if e["cat"] == "chunk"
+               and e["name"] == "load:fetch")
+    # the stats line gains per-span totals and self times
+    spans = stats["spans"]
+    assert spans["jfs.cmd.gc"]["n"] == 1
+    assert spans["jfs.tpu.hash.dispatch"]["self_s"] <= spans[
+        "jfs.tpu.hash.dispatch"]["total_s"]
+    assert spans["jfs.cmd.gc.readhash"]["total_s"] == stats[
+        "stage_seconds"]["readhash"]
+    # the root's children follow one another on its thread: the stages,
+    # and the plane coming up if this process's first scan is this one
+    kids = [e for e in evs if e["args"]["parent_id"] == root["args"]["span_id"]]
+    assert {e["name"] for e in kids} >= {
+        "gc:open", "gc:list", "gc:index_load", "gc:readhash", "gc:backfill",
+        "gc:group", "gc:reconcile"}
+    assert spans["jfs.cmd.gc"]["self_s"] == pytest.approx(
+        spans["jfs.cmd.gc"]["total_s"] - sum(e["dur"] for e in kids) / 1e6,
+        abs=1e-4)
+    # the device backend's profiler trace lies beside it, same directory
+    assert list(out.glob("plugins/profile/*/*.xplane.pb"))
+
+
+def test_gc_without_the_flag_attaches_nothing(tmp_path, capsys, monkeypatch):
+    """No --trace: no reader, no TraceAnnotation, no profiler session."""
+    import jax.profiler
+
+    tr = global_tracer()
+    opened = []
+    monkeypatch.setattr(tr, "open_reader",
+                        lambda *a, **k: opened.append(a))
+
+    def refuse(*a, **k):
+        raise AssertionError("the profiler is --trace's")
+
+    monkeypatch.setattr(jax.profiler, "start_trace", refuse)
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", refuse)
+    stats = _gc(capsys, _scan_volume(tmp_path))
+    assert stats["hashed_now"] == 9 and "spans" not in stats
+    assert opened == [] and not tr.active
+    assert tr.annotate is None
+
+
+def test_host_hash_scan_with_trace_starts_no_profiler(tmp_path, capsys,
+                                                     monkeypatch):
+    import jax.profiler
+
+    from juicefs_tpu.cmd import main
+
+    def refuse(*a, **k):
+        raise AssertionError("a host-hash scan must not bring JAX up")
+
+    monkeypatch.setattr(jax.profiler, "start_trace", refuse)
+    # no profiler session, so nobody to annotate for
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", refuse)
+    out = tmp_path / "tr"
+    capsys.readouterr()
+    assert main(["gc", _scan_volume(tmp_path), "--dedup", "--hash-backend",
+                 "cpu", "--trace", str(out)]) == 0
+    assert global_tracer().annotate is None
+    stats = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert stats["backend"] == "cpu" and "jfs.cmd.gc.readhash" in stats["spans"]
+    assert "jfs.tpu.hash.pack" not in stats["spans"]  # nothing is packed
+    assert os.listdir(out) == ["juicefs-trace.json"]
+
+
+def test_span_annotates_only_through_the_hook_and_only_live_spans(
+        monkeypatch):
+    """The tracer knows no profiler: a `Span` opens what `annotate` gives
+    it, a reader alone annotates nothing, and with no reader the timing-
+    only span never asks."""
+    tr = global_tracer()
+    seen = []
+
+    class Annotation:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            seen.append(("enter", self.name))
+
+        def __exit__(self, *a):
+            seen.append(("exit", self.name))
+
+    h = stage_hist("testlayer", "ann", "s")
+    assert tr.annotate is None
+    with _reader():
+        with tr.span("testlayer", "ann", stage="s", hist=h):
+            pass
+    assert seen == []
+    monkeypatch.setattr(tr, "annotate", Annotation)
+    with tr.span("testlayer", "ann", stage="s", hist=h):
+        pass
+    assert seen == []
+    with _reader():
+        with tr.span("testlayer", "ann", stage="s", hist=h):
+            with tr.span("testlayer", "inner"):
+                pass
+    assert seen == [("enter", "jfs.testlayer.ann.s"),
+                    ("enter", "jfs.testlayer.inner"),
+                    ("exit", "jfs.testlayer.inner"),
+                    ("exit", "jfs.testlayer.ann.s")]
+
+
+def test_gc_trace_sets_the_hook_for_the_profiler_session_alone(
+        tmp_path, capsys, monkeypatch):
+    """`gc --trace` on a device backend: the hook is the profiler's
+    annotation from `start_trace` to `stop_trace` and None again after."""
+    import jax.profiler
+
+    from juicefs_tpu.cmd import main
+
+    tr = global_tracer()
+    hook, names = [], set()
+
+    class Annotation(contextlib.nullcontext):
+        def __init__(self, name):
+            super().__init__()
+            names.add(name)
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Annotation)
+    monkeypatch.setattr(
+        jax.profiler, "start_trace",
+        lambda *a, **k: hook.append(("start", tr.annotate)))
+    monkeypatch.setattr(
+        jax.profiler, "stop_trace",
+        lambda: hook.append(("stop", tr.annotate)))
+    capsys.readouterr()
+    assert main(["gc", _scan_volume(tmp_path), "--dedup", "--hash-backend",
+                 "xla", "--trace", str(tmp_path / "tr")]) == 0
+    stats = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert hook == [("start", None), ("stop", None)]
+    assert tr.annotate is None and not tr.active
+    # what opened while the session ran was annotated, pool threads' GETs
+    # too; the root and `open` came before the backend was known
+    assert set(stats["spans"]) - names <= {"jfs.cmd.gc", "jfs.cmd.gc.open",
+                                           "jfs.tpu.device.init"}
+    assert {"jfs.tpu.hash.pack", "jfs.object.get",
+            "jfs.chunk.fetch.wait"} <= names
+    assert "jfs.cmd.gc" not in names and "jfs.cmd.gc.open" not in names
+
+
+def test_unbounded_reader_keeps_everything_and_a_ring_its_newest():
+    tr = global_tracer()
+    ring, whole = ("test", "ring"), ("test", "whole")
+    tr.open_reader(ring, max_events=4)
+    tr.open_reader(whole, max_events=None)
+    try:
+        for i in range(10):
+            with tr.span("testlayer", "drop", n=i):
+                pass
+        kept = tr.read(ring, 1 << 20).decode().splitlines()
+        assert [json.loads(l)["n"] for l in kept] == [6, 7, 8, 9]
+        assert len(tr.read(whole, 1 << 20).decode().splitlines()) == 10
+    finally:
+        tr.close_reader(ring)
+        tr.close_reader(whole)
+    assert not tr.active
+
+
+def test_timed_span_keeps_its_duration():
+    h = stage_hist("testlayer", "dur", "t")
+    before = h.sum
+    with global_tracer().span("testlayer", "dur", stage="t", hist=h) as sp:
+        time.sleep(0.002)
+    assert not sp.active and sp.dur >= 0.002
+    assert h.sum == pytest.approx(before + sp.dur)
+    with _reader():
+        with global_tracer().span("testlayer", "dur", stage="t", hist=h) as sp:
+            pass
+    assert sp.active and sp.dur >= 0
+
+
+def test_chrome_event_lane_is_the_thread():
+    from juicefs_tpu.cmd.stats import _chrome_event
+
+    ev = {"ts": 1.0, "dur": 0.5, "trace": 7, "id": 9, "parent": 8,
+          "tid": 4242, "layer": "chunk", "op": "fetch", "stage": "wait",
+          "ready": True}
+    got = _chrome_event(ev)
+    assert got["tid"] == 4242 and got["name"] == "fetch:wait"
+    assert got["args"] == {"ready": True, "span_id": 9, "parent_id": 8,
+                           "trace_id": 7}
+    del ev["tid"]  # an event recorded before threads were noted
+    assert _chrome_event(ev)["tid"] == 7
+
+
+def test_pack_span_says_what_was_copied_and_what_was_shipped():
+    """`bytes` is what `juicefs_tpu_hash_bytes` takes, `padded_bytes` what
+    `juicefs_tpu_h2d_bytes` takes: the padded share needs no counter of
+    its own."""
+    from juicefs_tpu.tpu.pipeline import HashPipeline, PipelineConfig
+
+    hashed, h2d = counter("juicefs_tpu_hash_bytes"), counter(
+        "juicefs_tpu_h2d_bytes")
+    b0, h0 = hashed.value, h2d.value
+    packs = hist_count("juicefs_tpu_stage_seconds", "tpu", "hash", "pack")
+    pipe = HashPipeline(PipelineConfig(backend="xla", batch_blocks=4,
+                                       pad_lanes=1))
+    sizes = (1, 100, 65536, 4000, 17)
+    with _reader() as r:
+        pipe.hash_blocks([os.urandom(n) for n in sizes])
+        evs = [e for e in r.drain() if e.get("stage") == "pack"]
+    assert [e["batch"] for e in evs] == [4, 1]
+    assert sum(e["bytes"] for e in evs) == sum(sizes) == hashed.value - b0
+    assert sum(e["padded_bytes"] for e in evs) == h2d.value - h0
+    assert all(e["padded_bytes"] >= e["bytes"] for e in evs)
+    assert hist_count("juicefs_tpu_stage_seconds",
+                      "tpu", "hash", "pack") == packs + 2  # 4 + 1
+
+
+def test_hash_packed_gets_h2d_and_enqueue_without_a_pack():
+    """The indexer's entry packs for itself: its dispatch span has the
+    plane's h2d and enqueue below it and no pack."""
+    from juicefs_tpu.tpu.jth256 import jth256, pack_blocks
+    from juicefs_tpu.tpu.pipeline import HashPipeline, PipelineConfig
+
+    pipe = HashPipeline(PipelineConfig(backend="xla", pad_lanes=1))
+    blocks = [os.urandom(n) for n in (5, 65536, 300, 1)]
+    with _reader() as r:
+        got = pipe.hash_packed(*pack_blocks(blocks, pad_lanes=1))
+        evs = r.drain()
+    assert got == [jth256(b) for b in blocks]
+    names = _names(evs)
+    assert sorted(names) == ["tpu.hash.dispatch", "tpu.hash.drain",
+                             "tpu.hash.enqueue", "tpu.hash.h2d"]
+    dispatch = evs[names.index("tpu.hash.dispatch")]
+    assert {evs[names.index(n)]["parent"] for n in
+            ("tpu.hash.h2d", "tpu.hash.enqueue")} == {dispatch["id"]}
+
+
+def test_plane_construction_is_the_device_init_span():
+    from juicefs_tpu.tpu import sharding
+
+    inits = hist_count("juicefs_tpu_stage_seconds", "tpu", "device", "init")
+    sharding._reset_plane_for_tests()
+    try:
+        with _reader() as r:
+            plane = sharding.get_plane()
+            assert sharding.get_plane() is plane  # once a process
+            evs = r.drain()
+    finally:
+        sharding._reset_plane_for_tests()
+    assert _names(evs) == ["tpu.device.init"]
+    assert evs[0]["devices"] == plane.snapshot()["devices"]
+    assert hist_count("juicefs_tpu_stage_seconds",
+                      "tpu", "device", "init") == inits + 1
+
+
+class _Monitoring:
+    """jax.monitoring's two registration calls, for count_compiles."""
+
+    def register_event_listener(self, fn):
+        self.on_event = fn
+
+    def register_event_duration_secs_listener(self, fn):
+        self.on_duration = fn
+
+
+def test_compiles_are_counted_by_where_the_program_came_from():
+    from juicefs_tpu.tpu import device
+
+    built, cached = (counter("juicefs_tpu_compiles", "built"),
+                     counter("juicefs_tpu_compiles", "cache"))
+    secs = global_registry()._metrics["juicefs_tpu_compile_seconds"]
+    b0, c0 = built.value, cached.value
+    sb, sc = secs.labels("built").sum, secs.labels("cache").sum
+    mon = _Monitoring()
+    device.count_compiles(mon)
+    compile_event = "/jax/core/compile/backend_compile_duration"
+    mon.on_duration(compile_event, 1.5, fun_name="jth256_hash")
+    mon.on_event("/jax/compilation_cache/compile_requests_use_cache")
+    mon.on_event("/jax/compilation_cache/cache_hits")
+    mon.on_duration("/jax/compilation_cache/cache_retrieval_time_sec", 0.2)
+    mon.on_duration(compile_event, 0.25, fun_name="jth256_hash")
+    mon.on_duration(compile_event, 2.0)  # the hit was used up: built again
+    # a hit seen on another thread says nothing about this one's program
+    t = threading.Thread(
+        target=mon.on_event, args=("/jax/compilation_cache/cache_hits",))
+    t.start()
+    t.join()
+    mon.on_duration(compile_event, 0.5)
+    # a hit that no duration followed (an AOT or other compile path) is
+    # not carried into the next request, which the compiler builds
+    mon.on_event("/jax/compilation_cache/compile_requests_use_cache")
+    mon.on_event("/jax/compilation_cache/cache_hits")
+    mon.on_event("/jax/compilation_cache/compile_requests_use_cache")
+    mon.on_duration(compile_event, 1.0)
+    assert (built.value - b0, cached.value - c0) == (4, 1)
+    assert secs.labels("built").sum - sb == pytest.approx(5.0)
+    assert secs.labels("cache").sum - sc == pytest.approx(0.25)
+
+
+def test_a_real_compile_reaches_the_counter():
+    import jax
+    import numpy as np
+
+    import juicefs_tpu.tpu  # noqa: F401  (registers the listener)
+
+    built = counter("juicefs_tpu_compiles", "built")
+    secs = global_registry()._metrics["juicefs_tpu_compile_seconds"]
+    b0, n0 = built.value, secs.labels("built").total
+    jax.jit(lambda x: x * 3 + len("a fresh program"))(np.arange(7))
+    # the test run has the persistent cache off: nothing can be loaded
+    assert built.value == b0 + 1 and secs.labels("built").total == n0 + 1
+
+
+@pytest.mark.parametrize("program,name", [
+    ("hash_jax:hash_packed_jax", "jth256_hash"),
+    ("hash_jax:_hash_packed_pallas_impl", "jth256_hash_pallas"),
+    ("dedup:dedup_scan_jax", "dedup_scan"),
+    ("dedup:scan_step_jax", "jth256_scan"),
+    ("sharding:sharded_hash_step", "jth256_hash_sharded"),
+    ("sharding:sharded_scan_step", "jth256_scan_sharded"),
+    ("sharding:sharded_estimate_step", "compress_estimate_sharded"),
+])
+def test_jitted_programs_have_fixed_names(program, name):
+    """XLA Modules events in a profiler trace read `jit_<name>(...)`."""
+    import importlib
+
+    import numpy as np
+
+    from juicefs_tpu.tpu import sharding
+    from juicefs_tpu.tpu.jth256 import pack_blocks
+
+    module, attr = program.split(":")
+    fn = getattr(importlib.import_module("juicefs_tpu.tpu." + module), attr)
+    words, counts, lengths = pack_blocks(
+        [os.urandom(n) for n in (9, 70000, 1, 65536)], pad_lanes=2)
+    if module == "sharding":
+        mesh = sharding.make_mesh(n_data=2, n_lane=2)
+        placed = sharding.shard_batch(mesh, words, counts, lengths)
+        args = placed[:2] if "estimate" in attr else placed
+        text = fn(mesh).lower(*args).as_text(debug_info=True)
+        assert "lane_all_gather" in text or "estimate" in attr
+    elif attr == "dedup_scan_jax":
+        text = fn.lower(np.zeros((4, 8), np.uint32)).as_text()
+    elif "pallas" in attr:
+        text = fn.lower(words, counts, lengths, np.zeros((1,), np.uint32),
+                        interpret=True).as_text(debug_info=True)
+    else:
+        text = fn.lower(words, counts, lengths).as_text(debug_info=True)
+    assert f"module @jit_{name} " in text
+    if name.startswith("jth256_"):
+        for scope in ("row_chain", "lane_fold", "lane_combine", "finish"):
+            assert scope in text or "pallas" in name and scope == "row_chain"
 
 
 # -- accesslog identity (satellite: real uid/gid/pid) ------------------------
