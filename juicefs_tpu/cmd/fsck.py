@@ -84,7 +84,10 @@ def run(args) -> int:
     if args.verify_data or args.hash_index:
         from ..tpu.jth256 import digest_hex
         from ..tpu.pipeline import HashPipeline, PipelineConfig
+        from ..utils.malloc import keep_freed_blocks
 
+        # a bulk scan from here on, as `gc --dedup` is (utils/malloc.py)
+        keep_freed_blocks()
         backend = args.hash_backend or fmt.hash_backend
         pipe = HashPipeline(
             PipelineConfig(backend=backend, pad_lanes=max(1, bs // 65536))

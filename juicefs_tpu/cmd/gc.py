@@ -22,6 +22,7 @@ import contextlib
 import json
 
 from ..chunk.cached_store import block_key, parse_block_key
+from ..metric import global_registry
 from ..metric.trace import global_tracer, span_summary, stage_hist
 from ..qos import IOClass
 from ..tpu.device import HASH_BACKENDS
@@ -39,6 +40,11 @@ _H_BACKFILL = stage_hist("cmd", "gc", "backfill")
 _H_GROUP = stage_hist("cmd", "gc", "group")
 _H_WRITE_INDEX = stage_hist("cmd", "gc", "write_index")
 _H_RECONCILE = stage_hist("cmd", "gc", "reconcile")
+_SCAN_MINOR_FAULTS = global_registry().counter(
+    "juicefs_scan_minor_faults",
+    "Minor page faults of this process (getrusage ru_minflt) over the "
+    "read+hash stage of dedup scans: what first-touching host memory costs",
+)
 
 
 def add_parser(sub):
@@ -141,8 +147,13 @@ def _gc(args, trace: "_ScanTrace | None", root) -> dict | None:
         on_device = False
         if args.dedup:
             from ..tpu.device import resolve_backend
+            from ..utils.malloc import keep_freed_blocks
 
             on_device = resolve_backend(backend) != "cpu"
+            # this process is a bulk scan from here on: the allocator
+            # recycles block-sized buffers (utils/malloc.py); before the
+            # store is built, so before the first GET
+            keep_freed_blocks()
         # meta-attached store: dedup-scan reads of PUT-elided blocks
         # resolve through the content-ref plane (ISSUE 5). No indexer: gc
         # backfills digest rows itself through dedup_scan's own pipeline.
@@ -255,6 +266,7 @@ def dedup_scan(meta, store, live: dict[str, int], backend: str,
     dispatch; results arrive in input order, so digests and index rows
     are byte-identical to the old serial walk.
     """
+    import resource
     import time as _time
 
     from ..chunk.parallel import FetchStats, fetch_ordered
@@ -297,6 +309,7 @@ def dedup_scan(meta, store, live: dict[str, int], backend: str,
         )
 
     backfill = []
+    faults0 = resource.getrusage(resource.RUSAGE_SELF).ru_minflt
     with _TR.span("cmd", "gc", stage="readhash",
                   hist=_H_READHASH) as sp_readhash:
         for key, digest in pipe.hash_stream(blocks()):
@@ -305,6 +318,8 @@ def dedup_scan(meta, store, live: dict[str, int], backend: str,
             backfill.append((sid, indx, bsize, digest))
         if sp_readhash.active:
             sp_readhash.set(blocks=len(backfill), window=window)
+    _SCAN_MINOR_FAULTS.inc(
+        resource.getrusage(resource.RUSAGE_SELF).ru_minflt - faults0)
     with _TR.span("cmd", "gc", stage="backfill",
                   hist=_H_BACKFILL) as sp_backfill:
         if backfill:

@@ -143,7 +143,8 @@ def digest_hex(digest: bytes) -> str:
 # ---------------------------------------------------------------------------
 
 def pack_blocks(
-    blocks: Sequence[bytes], pad_lanes: int | None = None
+    blocks: Sequence[bytes], pad_lanes: int | None = None,
+    out: np.ndarray | None = None,
 ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
     """Pack a batch to fixed shape for a single compiled program.
 
@@ -151,17 +152,42 @@ def pack_blocks(
     lengths (B,) uint32). Blocks shorter than M lanes are zero-padded;
     lane_counts masks the padded lanes out of the combine step, so padding
     never changes a digest.
+
+    With `out` (a C-contiguous, writeable `<u4` array of (>= B, M, 128,
+    128): a buffer its caller keeps) the blocks are written into its first
+    B rows and `words` is `out[:B]`: byte for byte what the call without
+    `out` returns, whatever the buffer held before.
     """
     counts = [max(1, -(-len(b) // LANE_BYTES)) for b in blocks]
     m = pad_lanes or max(counts, default=1)
     if max(counts, default=1) > m:
         raise ValueError(f"block needs {max(counts)} lanes > pad_lanes={m}")
-    out = np.zeros((len(blocks), m, ROWS, COLS), dtype=np.uint32)
-    for i, b in enumerate(blocks):
-        w = pack_block(b)
-        out[i, : w.shape[0]] = w
+    if out is None:
+        words = np.zeros((len(blocks), m, ROWS, COLS), dtype=np.uint32)
+        for i, b in enumerate(blocks):
+            w = pack_block(b)
+            words[i, : w.shape[0]] = w
+    else:
+        if (not isinstance(out, np.ndarray) or out.dtype != np.dtype("<u4")
+                or not out.flags.c_contiguous or not out.flags.writeable
+                or out.ndim != 4 or out.shape[0] < len(blocks)
+                or out.shape[1:] != (m, ROWS, COLS)):
+            raise ValueError(
+                f"out must be a C-contiguous writeable <u4 array of "
+                f"(>= {len(blocks)}, {m}, {ROWS}, {COLS})")
+        longest = max(map(len, blocks), default=0)
+        if longest > BLOCK_BYTES:  # what pack_block refuses
+            raise ValueError(f"block larger than {BLOCK_BYTES}: {longest}")
+        words = out[: len(blocks)]
+        rows = words.reshape(len(blocks), m * LANE_BYTES // 4).view(np.uint8)
+        for i, b in enumerate(blocks):
+            # the block's bytes as they lie, then zeros to the end of the
+            # row: the padding of its last lane and every lane it does not
+            # use, which the buffer's last batch may have written
+            rows[i, : len(b)] = np.frombuffer(b, dtype=np.uint8)
+            rows[i, len(b):] = 0
     lengths = np.array([len(b) for b in blocks], dtype=np.uint32)
-    return out, np.array(counts, dtype=np.int32), lengths
+    return words, np.array(counts, dtype=np.int32), lengths
 
 
 def hash_packed_np(

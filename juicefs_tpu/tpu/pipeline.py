@@ -20,6 +20,7 @@ resolved by tpu/device.py; a device backend that cannot initialise raises
 
 from __future__ import annotations
 
+import inspect
 import time
 from dataclasses import dataclass
 from typing import Iterable, Iterator, Optional
@@ -46,6 +47,12 @@ _HASH_BYTES = _reg.counter(
 _H2D_BYTES = _reg.counter(
     "juicefs_tpu_h2d_bytes",
     "Host-to-device bytes shipped as packed hash batches",
+)
+_PACK_FRESH_BYTES = _reg.counter(
+    "juicefs_tpu_pack_fresh_bytes",
+    "Packed bytes of hash batches written into a pack buffer on its first "
+    "use (hash_stream had just made it): the part of juicefs_tpu_h2d_bytes "
+    "whose pack paid first-touch page faults",
 )
 _BATCH_BLOCKS = _reg.histogram(
     "juicefs_tpu_batch_blocks", "Blocks per dispatched hash batch",
@@ -127,9 +134,31 @@ class HashPipeline:
         self, items: Iterable[tuple[str, bytes]]
     ) -> Iterator[tuple[str, bytes]]:
         cfg = self.config
-        pending: list[tuple[list[str], object, float]] = []
+        pending: list[tuple[list[str], object, float, object]] = []
         keys: list[str] = []
         blocks: list[bytes] = []
+        # Pack buffers this stream keeps (docs/ARCHITECTURE.md "The scan's
+        # host memory"): a batch packs into a free one, or fresh, and the
+        # fresh array becomes one. Only drain() gives a buffer back, once
+        # its batch's digests are here: until then the device may read the
+        # host words (the CPU backend's device_put can alias them, a TPU's
+        # may still be copying when it returns). At most
+        # max_inflight_batches of them, none outliving the stream.
+        free: list[np.ndarray] = []
+        # the module's name is a seam others stand functions in; under one
+        # that takes no `out` every batch packs fresh, as before
+        params = inspect.signature(pack_blocks).parameters.values()
+        keeps = any(p.name == "out" or p.kind is p.VAR_KEYWORD for p in params)
+
+        def pack(blocks):
+            # a buffer has the rows of its first batch: only a stream's
+            # last batch is shorter than batch_blocks, and nothing follows
+            if free:
+                buf = free.pop()
+                return pack_blocks(blocks, pad_lanes=cfg.pad_lanes,
+                                   out=buf), buf, False
+            packed = pack_blocks(blocks, pad_lanes=cfg.pad_lanes)
+            return packed, packed[0] if keeps else None, True
 
         def dispatch():
             nonlocal keys, blocks
@@ -148,25 +177,28 @@ class HashPipeline:
                     # and no device transfer (h2d counter stays untouched).
                     from .. import native
 
-                    pending.append((keys, native.jth256_batch(blocks), t0))
+                    pending.append(
+                        (keys, native.jth256_batch(blocks), t0, None))
                 else:
                     with _TR.span("tpu", "hash", stage="pack",
                                   hist=_H_PACK) as psp:
-                        words, counts, lengths = pack_blocks(
-                            blocks, pad_lanes=cfg.pad_lanes)
+                        (words, counts, lengths), buf, fresh = pack(blocks)
                         if psp.active:
                             psp.set(batch=len(blocks), bytes=nbytes,
-                                    padded_bytes=words.nbytes)
+                                    padded_bytes=words.nbytes,
+                                    fresh=int(fresh))
+                    if fresh:
+                        _PACK_FRESH_BYTES.inc(words.nbytes)
                     _H2D_BYTES.inc(words.nbytes)
                     pending.append(
-                        (keys, self._fn(words, counts, lengths), t0))
+                        (keys, self._fn(words, counts, lengths), t0, buf))
             _BATCH_BLOCKS.observe(len(blocks))
             _BLOCKS_HASHED.inc(len(blocks))
             _HASH_BYTES.inc(nbytes)
             keys, blocks = [], []
 
         def drain(batch) -> Iterator[tuple[str, bytes]]:
-            bkeys, out, t0 = batch
+            bkeys, out, t0, buf = batch
             if isinstance(out, list):
                 digests = out
             else:
@@ -178,6 +210,8 @@ class HashPipeline:
                         sp.set(batch=len(bkeys),
                                backend=self.config.backend)
                     digests = digests_to_bytes(np.asarray(out))
+                if buf is not None:
+                    free.append(buf)  # its batch has been read
                 self._note_first_batch(t0)
             return zip(bkeys, digests[: len(bkeys)])
 

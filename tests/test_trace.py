@@ -758,6 +758,26 @@ def test_pack_span_says_what_was_copied_and_what_was_shipped():
                       "tpu", "hash", "pack") == packs + 2  # 4 + 1
 
 
+@pytest.mark.parametrize("depth,fresh", [(1, [1, 0, 0, 0]), (2, [1, 1, 0, 0]),
+                                         (5, [1, 1, 1, 1])])
+def test_pack_span_says_whether_its_buffer_was_new(depth, fresh):
+    """`fresh` = 1 on the pack that wrote into memory on its first use (no
+    kept buffer was free); those packs' `padded_bytes` are what
+    `juicefs_tpu_pack_fresh_bytes` counts."""
+    from juicefs_tpu.tpu.pipeline import HashPipeline, PipelineConfig
+
+    counted = counter("juicefs_tpu_pack_fresh_bytes")
+    c0 = counted.value
+    pipe = HashPipeline(PipelineConfig(
+        backend="xla", batch_blocks=2, pad_lanes=1,
+        max_inflight_batches=depth))
+    with _reader() as r:
+        pipe.hash_blocks([os.urandom(n) for n in (5, 70, 900, 1, 65536, 3, 8)])
+        evs = [e for e in r.drain() if e.get("stage") == "pack"]
+    assert [e["fresh"] for e in evs] == fresh
+    assert sum(e["padded_bytes"] for e in evs if e["fresh"]) == counted.value - c0
+
+
 def test_hash_packed_gets_h2d_and_enqueue_without_a_pack():
     """The indexer's entry packs for itself: its dispatch span has the
     plane's h2d and enqueue below it and no pack."""
