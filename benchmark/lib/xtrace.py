@@ -6,8 +6,10 @@ What a TPU trace holds (looked at by hand, PR 24): a plane `/device:TPU:<n>`
 for each chip with the lines `XLA Modules` (one event for each program run)
 and `XLA Ops` (one for each operation inside it), and a plane `/host:CPU`
 with one line for each host thread, where `TraceAnnotation`s appear by name,
-on the same clock. The window is the annotation WINDOW_SPAN; its line is
-the feeding thread.
+on the same clock. Those lines all carry the process's name (`python3`), so
+`load` keeps them apart by position. The window is the annotation
+WINDOW_SPAN; its line is the feeding thread, and the only one whose spans
+name an idle gap: pool threads' spans run beside it, not inside it.
 
 `reduce()` works on plain lists, so that tests can hand it a synthetic trace.
 """
@@ -17,6 +19,7 @@ from __future__ import annotations
 import glob
 import os
 
+HOST_PLANE = "/host:CPU"
 WINDOW_SPAN = "jfs.window"
 SPAN_PREFIX = "jfs."
 UNATTRIBUTED = "host:no_benchmark_span_on_the_feeding_thread"
@@ -49,15 +52,19 @@ def stop_and_reduce(trace_dir: str, n_tpus: int) -> dict:
 
 
 def load(path: str) -> dict:
-    """{plane name: {line name: [(name, start_s, duration_s), ...]}}"""
+    """{plane name: {line: [(name, start_s, duration_s), ...]}}. A device's
+    lines are keyed by name; the host's, one a thread and all of one name,
+    by `<name>#<position>`, so that no two threads fold into one."""
     import jax.profiler
 
     data = jax.profiler.ProfileData.from_file(path)
     planes: dict[str, dict[str, list[Event]]] = {}
     for plane in data.planes:
         lines = planes.setdefault(plane.name, {})
-        for line in plane.lines:
-            lines.setdefault(line.name, []).extend(
+        for position, line in enumerate(plane.lines):
+            key = (f"{line.name}#{position}" if plane.name == HOST_PLANE
+                   else line.name)
+            lines.setdefault(key, []).extend(
                 (e.name, e.start_ns * 1e-9, e.duration_ns * 1e-9)
                 for e in line.events)
     return planes
@@ -102,17 +109,44 @@ def innermost_segments(events: list[Event]) -> list[tuple[float, float, str]]:
         marks.append((s, 1, -d, name))       # opens: longer (outer) first
         marks.append((s + d, 0, 0.0, name))  # closes sort before opens
     marks.sort()
-    out, stack, at = [], [], None
+    out, at = [], None
+    stack: list[list] = []              # [name, still open], outermost first
+    open_by_name: dict[str, list] = {}  # name -> its entries of `stack`
     for t, opens, _, name in marks:
         if stack and t > at:
-            out.append((at, t, stack[-1]))
+            out.append((at, t, stack[-1][0]))
         if opens:
-            stack.append(name)
-        elif name in stack:
-            stack.reverse()
-            stack.remove(name)  # the innermost span of that name
-            stack.reverse()
+            entry = [name, True]
+            stack.append(entry)
+            open_by_name.setdefault(name, []).append(entry)
+        elif open_by_name.get(name):
+            open_by_name[name].pop()[1] = False  # the innermost of that name
+            while stack and not stack[-1][1]:
+                stack.pop()
         at = t
+    return out
+
+
+def gap_seconds_by_segment(idle, segments) -> dict[str, float]:
+    """Seconds of the idle gaps under each segment's name, and under
+    UNATTRIBUTED what no segment covers. Both lists are sorted and disjoint,
+    so one sweep with a cursor in each does it."""
+    out: dict[str, float] = {}
+    first = 0  # the first segment that ends after the gap at hand starts
+    for gs, ge in idle:
+        while first < len(segments) and segments[first][1] <= gs:
+            first += 1
+        covered = 0.0
+        i = first
+        while i < len(segments) and segments[i][0] < ge:
+            ss, se, name = segments[i]
+            overlap = min(ge, se) - max(gs, ss)
+            if overlap > 0:
+                out[name] = out.get(name, 0.0) + overlap
+                covered += overlap
+            i += 1
+        if ge - gs - covered > 0:
+            out[UNATTRIBUTED] = out.get(UNATTRIBUTED, 0.0) + ge - gs - covered
     return out
 
 
@@ -127,7 +161,7 @@ def top(seconds_by_name: dict[str, float], n: int = 10) -> list[list]:
 
 
 def reduce(planes: dict) -> dict:
-    host_lines = planes.get("/host:CPU", {})
+    host_lines = planes.get(HOST_PLANE, {})
     feeding = [(line, e) for line, events in host_lines.items()
                for e in events if e[0] == WINDOW_SPAN]
     if len(feeding) != 1:
@@ -160,17 +194,7 @@ def reduce(planes: dict) -> dict:
         idle = gaps(busy_intervals[busiest], lo, hi)
         spans = [e for e in host_lines[line]
                  if e[0].startswith(SPAN_PREFIX) and e[0] != WINDOW_SPAN]
-        segments = innermost_segments(spans)
-        for gs, ge in idle:
-            covered = 0.0
-            for ss, se, name in segments:
-                overlap = min(ge, se) - max(gs, ss)
-                if overlap > 0:
-                    gap_seconds[name] = gap_seconds.get(name, 0.0) + overlap
-                    covered += overlap
-            if ge - gs - covered > 0:
-                gap_seconds[UNATTRIBUTED] = (
-                    gap_seconds.get(UNATTRIBUTED, 0.0) + ge - gs - covered)
+        gap_seconds = gap_seconds_by_segment(idle, innermost_segments(spans))
 
     return {
         "window_s": length,
