@@ -4,8 +4,12 @@ plan (`make_plan`, `block_bytes`), with every size a parameter so that a
 configuration file states them. Imports nothing of the program.
 
 Every seed gives the same sizes: `big_objects` objects of `object_blocks`
-full blocks each, then the ragged handful. Only which blocks repeat a pool
-entry — and the bytes — change with the seed.
+full blocks each, then `files` small files of `file_bytes` (each an object of
+one block of its own size; a volume that states none has none), then the
+ragged handful. Only which blocks repeat a pool entry — and the bytes —
+change with the seed. Full blocks repeat one of `pool_blocks` whole-block
+contents, files one of `file_pool_blocks` contents of `file_bytes`: a
+duplicate has the size of what it repeats.
 """
 
 from __future__ import annotations
@@ -19,7 +23,8 @@ import numpy as np
 class PlannedBlock:
     """One block of one object. Two blocks are duplicates exactly when
     their `content` ids are equal."""
-    content: tuple  # ("pool", i) | ("fresh", object_index, block_index)
+    # ("pool", i) | ("pool", file_bytes, i) | ("fresh", object_index, block_index)
+    content: tuple
     size: int
 
 
@@ -56,7 +61,12 @@ class Plan:
 def make_plan(seed: int, big_objects: int, *, block: int = 4 << 20,
               object_blocks: int = 16, pool_blocks: int = 4,
               dup_probability: float = 0.3,
-              ragged_sizes=(1, 100_001, (4 << 20) - 1, (4 << 20) + 7)) -> Plan:
+              ragged_sizes=(1, 100_001, (4 << 20) - 1, (4 << 20) + 7),
+              files: int = 0, file_bytes: int = 0,
+              file_pool_blocks: int = 0) -> Plan:
+    if files and not (0 < file_bytes <= block and file_pool_blocks > 0):
+        raise ValueError(f"a file of {file_bytes} B from a pool of "
+                         f"{file_pool_blocks} is not one block of {block}")
     rng = np.random.default_rng([seed, 0])
     objects = []
     for o in range(big_objects):
@@ -68,8 +78,17 @@ def make_plan(seed: int, big_objects: int, *, block: int = 4 << 20,
                 content = ("fresh", o, b)
             blocks.append(PlannedBlock(content, block))
         objects.append(PlannedObject(f"big-{o:04d}", tuple(blocks)))
+    for f in range(files):
+        if rng.random() < dup_probability:
+            # the files' pool is named by their size: ("pool", i) is a whole
+            # block's content and never a file's
+            content = ("pool", file_bytes, int(rng.integers(file_pool_blocks)))
+        else:
+            content = ("fresh", big_objects + f, 0)
+        objects.append(PlannedObject(
+            f"file-{f:05d}", (PlannedBlock(content, file_bytes),)))
     for k, size in enumerate(ragged_sizes):
-        o = big_objects + k
+        o = big_objects + files + k
         sizes = [block] * (size // block) + ([size % block] if size % block else [])
         objects.append(PlannedObject(
             f"ragged-{size}",
@@ -90,4 +109,6 @@ def plan_of(seed: int, volume: dict) -> Plan:
         seed, volume["big_objects"], block=volume["block_bytes"],
         object_blocks=volume["object_blocks"], pool_blocks=volume["pool_blocks"],
         dup_probability=volume["dup_probability"],
-        ragged_sizes=tuple(volume["ragged_sizes"]))
+        ragged_sizes=tuple(volume["ragged_sizes"]),
+        files=volume.get("files", 0), file_bytes=volume.get("file_bytes", 0),
+        file_pool_blocks=volume.get("file_pool_blocks", 0))

@@ -9,7 +9,15 @@ line that carries every accepted per-layer metric it can read and its own,
 and the accepted cells resolve to what they resolve to today.
 
 No file that the copy started with is edited but BENCHMARK.json, and there
-nothing but appended entries and appended names."""
+nothing but appended entries and appended names.
+
+Every pin is relative to the manifest the growth started from, never to a
+count: each growth test runs from the repo's manifest and again from a base
+that already holds one more cell than the repo (the copy grown once by NEXT),
+so the cell after the next one meets no pin either. The repo's own newest
+cell (`scan-cold-bench-mix`, PR 31) is held by name: its two entries, its
+source down to the defaults that define its volume, and the other accepted
+cells resolve alike without it."""
 
 import copy
 import json
@@ -54,18 +62,28 @@ FIFTH = {
     "cell": {"name": "scan-cold-x4-halfdup", "chips": 4,
              "why": "a fifth cell, on four chips"},
 }
-# the cell that waits (PERF.md section 7 (a)), over a stand-in configuration file
-WAITING = {
-    "config": {"name": "scan-sqlite-file-smallfiles", "like": "scan-sqlite-file-4m",
-               "source": "JuiceFS `juicefs bench`/`objbench` defaults (cmd/bench.go: 4 MiB "
-                         "blocks, small files 128 KiB, one object each) on sqlite3 + "
-                         "file://; BASELINE.json metric \"dedup scan GiB/s and blocks/s\"",
-               "volume": {}},
-    "mix": ("cold-files", {"driver": "scan", "forget": "all"}),
-    "cell": {"name": "scan-cold-smallfiles", "chips": 1,
-             "why": "4,165 blocks an op, 98% of them 128 KiB files of one object each: "
-                    "batches by lane class; closed loop, one operator, every row "
-                    "forgotten before each op."},
+# a cell more than the repo holds: the base the growth tests run from again
+NEXT = {
+    "config": {"name": "scan-sqlite-file-4m-nodup", "like": "scan-sqlite-file-4m",
+               "source": "a test's own deployment: the sqlite3 + file:// 4 MiB volume "
+                         "with no duplicate planted",
+               "volume": {"dup_probability": 0.0}},
+    "mix": ("cold-next", {"driver": "scan", "forget": "all"}),
+    "cell": {"name": "scan-cold-nodup", "chips": 1,
+             "why": "the cell a later PR has already appended when this one comes"},
+}
+# the repo's newest cell (PR 31): the volume of upstream's own benchmark, its
+# source named down to the defaults that fix the mix of files and full blocks
+NEWEST = {
+    "config": {
+        "name": "scan-sqlite-file-bench-mix",
+        "source": "JuiceFS docs, `juicefs bench` defaults (cmd/bench.go: --big-file-size "
+                  "1024 MiB, --small-file-size 128 KiB, --small-file-count 100, -p 1) then "
+                  "`juicefs gc`; sqlite3 + file://: BASELINE.json configs[0]",
+        "file": "benchmark/configs/scan-sqlite-file-bench-mix.json",
+        "reduced": ["volume_blocks"]},
+    "cell": {"name": "scan-cold-bench-mix", "config": "scan-sqlite-file-bench-mix",
+             "traffic": "cold", "chips": 1},
 }
 
 
@@ -121,27 +139,49 @@ def resolved_names(root, cell):
             [e["name"] for e in r["per_layer"]])
 
 
-def check_all_and_the_pins(root, appended):
-    """Every check of the manifest; and what is accepted is as accepted, but
-    for the names appended to its per-layer lists."""
+def standing(root):
+    """What a root holds before it grows: its manifest, and what each of
+    its cells resolves to."""
+    m = checks.manifest(root)
+    return {"manifest": m, "resolved": {
+        w["name"]: resolved_names(root, w["name"]) for w in m["workloads"]}}
+
+
+@pytest.fixture(params=["the_repo", "one_cell_more"])
+def base(request, tmp_path):
+    """A copy to grow, and what it held before: the repo's manifest, or the
+    repo's grown once by NEXT (one more cell than the repo holds)."""
+    root = new_root(tmp_path)
+    if request.param == "one_cell_more":
+        add_deployment(root, **NEXT)
+        check_all_and_the_pins(root, [NEXT["cell"]["name"]], standing(REPO))
+    return root, standing(root)
+
+
+def check_all_and_the_pins(root, appended, was):
+    """Every check of the manifest; and what the root held before (`was`,
+    of `standing`) is as it was, but for the names appended to its
+    per-layer lists."""
     checks.check_all(root)
-    accepted, grown = checks.manifest(REPO), checks.manifest(root)
+    before, grown = was["manifest"], checks.manifest(root)
     for key in ("command", "paths", "run_seconds", "end_to_end"):
-        assert grown[key] == accepted[key]
+        assert grown[key] == before[key]
     for key in ("configs", "workloads"):
-        assert grown[key][:len(accepted[key])] == accepted[key]
-    for was, now in zip(accepted["per_layer"], grown["per_layer"]):
-        assert now == dict(was, workloads=was["workloads"] + appended)
+        assert grown[key][:len(before[key])] == before[key]
+    assert [w["name"] for w in grown["workloads"][len(before["workloads"]):]
+            ] == appended
+    for old, now in zip(before["per_layer"], grown["per_layer"]):
+        assert now == dict(old, workloads=old["workloads"] + appended)
     for name in PINNED_BY_NAME:
         checks.check_accepted_metric_lists_its_cells(root, name)
-    for cell in checks.ACCEPTED_CELLS:
-        assert resolved_names(root, cell) == resolved_names(REPO, cell)
+    for cell, names in was["resolved"].items():
+        assert resolved_names(root, cell) == names
 
 
-def test_a_deployment_comes_as_files_and_entries_alone(tmp_path, any_device, capsys):
-    root = new_root(tmp_path)
+def test_a_deployment_comes_as_files_and_entries_alone(base, any_device, capsys):
+    root, was = base
     body = add_deployment(root, **FOURTH)
-    check_all_and_the_pins(root, [FOURTH["cell"]["name"]])
+    check_all_and_the_pins(root, [FOURTH["cell"]["name"]], was)
 
     cell, own = FOURTH["cell"]["name"], FOURTH["metric"]["name"]
     assert run.main(argv(cell, trace=1), root=root, device_check=any_device) == 0
@@ -153,13 +193,17 @@ def test_a_deployment_comes_as_files_and_entries_alone(tmp_path, any_device, cap
     assert set(line["breakdown"]) == {"device_ops", "idle_gaps"}
 
 
-def test_a_fifth_cell_on_four_chips_is_admitted_at_five_cells_and_not_at_three(tmp_path):
-    root = new_root(tmp_path)
+def test_a_fifth_cell_on_four_chips_is_admitted_at_five_cells_and_not_at_three(
+        base, tmp_path):
+    root, was = base
     add_deployment(root, **FOURTH)
     add_deployment(root, **FIFTH)
-    check_all_and_the_pins(root, [FOURTH["cell"]["name"], FIFTH["cell"]["name"]])
+    check_all_and_the_pins(
+        root, [FOURTH["cell"]["name"], FIFTH["cell"]["name"]], was)
     m = checks.manifest(root)
-    assert [w["chips"] for w in m["workloads"]] == [1, 1, 4, 1, 4]
+    # what the test added, not what the root held: a one-chip cell, then a
+    # four-chip one, and with them half of the cells or fewer take four
+    assert [w["chips"] for w in m["workloads"]][-2:] == [1, 4]
 
     # the same two four-chip cells among three cells: refused
     three = copy.deepcopy(m)
@@ -171,21 +215,50 @@ def test_a_fifth_cell_on_four_chips_is_admitted_at_five_cells_and_not_at_three(t
         checks.check_at_most_half_take_four_chips(str(tmp_path / "three"))
 
 
-def test_the_cell_that_waits_passes_the_manifest_checks(tmp_path):
+def without(m, config, cell):
+    """The manifest as it stood before `cell` on `config` was appended."""
+    return dict(
+        m, configs=[c for c in m["configs"] if c["name"] != config],
+        workloads=[w for w in m["workloads"] if w["name"] != cell],
+        per_layer=[dict(e, workloads=[w for w in e["workloads"] if w != cell])
+                   for e in m["per_layer"]])
+
+
+def test_the_cell_that_waited_passes_the_manifest_checks_in_the_repo(tmp_path):
+    """The repo's own newest accepted cell (PERF.md section 7 (a) kept it
+    waiting through two refused PRs; PR 31 brought it at the source's own
+    mix of files and full blocks): its two entries, among the accepted cells,
+    the accepted names first and in order in every list that holds it; and
+    with it taken out by name the other accepted cells resolve to what they
+    resolve to with it. Nothing here counts what else the manifest holds: a
+    later PR appends."""
+    config, cell = NEWEST["config"]["name"], NEWEST["cell"]["name"]
+    checks.check_all(REPO)
+    m = checks.manifest(REPO)
+    entry, = [c for c in m["configs"] if c["name"] == config]
+    assert {k: entry[k] for k in NEWEST["config"]} == NEWEST["config"]
+    listed, = [w for w in m["workloads"] if w["name"] == cell]
+    assert {k: listed[k] for k in NEWEST["cell"]} == NEWEST["cell"]
+    assert listed["why"].startswith("361 blocks an op: bench's default mix")
+    assert run.read_json(os.path.join(REPO, entry["file"]))["source"] == entry["source"]
+    assert cell in checks.ACCEPTED_CELLS
+    holding = [e["name"] for e in m["per_layer"] if cell in e["workloads"]]
+    assert set(PINNED_BY_NAME) | {"tpu.blocks_per_batch"} <= set(holding)
+    for name in holding:
+        checks.check_accepted_metric_lists_its_cells(REPO, name)
+
     root = new_root(tmp_path)
-    add_deployment(root, **WAITING)
-    check_all_and_the_pins(root, [WAITING["cell"]["name"]])
-    m = checks.manifest(root)
-    assert m["configs"][-1]["reduced"] == ["volume_blocks"]
-    assert m["workloads"][-1] == {
-        "name": "scan-cold-smallfiles", "config": "scan-sqlite-file-smallfiles",
-        "traffic": "cold-files", "chips": 1, "why": WAITING["cell"]["why"]}
+    write_json(os.path.join(root, "BENCHMARK.json"), without(m, config, cell))
+    for other in (c for c in checks.ACCEPTED_CELLS if c != cell):
+        assert resolved_names(root, other) == resolved_names(REPO, other)
+    with pytest.raises(run.Refused):
+        run.resolve(root, cell)
 
 
-def test_an_edit_to_what_is_accepted_fails_the_pins(tmp_path):
-    """The pins bite: a cell put before the accepted three, or a name put
+def test_an_edit_to_what_is_accepted_fails_the_pins(base):
+    """The pins bite: a cell put before the accepted ones, or a name put
     into the middle of an accepted list, is no longer "appended"."""
-    root = new_root(tmp_path)
+    root, was = base
     add_deployment(root, **FOURTH)
     m = checks.manifest(root)
     moved = copy.deepcopy(m)
@@ -193,9 +266,18 @@ def test_an_edit_to_what_is_accepted_fails_the_pins(tmp_path):
     write_json(os.path.join(root, "BENCHMARK.json"), moved)
     with pytest.raises(AssertionError):
         checks.check_accepted_cells_come_first(root)
+    with pytest.raises(AssertionError):
+        check_all_and_the_pins(root, [FOURTH["cell"]["name"]], was)
     moved = copy.deepcopy(m)
     listed = moved["per_layer"][-2]["workloads"]  # an accepted metric's
     listed.insert(1, listed.pop())
     write_json(os.path.join(root, "BENCHMARK.json"), moved)
     with pytest.raises(AssertionError):
         checks.check_accepted_metric_lists_its_cells(root, moved["per_layer"][-2]["name"])
+    # a cell put inside what the root held, after the accepted ones
+    moved = copy.deepcopy(m)
+    moved["workloads"].insert(len(was["manifest"]["workloads"]) - 1,
+                              moved["workloads"].pop())
+    write_json(os.path.join(root, "BENCHMARK.json"), moved)
+    with pytest.raises(AssertionError):
+        check_all_and_the_pins(root, [FOURTH["cell"]["name"]], was)

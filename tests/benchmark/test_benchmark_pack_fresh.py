@@ -28,7 +28,7 @@ METRICS = {
 
 
 @pytest.mark.parametrize("metric", METRICS)
-def test_manifest_entry_names_its_layer_and_the_three_cells(metric):
+def test_manifest_entry_names_its_layer_and_the_accepted_cells(metric):
     """The accepted cells first and in order, in the manifest and in the
     metric's list; a later PR's cells come after them."""
     manifest = checks.manifest(REPO)

@@ -20,10 +20,12 @@ RESULT_KEYS = ["correct", "attempted", "failed", "metrics", "device"]
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
-def make_root(dst: str, big_objects: int = 2, hash_backend: str = "xla") -> str:
+def make_root(dst: str, big_objects: int = 2, hash_backend: str = "xla",
+              files: int = 6) -> str:
     """BENCHMARK.json + benchmark/ copied, juicefs_tpu/ linked, every
-    configuration cut to `big_objects` (37 blocks at 2) and pointed at the
-    hash backend that runs on whatever JAX found."""
+    configuration cut to `big_objects` (37 blocks at 2) and, where its volume
+    has small files, to `files` of them (43 blocks at 2 and 6), and pointed
+    at the hash backend that runs on whatever JAX found."""
     os.makedirs(dst, exist_ok=True)
     os.symlink(os.path.join(REPO, "juicefs_tpu"), os.path.join(dst, "juicefs_tpu"))
     shutil.copytree(os.path.join(REPO, "benchmark"), os.path.join(dst, "benchmark"),
@@ -35,6 +37,8 @@ def make_root(dst: str, big_objects: int = 2, hash_backend: str = "xla") -> str:
         with open(path) as f:
             cfg = json.load(f)
         cfg["volume"]["big_objects"] = big_objects
+        if "files" in cfg["volume"]:
+            cfg["volume"]["files"] = files
         cfg["deployment"]["hash_backend"] = hash_backend
         with open(path, "w") as f:
             json.dump(cfg, f)
@@ -103,7 +107,8 @@ def test_without_the_program_beside_it_it_refuses(tmp_path, capsys):
     assert capsys.readouterr().out == ""
 
 
-@pytest.mark.parametrize("workload", ["scan-cold", "scan-incr", "scan-cold-x4"])
+@pytest.mark.parametrize("workload", ["scan-cold", "scan-incr", "scan-cold-x4",
+                                      "scan-cold-bench-mix"])
 def test_untraced_run_prints_the_contracts_line(tiny_root, any_device, capsys, workload):
     assert run.main(argv(workload), root=tiny_root, device_check=any_device) == 0
     line = last_line(capsys)
