@@ -264,7 +264,13 @@ def dedup_scan(meta, store, live: dict[str, int], backend: str,
     Object GETs run `threads` deep through the ordered parallel-fetch
     stage (chunk/parallel.py), overlapping storage I/O with TPU hash
     dispatch; results arrive in input order, so digests and index rows
-    are byte-identical to the old serial walk.
+    are byte-identical to the old serial walk.  Never more than `threads`
+    GETs run at once; the stage fetches one hash batch ahead of them
+    (`batch_blocks` of the pipeline, 32), so that while this thread packs,
+    ships and drains batch k the pool is fetching batch k + 1.  Host
+    memory: at most `(threads + batch_blocks) x block_size` of fetched
+    blocks wait for the hash (42 x 4 MiB = 168 MiB at the defaults with
+    `--threads 10`), beside the batch being gathered.
     """
     import resource
     import time as _time
@@ -296,16 +302,20 @@ def dedup_scan(meta, store, live: dict[str, int], backend: str,
         PipelineConfig(backend=backend, pad_lanes=max(1, block_size // 65536))
     )
     window = max(1, threads)
+    # what hash_stream takes between two stretches of its own work
+    ahead = pipe.config.batch_blocks
     fstats = FetchStats()
 
     def blocks():
-        # windowed parallel GETs on the store's download pool, yielded in
-        # input order straight into the hash pipeline; a bad block is
-        # skipped (and logged by the stage), never aborts the scan
+        # windowed parallel GETs on the store's download pool, a batch
+        # ahead of the hash pipeline and yielded into it in input order; a
+        # bad block is skipped (and logged by the stage), never aborts the
+        # scan
         yield from fetch_ordered(
             missing,
             lambda key: store._load_block(key, live[key], cache_after=False),
             store._bulk_pool, window, on_error="skip", stats=fstats,
+            ahead=ahead,
         )
 
     backfill = []
@@ -317,7 +327,8 @@ def dedup_scan(meta, store, live: dict[str, int], backend: str,
             sid, indx, bsize = parse_block_key(key)
             backfill.append((sid, indx, bsize, digest))
         if sp_readhash.active:
-            sp_readhash.set(blocks=len(backfill), window=window)
+            sp_readhash.set(blocks=len(backfill), window=window,
+                            ahead=ahead)
     _SCAN_MINOR_FAULTS.inc(
         resource.getrusage(resource.RUSAGE_SELF).ru_minflt - faults0)
     with _TR.span("cmd", "gc", stage="backfill",
@@ -361,6 +372,7 @@ def dedup_scan(meta, store, live: dict[str, int], backend: str,
         # the backend that RAN (requested name is in device.requested)
         "backend": pipe.config.backend,
         "fetch_window": window,
+        "fetch_ahead": ahead,
         # stage breakdown (VERDICT r3 #2: the bottleneck must be explicit).
         # `get` is WALL time the fetch stage had GETs in flight;
         # `get_threads` is aggregate per-thread GET seconds — their ratio

@@ -14,6 +14,7 @@ Exports:
     crc32c(data, crc=0)            hardware CRC32C (SSE4.2 when available)
     jth256(data) -> 32B digest     C++ JTH-256, byte-identical to the spec
     jth256_batch(blocks, threads)  multithreaded batch hash
+    pack_rows(blocks, rows) -> bool  a hash batch's rows in one call
     available() -> bool
 """
 
@@ -110,6 +111,14 @@ def _load() -> Optional[ctypes.CDLL]:
                 ctypes.c_char_p,
                 ctypes.c_int,
             ]
+            lib.jfs_pack_rows.restype = None
+            lib.jfs_pack_rows.argtypes = [
+                ctypes.POINTER(ctypes.c_char_p),
+                ctypes.POINTER(ctypes.c_size_t),
+                ctypes.c_size_t,
+                ctypes.c_void_p,
+                ctypes.c_size_t,
+            ]
             if lib.jfs_abi_version() != 1:
                 raise OSError("jfscore ABI mismatch")
             _lib = lib
@@ -144,6 +153,26 @@ def jth256(data: bytes) -> bytes:
     return out.raw
 
 
+def _pointers(blocks: Sequence[bytes]):
+    """Zero-copy pointers for bytes AND writable buffers (bytearray from
+    the WSlice block buffers — the ingest path hashes them in place; the C
+    side only reads, bounded by the explicit lengths), their lengths, and
+    what has to stay alive across the call. TypeError for a read-only
+    buffer that is not `bytes`."""
+    n = len(blocks)
+    arr = (ctypes.c_char_p * n)()
+    keepalive = []
+    for i, b in enumerate(blocks):
+        if isinstance(b, bytes):
+            arr[i] = b
+        else:
+            view = (ctypes.c_char * len(b)).from_buffer(b)
+            keepalive.append(view)
+            arr[i] = ctypes.cast(view, ctypes.c_char_p)
+    lens = (ctypes.c_size_t * n)(*map(len, blocks))
+    return arr, lens, keepalive
+
+
 def jth256_batch(blocks: Sequence[bytes], threads: int = 0) -> list[bytes]:
     lib = _load()
     if lib is None:
@@ -155,21 +184,30 @@ def jth256_batch(blocks: Sequence[bytes], threads: int = 0) -> list[bytes]:
     if threads <= 0:
         threads = min(len(blocks), os.cpu_count() or 1)
     n = len(blocks)
-    # zero-copy pointers for bytes AND writable buffers (bytearray from
-    # the WSlice block buffers — the ingest path hashes them in place;
-    # the C side only reads, bounded by the explicit lengths)
-    arr = (ctypes.c_char_p * n)()
-    _keepalive = []
-    for i, b in enumerate(blocks):
-        if isinstance(b, bytes):
-            arr[i] = b
-        else:
-            view = (ctypes.c_char * len(b)).from_buffer(b)
-            _keepalive.append(view)
-            arr[i] = ctypes.cast(view, ctypes.c_char_p)
-    lens = (ctypes.c_size_t * n)(*[len(b) for b in blocks])
+    arr, lens, _keepalive = _pointers(blocks)
     outs = ctypes.create_string_buffer(32 * n)
-    lib.jfs_jth256_batch(
-        ctypes.cast(arr, ctypes.POINTER(ctypes.c_char_p)), lens, n, outs, threads
-    )
+    lib.jfs_jth256_batch(arr, lens, n, outs, threads)
     return [outs.raw[i * 32 : (i + 1) * 32] for i in range(n)]
+
+
+def pack_rows(blocks: Sequence[bytes], rows) -> bool:
+    """Block i into `rows[i]` from its start, zeros to the row's end, for
+    every block in ONE call outside the interpreter lock. `rows` is a
+    C-contiguous writeable (>= len(blocks), row_bytes) uint8 array and no
+    block is longer than a row: the caller's to hold. False, with nothing
+    written, when there is no library or a block is a read-only buffer
+    that is not `bytes`: the caller copies row by row instead.
+
+    Why one call (ISSUE 32): a numpy copy leaves the lock for its memcpy and
+    has to retake it after every block; beside the scan's ten GET threads
+    each retaking is a wait in their queue, and a 128 MiB pack took 41 ms
+    where it takes 29 alone."""
+    lib = _load()
+    if lib is None:
+        return False
+    try:
+        arr, lens, _keepalive = _pointers(blocks)
+    except TypeError:
+        return False
+    lib.jfs_pack_rows(arr, lens, len(blocks), rows.ctypes.data, rows.shape[1])
+    return True

@@ -30,6 +30,8 @@ uint32_t jfs_crc32c(const uint8_t *data, size_t n, uint32_t crc);
 void jfs_jth256(const uint8_t *data, size_t n, uint8_t out[32]);
 void jfs_jth256_batch(const uint8_t *const *blocks, const size_t *lens,
                       size_t count, uint8_t *outs, int threads);
+void jfs_pack_rows(const uint8_t *const *blocks, const size_t *lens,
+                   size_t count, uint8_t *rows, size_t row_bytes);
 int jfs_abi_version();
 }
 
@@ -208,4 +210,20 @@ void jfs_jth256_batch(const uint8_t *const *blocks, const size_t *lens,
   std::vector<std::thread> pool;
   for (unsigned t = 0; t < nt; t++) pool.emplace_back(worker);
   for (auto &t : pool) t.join();
+}
+
+// ------------------------------------------------------------- pack rows --
+
+// One hash batch's host pack: block i to the start of row i, zeros to the
+// end of the row. One call for the whole batch, so that the caller leaves
+// and retakes the interpreter lock once and not once a block: beside ten
+// GET threads each retaking costs the packing thread a queue for the lock.
+// The caller has checked lens[i] <= row_bytes.
+void jfs_pack_rows(const uint8_t *const *blocks, const size_t *lens,
+                   size_t count, uint8_t *rows, size_t row_bytes) {
+  for (size_t i = 0; i < count; i++) {
+    uint8_t *row = rows + i * row_bytes;
+    memcpy(row, blocks[i], lens[i]);
+    memset(row + lens[i], 0, row_bytes - lens[i]);
+  }
 }

@@ -163,10 +163,7 @@ def pack_blocks(
     if max(counts, default=1) > m:
         raise ValueError(f"block needs {max(counts)} lanes > pad_lanes={m}")
     if out is None:
-        words = np.zeros((len(blocks), m, ROWS, COLS), dtype=np.uint32)
-        for i, b in enumerate(blocks):
-            w = pack_block(b)
-            words[i, : w.shape[0]] = w
+        words = np.empty((len(blocks), m, ROWS, COLS), dtype="<u4")
     else:
         if (not isinstance(out, np.ndarray) or out.dtype != np.dtype("<u4")
                 or not out.flags.c_contiguous or not out.flags.writeable
@@ -175,15 +172,21 @@ def pack_blocks(
             raise ValueError(
                 f"out must be a C-contiguous writeable <u4 array of "
                 f"(>= {len(blocks)}, {m}, {ROWS}, {COLS})")
-        longest = max(map(len, blocks), default=0)
-        if longest > BLOCK_BYTES:  # what pack_block refuses
-            raise ValueError(f"block larger than {BLOCK_BYTES}: {longest}")
         words = out[: len(blocks)]
-        rows = words.reshape(len(blocks), m * LANE_BYTES // 4).view(np.uint8)
+    longest = max(map(len, blocks), default=0)
+    if longest > BLOCK_BYTES:  # what pack_block refuses
+        raise ValueError(f"block larger than {BLOCK_BYTES}: {longest}")
+    # Every row: the block's bytes as they lie, then zeros to the end of
+    # the row (the padding of its last lane and every lane it does not
+    # use), whatever the array held: fresh memory, or the buffer's last
+    # batch. In one native call where the library is there, so that the
+    # packing thread does not queue for the interpreter lock after every
+    # block, behind the threads that fetch the next batch (ISSUE 32).
+    from .. import native  # not at the top: chip_smoke.py loads this file alone
+
+    rows = words.reshape(len(blocks), m * LANE_BYTES // 4).view(np.uint8)
+    if not native.pack_rows(blocks, rows):
         for i, b in enumerate(blocks):
-            # the block's bytes as they lie, then zeros to the end of the
-            # row: the padding of its last lane and every lane it does not
-            # use, which the buffer's last batch may have written
             rows[i, : len(b)] = np.frombuffer(b, dtype=np.uint8)
             rows[i, len(b):] = 0
     lengths = np.array([len(b) for b in blocks], dtype=np.uint32)

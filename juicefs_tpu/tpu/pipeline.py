@@ -171,12 +171,12 @@ class HashPipeline:
                 if sp.active:
                     sp.set(batch=len(blocks), bytes=nbytes,
                            backend=self.config.backend)
+                from .. import native
+
                 if self._fn is None:
                     # CPU path: hash raw bytes directly (native C++ batch with
                     # numpy fallback) — no packing cost, already synchronous,
                     # and no device transfer (h2d counter stays untouched).
-                    from .. import native
-
                     pending.append(
                         (keys, native.jth256_batch(blocks), t0, None))
                 else:
@@ -186,7 +186,10 @@ class HashPipeline:
                         if psp.active:
                             psp.set(batch=len(blocks), bytes=nbytes,
                                     padded_bytes=words.nbytes,
-                                    fresh=int(fresh))
+                                    fresh=int(fresh),
+                                    # which pack ran: libjfscore's one
+                                    # call, or numpy row by row (0)
+                                    native=int(native.available()))
                     if fresh:
                         _PACK_FRESH_BYTES.inc(words.nbytes)
                     _H2D_BYTES.inc(words.nbytes)

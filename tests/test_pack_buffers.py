@@ -197,3 +197,44 @@ def test_a_stream_holds_no_buffer_once_it_is_over(monkeypatch, how):
     del stream
     gc.collect()
     assert len(made) == 2 and all(ref() is None for ref in made)
+
+
+@pytest.mark.parametrize("how", ["library", "no-library", "bytearray", "read-only"])
+def test_pack_in_one_native_call_and_row_by_row_write_the_same(how,
+                                                               monkeypatch):
+    """ISSUE 32: the rows are written in one call of libjfscore where it is
+    there (one leave and retake of the interpreter lock a batch), row by
+    row with numpy where it is not, or where a block is a read-only buffer
+    that is not `bytes` (ctypes takes no pointer to one)."""
+    from juicefs_tpu import native
+
+    sizes = [0, 1, 65_535, 65_536, 100_001, MIB4 - 1, MIB4, 131_072, 777]
+    blocks = _blocks(seed=32, sizes=sizes)
+    by_hand = np.zeros((len(blocks), 64, ROWS, COLS), dtype=np.uint32)
+    for i, b in enumerate(blocks):
+        w = pack_block(b)
+        by_hand[i, : w.shape[0]] = w
+    calls = []
+    real = native.pack_rows
+    if how == "no-library":
+        monkeypatch.setattr(native, "pack_rows", lambda blocks, rows: False)
+    else:
+        if how == "bytearray":
+            blocks = [bytearray(b) for b in blocks]
+        elif how == "read-only":
+            blocks = [memoryview(b) for b in blocks]
+
+        def counted(blocks, rows):
+            calls.append(real(blocks, rows))
+            return calls[-1]
+        monkeypatch.setattr(native, "pack_rows", counted)
+    fresh = pack_blocks(blocks, pad_lanes=64)[0]
+    dirty = np.full((len(blocks) + 1, 64, ROWS, COLS), STALE, dtype=np.uint32)
+    kept = pack_blocks(blocks, pad_lanes=64, out=dirty)[0]
+    assert fresh.tobytes() == kept.tobytes() == by_hand.tobytes()
+    assert dirty[-1].min() == STALE
+    if how in ("library", "bytearray") and native.available():
+        assert calls == [True, True]  # one call a batch, and it wrote
+    elif how == "read-only":
+        assert calls == [False, False]
+    assert pack_blocks([], pad_lanes=64)[0].shape == (0, 64, ROWS, COLS)

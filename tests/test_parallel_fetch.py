@@ -301,3 +301,485 @@ def test_pipeline_inflight_depth_preserves_results():
         ))
         out = pipe.hash_blocks(blocks)
         assert out == [jth256(b) for b in blocks]
+
+
+# -- look-ahead: `ahead` items fetched past the window (ISSUE 32) ------------
+
+class _GatedFn:
+    """The file's gating fake store as a callable: counts calls running at
+    once, calls begun and calls ended; a call may be held on an event."""
+
+    def __init__(self, hold=(), delay=0.0):
+        self.lock = threading.Lock()
+        self.cur = self.max_running = self.begun = self.ended = 0
+        self.started: list = []
+        self.hold = set(hold)
+        self.release = threading.Event()
+        self.delay = delay
+
+    def __call__(self, i):
+        with self.lock:
+            self.cur += 1
+            self.begun += 1
+            self.started.append(i)
+            self.max_running = max(self.max_running, self.cur)
+        try:
+            if i in self.hold:
+                assert self.release.wait(timeout=10)
+            elif self.delay:
+                time.sleep(self.delay)
+            return i * 10
+        finally:
+            with self.lock:
+                self.cur -= 1
+                self.ended += 1
+
+
+def _until(cond, timeout=10.0):
+    deadline = time.time() + timeout
+    while not cond() and time.time() < deadline:
+        time.sleep(0.002)
+    return cond()
+
+
+def test_ahead_runs_window_calls_and_fetches_window_plus_ahead(pool):
+    # 8 pool workers, window 3, ahead 5: never more than 3 calls run, and a
+    # consumer that stands still finds window + ahead = 8 items fetched or
+    # fetching, not one more
+    fn = _GatedFn(delay=0.002)
+    gen = fetch_ordered(range(40), fn, pool, window=3, ahead=5)
+    assert next(gen) == (0, 0)
+    assert _until(lambda: fn.ended == 8)  # nothing pulled them: look-ahead
+    time.sleep(0.05)
+    assert fn.begun == 8
+    consumed, most = 1, 0
+    for i, out in gen:
+        assert (i, out) == (consumed, consumed * 10)
+        consumed += 1
+        most = max(most, fn.begun - consumed)
+    assert consumed == 40
+    assert most <= 8
+    assert 2 <= fn.max_running <= 3
+
+
+def test_ahead_buffers_at_most_window_plus_ahead(pool):
+    # item 0 is held: everything behind it completes and waits, but
+    # completed-minus-consumed never passes window + ahead
+    fn = _GatedFn(hold={0})
+    gen = fetch_ordered(range(30), fn, pool, window=4, ahead=6)
+    got = []
+    t = threading.Thread(target=lambda: got.extend(gen), daemon=True)
+    t.start()
+    assert _until(lambda: fn.ended == 9)  # all but the held head
+    time.sleep(0.05)
+    assert fn.begun == 10 and fn.ended == 9
+    fn.release.set()
+    t.join(timeout=10)
+    assert not t.is_alive()
+    assert got == [(i, i * 10) for i in range(30)]
+    assert fn.max_running <= 4
+
+
+def test_ahead_yields_in_input_order_under_out_of_order_completion(pool):
+    def fn(i):
+        time.sleep((11 - i) * 0.004)
+        return i * 10
+
+    out = list(fetch_ordered(range(12), fn, pool, window=4, ahead=8))
+    assert out == [(i, i * 10) for i in range(12)]
+
+
+@pytest.mark.parametrize("ahead", [0, 5])
+def test_error_policies_hold_with_and_without_ahead(pool, ahead):
+    from juicefs_tpu.object.resilient import BreakerOpenError
+
+    def fn(i):
+        if i in (2, 5):
+            raise IOError("backend hiccup")
+        if i == 7:
+            raise NotFoundError("gone")
+        return i
+
+    stats = FetchStats()
+    out = list(fetch_ordered(range(12), fn, pool, window=3, on_error="skip",
+                             stats=stats, ahead=ahead))
+    assert [i for i, _ in out] == [0, 1, 3, 4, 6, 8, 9, 10, 11]
+    assert (stats.errors, stats.items) == (3, 12)
+
+    seen = []
+    with pytest.raises(IOError, match="hiccup"):
+        for i, _ in fetch_ordered(range(12), fn, pool, window=3,
+                                  on_error="raise", ahead=ahead):
+            seen.append(i)
+    assert seen == [0, 1]
+
+    def tripped(i):
+        if i == 4:
+            raise BreakerOpenError("circuit open")
+        return i
+
+    seen = []
+    with pytest.raises(BreakerOpenError):  # even under "skip"
+        for i, _ in fetch_ordered(range(12), tripped, pool, window=3,
+                                  on_error="skip", ahead=ahead):
+            seen.append(i)
+    assert seen == [0, 1, 2, 3]
+
+
+@pytest.mark.parametrize("workers,may_start", [(1, {0, 1}), (8, {0, 1, 2, 3})])
+def test_abandoned_generator_with_ahead_leaves_nothing_queued(workers,
+                                                              may_start):
+    # one worker: items 2 and 3 are queued in the pool when the consumer
+    # walks away; eight: 1-3 run (the window) and 4.. wait in the stage.
+    # Nothing that had not begun by then ever runs.
+    p = ThreadPoolExecutor(max_workers=workers, thread_name_prefix="t-aband")
+    try:
+        fn = _GatedFn(hold=set(range(1, 20)))
+        gen = fetch_ordered(range(20), fn, p, window=3, ahead=6)
+        assert next(gen) == (0, 0)
+        if workers > 1:
+            assert _until(lambda: fn.begun == 4)
+        gen.close()
+        fn.release.set()
+    finally:
+        p.shutdown(wait=True)
+    assert set(fn.started) <= may_start
+    assert fn.begun == fn.ended
+
+
+def test_stalling_consumer_finds_every_later_batch_ready(pool):
+    # the scan's shape: pull a batch, then stand in the "pack" long enough
+    # for the pool to fetch the next. From the second batch on every pull
+    # finds its block there — by the stage's own counter, not by a timing.
+    from juicefs_tpu.chunk.parallel import _WAIT_BLOCKED, _WAIT_READY
+
+    class Counting:
+        """Counts pool jobs that have returned: by then the stage has the
+        result (a call's own end comes a moment before that)."""
+
+        def __init__(self, inner):
+            self.inner, self.finished = inner, 0
+            self.lock = threading.Lock()
+
+        def submit(self, job, *a):
+            def counted():
+                try:
+                    return job(*a)
+                finally:
+                    with self.lock:
+                        self.finished += 1
+            return self.inner.submit(counted)
+
+    batch, n = 8, 40
+    counting = Counting(pool)
+    gen = fetch_ordered(range(n), lambda i: time.sleep(0.001), counting,
+                        window=3, ahead=batch)
+    blocked_after_first = None
+    ready0 = _WAIT_READY.value
+    for k in range(n // batch):
+        for j in range(batch):
+            assert next(gen)[0] == k * batch + j
+        if k == 0:
+            blocked_after_first = _WAIT_BLOCKED.value
+        # the "pack": until every call the stage may start has returned —
+        # the generator stands with window + ahead - 1 items pulled past
+        # the one it yielded (a count of returns alone could be met by
+        # later items while one of the next batch still runs)
+        want = min(n, (k + 1) * batch + 3 + batch - 1)
+        assert _until(lambda: counting.finished >= want)
+    assert list(gen) == []
+    assert _WAIT_BLOCKED.value == blocked_after_first
+    assert _WAIT_READY.value - ready0 >= n - batch
+
+
+def test_failed_submit_on_a_pool_thread_reaches_the_consumer():
+    # the stage submits from pool threads too: a pool that refuses there
+    # (shut down under a live stage) must fail the scan in the consumer,
+    # under "skip" as well, not hang it
+    class Refusing:
+        def __init__(self, inner, after):
+            self.inner, self.left = inner, after
+
+        def submit(self, *a, **kw):
+            self.left -= 1
+            if self.left < 0:
+                raise RuntimeError("cannot schedule new futures")
+            return self.inner.submit(*a, **kw)
+
+    p = ThreadPoolExecutor(max_workers=4, thread_name_prefix="t-refuse")
+    refusing = Refusing(p, 9)
+    got, err = [], []
+
+    def consume():
+        try:
+            for pair in fetch_ordered(range(30), lambda i: i, refusing,
+                                      window=2, on_error="skip", ahead=10):
+                got.append(pair)
+                # stand still until a pool thread has met the refusal
+                assert _until(lambda: refusing.left < 0)
+        except RuntimeError as e:
+            err.append(e)
+
+    try:
+        t = threading.Thread(target=consume, daemon=True)
+        t.start()
+        t.join(timeout=10)
+        assert not t.is_alive()
+    finally:
+        p.shutdown(wait=True)
+    assert got == [(i, i) for i in range(9)]  # what was submitted, in order
+    assert len(err) == 1 and "cannot schedule" in str(err[0])
+
+
+class _Pools:
+    """The two kinds of pool the stage runs on, two workers each: compact's
+    own ThreadPoolExecutor, and a BACKGROUND executor of a scheduler lane
+    (`CachedStore._bulk_pool`: the scan, `fill_cache`, `remove`)."""
+
+    def __init__(self, kind):
+        from juicefs_tpu.qos.scheduler import IOClass, Scheduler
+
+        self.sched = None
+        if kind == "lane":
+            self.sched = Scheduler()
+            self.pool = self.sched.executor("t-cancel", IOClass.BACKGROUND,
+                                            width=2)
+        else:
+            self.pool = ThreadPoolExecutor(max_workers=2,
+                                           thread_name_prefix="t-cancel")
+
+    def close(self):
+        self.pool.shutdown(wait=True)
+        if self.sched is not None:
+            self.sched.close()
+
+
+@pytest.mark.parametrize("kind", ["threads", "lane"])
+@pytest.mark.parametrize("ahead", [0, 6])
+@pytest.mark.parametrize("on_error", ["raise", "skip"])
+def test_a_pool_shut_down_under_a_live_stage_ends_the_consumer(kind, ahead,
+                                                               on_error):
+    # `CachedStore.close()` cancels what its pools have queued. Two calls
+    # run (held), two are queued in the pool, `ahead` more wait in the
+    # stage; the pool is shut down with cancel_futures and the gate opens.
+    # The consumer gets what ran, meets the cancelled calls as failed items
+    # (raise: CancelledError; skip: skipped) and ends: it never waits on a
+    # call that nobody will run.
+    from concurrent.futures import CancelledError
+
+    pools = _Pools(kind)
+    fn = _GatedFn(hold={0, 1})
+    got, err = [], []
+
+    def consume():
+        try:
+            got.extend(fetch_ordered(range(4 + ahead), fn, pools.pool,
+                                     window=4, on_error=on_error,
+                                     ahead=ahead))
+        except Exception as e:
+            err.append(e)
+
+    t = threading.Thread(target=consume, daemon=True)
+    try:
+        t.start()
+        assert _until(lambda: fn.begun == 2)
+        time.sleep(0.02)  # the consumer stands on the held head
+        pools.pool.shutdown(wait=False, cancel_futures=True)
+        fn.release.set()
+        t.join(timeout=10)
+        assert not t.is_alive()
+    finally:
+        fn.release.set()
+        pools.close()
+    assert got == [(0, 0), (1, 10)]
+    assert fn.started == [0, 1] or fn.started == [1, 0]
+    if on_error == "raise":
+        assert len(err) == 1 and isinstance(err[0], CancelledError)
+    else:
+        assert err == []
+
+
+@pytest.mark.parametrize("kind", ["threads", "lane"])
+@pytest.mark.parametrize("ahead", [0, 6])
+def test_a_pool_shut_down_refuses_what_a_skipping_consumer_pulls_next(kind,
+                                                                     ahead):
+    # as above with input left to pull: under "skip" the consumer's next
+    # submit (or a running call's) meets the closed pool and the stage
+    # fails with the pool's own refusal, as it did before it had a depth
+    pools = _Pools(kind)
+    fn = _GatedFn(hold={0, 1})
+    got, err = [], []
+
+    def consume():
+        try:
+            got.extend(fetch_ordered(range(200), fn, pools.pool, window=4,
+                                     on_error="skip", ahead=ahead))
+        except RuntimeError as e:
+            err.append(e)
+
+    t = threading.Thread(target=consume, daemon=True)
+    try:
+        t.start()
+        assert _until(lambda: fn.begun == 2)
+        time.sleep(0.02)
+        pools.pool.shutdown(wait=False, cancel_futures=True)
+        fn.release.set()
+        t.join(timeout=10)
+        assert not t.is_alive()
+    finally:
+        fn.release.set()
+        pools.close()
+    assert got == [(0, 0), (1, 10)][:len(got)]
+    assert set(fn.started) == {0, 1}
+    assert len(err) == 1 and "shutdown" in str(err[0])
+
+
+@pytest.mark.parametrize("ahead,submitters", [(0, "consumer"), (6, "both")])
+def test_without_ahead_every_call_is_submitted_by_the_consumer(pool, ahead,
+                                                               submitters):
+    # compact, remove and fill_cache pass no `ahead`: the stage then submits
+    # from the consumer's thread alone, as it did before it had a depth (a
+    # call's end is counted before its result shows, so the cap is open
+    # whenever the consumer pulls). With `ahead`, pool threads submit too.
+    class Recording:
+        def __init__(self, inner):
+            self.inner, self.by = inner, set()
+
+        def submit(self, *a, **kw):
+            self.by.add(threading.get_ident())
+            return self.inner.submit(*a, **kw)
+
+    import sys
+
+    rec = Recording(pool)
+    n = 2000
+    out = []
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)  # a thread loses the lock between any two steps
+    try:
+        for pair in fetch_ordered(range(n), lambda i: i, rec, window=3,
+                                  ahead=ahead):
+            out.append(pair)
+            if ahead and len(out) == 1:
+                time.sleep(0.02)  # a "pack": the pool runs on without a pull
+    finally:
+        sys.setswitchinterval(old)
+    assert out == [(i, i) for i in range(n)]
+    me = threading.get_ident()
+    if submitters == "consumer":
+        assert rec.by == {me}
+    else:
+        assert me in rec.by and len(rec.by) > 1
+
+
+@pytest.mark.parametrize("ahead", [0, 5])
+def test_a_consumed_result_is_freed_at_once(pool, ahead):
+    # a fetched block is 4 MiB: once the consumer lets go of it nothing of
+    # the stage may keep it alive, and no reference cycle either — with
+    # the collector off, it is gone by the next pull (the scan's GET
+    # buffers are recycled by malloc only if they are freed, PERF.md PR 26)
+    import gc
+    import weakref
+
+    class Block:
+        pass
+
+    refs = []
+
+    def fn(i):
+        b = Block()
+        refs.append((i, weakref.ref(b)))
+        return b
+
+    gc.collect()
+    gc.disable()
+    try:
+        gen = fetch_ordered(range(40), fn, pool, window=3, ahead=ahead)
+        for n, (i, b) in enumerate(gen):
+            del b
+            # (a pool thread may still be on its way out of the call)
+            assert _until(lambda: not [j for j, r in refs
+                                       if j < i and r() is not None], 2.0)
+        assert n == 39
+        del gen
+        assert _until(lambda: not [j for j, r in refs if r() is not None],
+                      2.0)
+    finally:
+        gc.enable()
+
+
+def test_ahead_stress_keeps_order_and_the_running_cap():
+    # more workers than cores, a short switch interval: a lost update of
+    # the running count would pass the cap or strand the tail
+    import sys
+
+    p = ThreadPoolExecutor(max_workers=32, thread_name_prefix="t-stress")
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    fn = _GatedFn()
+    out = []
+    try:
+        t = threading.Thread(
+            target=lambda: out.extend(
+                fetch_ordered(range(3000), fn, p, window=5, ahead=7)),
+            daemon=True)
+        t.start()
+        t.join(timeout=60)
+        assert not t.is_alive()
+    finally:
+        sys.setswitchinterval(old)
+        p.shutdown(wait=True)
+    assert out == [(i, i * 10) for i in range(3000)]
+    assert fn.max_running <= 5
+
+
+def test_dedup_scan_fetches_ahead_inside_threads(tmp_path, capsys,
+                                                 monkeypatch):
+    """`gc --dedup --threads 3` over 80 blocks (three hash batches): never
+    more than 3 GETs at once, more than 3 blocks fetched before the hash
+    takes them, and digests and index rows as with `--threads 1`."""
+    import json
+
+    from juicefs_tpu.cmd import main, open_meta
+    from test_trace import _scan_volume
+
+    meta_url = _scan_volume(tmp_path, blocks=80, block_kib=64)
+    state = {"cur": 0, "max": 0}
+    lock = threading.Lock()
+    real = CachedStore._load_block
+
+    def counted(self, key, size, **kw):
+        with lock:
+            state["cur"] += 1
+            state["max"] = max(state["max"], state["cur"])
+        try:
+            time.sleep(0.002)
+            return real(self, key, size, **kw)
+        finally:
+            with lock:
+                state["cur"] -= 1
+
+    monkeypatch.setattr(CachedStore, "_load_block", counted)
+
+    def scan(threads):
+        index = str(tmp_path / f"index{threads}.json")
+        capsys.readouterr()
+        assert main(["gc", meta_url, "--dedup", "--hash-backend", "xla",
+                     "--threads", str(threads), "--dedup-index", index]) == 0
+        stats = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+        m, _ = open_meta(meta_url)
+        rows = sorted(m.scan_block_digests())
+        with open(index) as f:
+            return stats, rows, json.load(f), m
+
+    stats, rows, digests, m = scan(3)
+    assert stats["hashed_now"] == 80 and len(rows) == 80
+    assert stats["fetch_window"] == 3
+    assert stats["fetch_ahead"] == 32  # the pipeline's batch_blocks
+    assert 2 <= state["max"] <= 3
+    m.delete_block_digests([(sid, indx) for sid, indx, _, _ in rows])
+    state["max"] = 0
+    stats1, rows1, digests1, _ = scan(1)
+    assert stats1["hashed_now"] == 80 and stats1["fetch_window"] == 1
+    assert state["max"] == 1
+    assert rows1 == rows and digests1 == digests

@@ -778,6 +778,29 @@ def test_pack_span_says_whether_its_buffer_was_new(depth, fresh):
     assert sum(e["padded_bytes"] for e in evs if e["fresh"]) == counted.value - c0
 
 
+@pytest.mark.parametrize("library", [True, False])
+def test_pack_span_says_which_pack_ran(library, monkeypatch):
+    """`native` = 1 when libjfscore is loaded (a batch's rows go in one
+    call outside the interpreter lock), 0 when numpy copies row by row: a
+    run without a compiler shows it in its trace."""
+    from juicefs_tpu import native
+    from juicefs_tpu.tpu.pipeline import HashPipeline, PipelineConfig
+
+    if library and not native.available():
+        pytest.skip("no libjfscore here")
+    if not library:
+        monkeypatch.setattr(native, "_load", lambda: None)
+    pipe = HashPipeline(PipelineConfig(backend="xla", batch_blocks=2,
+                                       pad_lanes=1))
+    blocks = [os.urandom(n) for n in (5, 70, 900)]
+    with _reader() as r:
+        out = pipe.hash_blocks(blocks)
+        evs = [e for e in r.drain() if e.get("stage") == "pack"]
+    assert [e["native"] for e in evs] == [int(library)] * 2
+    monkeypatch.undo()
+    assert out == [native.jth256(b) for b in blocks]
+
+
 def test_hash_packed_gets_h2d_and_enqueue_without_a_pack():
     """The indexer's entry packs for itself: its dispatch span has the
     plane's h2d and enqueue below it and no pack."""
@@ -1001,9 +1024,17 @@ def test_no_reader_overhead_under_5pct(vfs):
     gc.disable()
     try:
         # more attempts, same bar: on a small container the full
-        # suite's background pools can inflate both of the first
-        # attempts; the minimum over 5 finds a quiet window
-        runs = [measure() for _ in range(5)]
+        # suite's background pools can inflate the first attempts; the
+        # statistic is a minimum, so go on measuring until one attempt
+        # finds a quiet window, fifteen at most (it read 2.3-2.8 us of the
+        # 3 on a quiet machine and failed beside a loaded suite, ISSUE 32)
+        runs = []
+        while len(runs) < 15:
+            runs.append(measure())
+            if len(runs) >= 5 and min(
+                    min(on / off - 1.05, (on - off) / N - 3e-6)
+                    for on, off in runs) < 0:
+                break
     finally:
         gc.enable()
     ratio = min(on / off for on, off in runs)
