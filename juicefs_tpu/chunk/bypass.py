@@ -1,11 +1,12 @@
 """Adaptive elision bypass (ISSUE 8): sample the live dedup hit rate and
 stop paying for hash+lookup when duplicate density is low.
 
-Inline dedup (chunk/ingest.py) is a pure win when duplicates exist
-(dup-0.3 -> 1.15x, dup-0.7 -> 1.95x, BENCH_r06) but a measured 0.80x
-REGRESSION on a zero-duplicate workload: every block pays hashing, a
-content-ref lookup, and the batch-barrier latency with nothing ever
-elided. The governor makes the stage self-tuning:
+Inline dedup (chunk/ingest.py) wins when duplicates exist (every elided
+block is a PUT not made) and loses on a zero-duplicate workload: every
+block pays hashing, a content-ref lookup, and the batch-barrier latency
+with nothing ever elided (host-clock readings of an earlier round; no
+cell measures it on the chip yet, PERF.md §7). The governor makes the
+stage self-tuning:
 
     SAMPLE   every block runs the full dedup path; each outcome
              (hit=elided / miss) lands in a sliding window. Startup

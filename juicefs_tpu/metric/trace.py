@@ -42,7 +42,7 @@ from typing import Optional
 from . import global_registry
 
 __all__ = ["NULL_SPAN", "Tracer", "global_tracer", "span_name",
-           "span_summary", "stage_hist", "stage_metrics_snapshot"]
+           "span_summary", "stage_hist"]
 
 MAX_BUFFERED_EVENTS = 10240
 
@@ -57,34 +57,6 @@ def stage_hist(layer: str, op: str, stage: str = "total"):
     """Pre-resolve one (layer, op, stage) histogram child for hot paths
     (labels() does a locked dict lookup; call sites bind once)."""
     return _STAGE_SECONDS.labels(layer, op, stage)
-
-
-def stage_metrics_snapshot() -> dict:
-    """Compact {layer.op.stage: {count, sum_seconds}} dump of the stage
-    rollup (bench.py attaches this to its JSON line). The object layer's
-    per-backend request histogram is folded in as object.<method>.<backend>
-    so the snapshot attributes every stage without double-observing on the
-    object hot path."""
-    out = {}
-
-    def collect(hist, keyfn):
-        with hist._lock:
-            children = list(hist._children.values())
-        for c in children:
-            out[keyfn(c._label_dict())] = {
-                "count": c.total, "sum_seconds": round(c.sum, 6),
-            }
-
-    collect(_STAGE_SECONDS,
-            lambda l: f"{l.get('layer')}.{l.get('op')}.{l.get('stage')}")
-    obj = global_registry()._metrics.get(
-        "juicefs_object_request_durations_histogram_seconds"
-    )
-    if obj is not None:
-        collect(obj,
-                lambda l: f"object.{l.get('method', '?').lower()}"
-                          f".{l.get('backend', '?')}")
-    return out
 
 
 def span_name(layer, op, stage="") -> str:

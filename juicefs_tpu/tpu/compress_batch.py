@@ -3,8 +3,9 @@
 The third stage of the north-star triad (PAPER.md §7: device-batched
 hashing + dedup scan + LZ4/Zstd compression behind the chunk-store
 boundary). Hashing and the dedup scan went device-batched in PRs 3-5;
-compression stayed serial ctypes-liblz4 inside each upload worker, and
-BENCH_r06 showed it burning ~1.7-1.9 s of a ~2.1-2.6 s ingest.
+compression stayed serial ctypes-liblz4 inside each upload worker, where
+it took most of an ingest's wall time (a host-clock reading of an earlier
+round; no chip figure — speed is in PERF.md).
 
 `CompressPlane` mirrors the `HashPipeline` backend-registry contract
 (`cpu | xla`, tpu/pipeline.py):

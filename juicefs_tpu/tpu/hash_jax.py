@@ -27,14 +27,10 @@ from jax import lax
 from .jth256 import (
     COLS,
     IV,
-    LANE_BYTES,
     ROWS,
     digests_to_bytes,
     pack_blocks,
 )
-# the reference hash FUNCTION (the package re-exports the name `jth256`,
-# shadowing the submodule attribute — import the callable directly)
-from .jth256 import jth256 as _jth256_ref
 
 # Plain ints here: wrapping them in jnp.uint32 at module scope would
 # initialize a JAX backend at import time, breaking accelerator-free
@@ -88,7 +84,7 @@ def _row_chain_scan(words: jax.Array, s0: jax.Array) -> jax.Array:
 
 
 def _lane_states(words: jax.Array, lane_offset=0) -> jax.Array:
-    """Initial row-chain states. lane_offset shifts the per-lane tweak so a
+    """Initial row-chain states. lane_offset shifts the per-lane term so a
     lane-sharded device computes with its *global* lane indices."""
     b, m = words.shape[0], words.shape[1]
     j = jnp.arange(COLS, dtype=jnp.uint32)
@@ -191,28 +187,20 @@ def last_pallas_mode() -> str | None:
 
 
 def _pallas_row_chain(
-    words_flat: jax.Array, m: int, tweak: jax.Array, unroll: int = 8,
-    interpret: bool = False, lane_group: int | None = None,
+    words_flat: jax.Array, m: int, unroll: int = 8, interpret: bool = False,
 ) -> jax.Array:
     """words_flat (L, 128, 128) -> lane states (L, 128); L = B*M lanes.
 
-    One grid step keeps `lane_group` lane tiles (x 64 KiB) resident in
+    One grid step keeps `_LANE_GROUP` lane tiles (x 64 KiB) resident in
     VMEM and runs their row chains together; the Pallas pipeline
     double-buffers the HBM->VMEM streaming across grid steps.
-
-    `tweak` (uint32 (1,), in SMEM) is xor'ed into every word INSIDE the
-    kernel: a caller that hashes the same resident batch repeatedly can
-    vary the input per iteration without materializing a tweaked copy in
-    HBM (pallas_call is opaque to XLA fusion, so `words ^ k` outside the
-    kernel costs one extra HBM write+read per pass). Zero hashes the
-    words as they are, which is what every served path passes.
     """
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    group = lane_group or _LANE_GROUP
+    group = _LANE_GROUP
 
-    def kernel(t_ref, w_ref, out_ref):
+    def kernel(w_ref, out_ref):
         # Constants are rebuilt from Python ints here: a pallas kernel may
         # not close over device arrays created outside the trace.
         p1, p2, p3, p5 = (
@@ -221,7 +209,6 @@ def _pallas_row_chain(
             jnp.uint32(0xC2B2AE3D),
             jnp.uint32(0x165667B1),
         )
-        tw = t_ref[0]
         i = pl.program_id(0)
         u8 = jax.lax.broadcasted_iota(jnp.uint32, (group, 1), 0)
         lane = jax.lax.rem(jnp.uint32(i * group) + u8, jnp.uint32(m))
@@ -230,7 +217,7 @@ def _pallas_row_chain(
 
         def body(r, s):
             for u in range(unroll):
-                w = w_ref[:, r * unroll + u, :] ^ tw
+                w = w_ref[:, r * unroll + u, :]
                 s = (s ^ w) * p1
                 s = ((s << jnp.uint32(13)) | (s >> jnp.uint32(19))) * p2
                 s = s ^ (s >> jnp.uint32(15))
@@ -250,7 +237,6 @@ def _pallas_row_chain(
         out_shape=jax.ShapeDtypeStruct((padded, COLS), jnp.uint32),
         grid=(padded // group,),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec(
                 (group, ROWS, COLS),
                 lambda i: (i, 0, 0),
@@ -259,25 +245,24 @@ def _pallas_row_chain(
         ],
         out_specs=pl.BlockSpec((group, COLS), lambda i: (i, 0)),
         interpret=interpret,
-    )(tweak, words_flat)
+    )(words_flat)
     return out[:n_lanes]
 
 
 def _hash_packed_pallas_impl(
     words: jax.Array, lane_counts: jax.Array, lengths: jax.Array,
-    tweak: jax.Array, interpret: bool, lane_group: int | None = None,
+    interpret: bool,
 ) -> jax.Array:
     b, m = words.shape[0], words.shape[1]
     s = _pallas_row_chain(
-        words.reshape(b * m, ROWS, COLS), m, tweak, interpret=interpret,
-        lane_group=lane_group,
+        words.reshape(b * m, ROWS, COLS), m, interpret=interpret,
     ).reshape(b, m, COLS)
     return _finish(s, lane_counts, lengths)
 
 
 _hash_packed_pallas_impl = named_jit(
     "jth256_hash_pallas", _hash_packed_pallas_impl,
-    static_argnames=("interpret", "lane_group"))
+    static_argnames=("interpret",))
 
 
 def hash_packed_pallas(
@@ -285,27 +270,16 @@ def hash_packed_pallas(
     lane_counts: jax.Array,
     lengths: jax.Array,
     interpret: bool | None = None,
-    tweak: jax.Array | None = None,
-    lane_group: int | None = None,
 ) -> jax.Array:
     """Pallas path: (B, M, 128, 128) uint32 -> (B, 8) uint32 digests.
 
     interpret=None resolves via pallas_interpret_active(); the resolved mode
     is recorded for last_pallas_mode() so callers can assert a compiled run.
-    tweak xors a scalar into every input word inside the kernel (vary a
-    resident batch without an HBM copy); None/0 hashes the words as-is.
     """
     global _LAST_PALLAS_MODE
     mode = pallas_interpret_active() if interpret is None else interpret
     _LAST_PALLAS_MODE = "interpret" if mode else "compiled"
-    if tweak is None:
-        tweak = jnp.zeros((1,), jnp.uint32)
-    else:
-        tweak = tweak.reshape((1,)).astype(jnp.uint32)
-    return _hash_packed_pallas_impl(
-        words, lane_counts, lengths, tweak, interpret=mode,
-        lane_group=lane_group,
-    )
+    return _hash_packed_pallas_impl(words, lane_counts, lengths, interpret=mode)
 
 
 _IMPLS = {"xla": hash_packed_jax, "pallas": hash_packed_pallas}
@@ -330,15 +304,3 @@ def hash_blocks_jax(
     fn = make_hash_fn(impl)
     out = np.asarray(jax.device_get(fn(words, counts, lengths)))
     return digests_to_bytes(out)
-
-
-def verify_backend(impl: str = "xla", seed: int = 0) -> bool:
-    """Self-check: device digests byte-identical to the numpy reference."""
-    rng = np.random.default_rng(seed)
-    blocks = [
-        rng.integers(0, 256, size=n, dtype=np.uint8).tobytes()
-        for n in (0, 1, 100, LANE_BYTES, LANE_BYTES + 7, 3 * LANE_BYTES)
-    ]
-    dev = hash_blocks_jax(blocks, impl=impl)
-    ref = [_jth256_ref(b) for b in blocks]
-    return dev == ref

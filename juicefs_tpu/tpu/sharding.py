@@ -99,10 +99,9 @@ def make_mesh(
 
 
 def _scan_body(words, lane_counts, lengths):
-    """The shared per-device scan body (used by the one-shot step and the
-    fused benchmark loop — one definition, no drift): row chains on local
-    lanes, gather tiny per-lane digests across the lane axis, combine,
-    gather 32 B/block digests across data, dedup."""
+    """The per-device scan body: row chains on local lanes, gather tiny
+    per-lane digests across the lane axis, combine, gather 32 B/block
+    digests across data, dedup."""
     all_digests = _hash_body(words, lane_counts, lengths)
     dup, first = dedup_scan_jax(all_digests)
     return all_digests, dup, first
@@ -139,35 +138,6 @@ def sharded_scan_step(mesh: Mesh):
         check_vma=False,
     )
     return named_jit("jth256_scan_sharded", mapped)
-
-
-def sharded_scan_many(mesh: Mesh):
-    """Multi-iteration sharded scan as ONE device program (the
-    device-resident form: one dispatch covers `iters` passes, so host
-    dispatch latency is paid once). Each iteration hashes a tweaked copy
-    of the resident batch — the xor fuses into the first read — and the
-    collectives (digest-sized only) repeat per iteration.
-
-    Returns jit(fn(words, lane_counts, lengths, iters) -> uint32 checksum).
-    """
-
-    def many(words, lane_counts, lengths, iters):
-        def body(k, acc):
-            all_d, dup, _first = _scan_body(
-                words ^ k.astype(jnp.uint32), lane_counts, lengths
-            )
-            return acc ^ all_d.sum(dtype=jnp.uint32) ^ dup.sum().astype(jnp.uint32)
-
-        return lax.fori_loop(jnp.uint32(0), iters, body, jnp.uint32(0))
-
-    mapped = jax.shard_map(
-        many,
-        mesh=mesh,
-        in_specs=(P("data", "lane", None, None), P("data"), P("data"), P()),
-        out_specs=P(),
-        check_vma=False,
-    )
-    return jax.jit(mapped)
 
 
 def shard_batch(mesh: Mesh, words, lane_counts, lengths):

@@ -78,8 +78,8 @@ def test_ingest_seam_lint():
 
 
 def test_qos_registry_pinned():
-    """The juicefs_qos_* series the chaos drill and BENCH_r07 counter-
-    assert must all exist; nothing squats under the prefix."""
+    """The juicefs_qos_* series the chaos drill and tests/test_qos.py
+    counter-assert must all exist; nothing squats under the prefix."""
     lint = _load_lint()
     assert lint.lint_qos() == []
     from juicefs_tpu.metric import Registry

@@ -12,9 +12,9 @@ from __future__ import annotations
 
 from ..core import Finding, Pass, SourceFile
 
-# pinned per-subsystem series (ISSUE 4/5/6 contracts): tests and the
-# BENCHMARKS tables counter-assert these — a rename must fail CI, not
-# silently zero a dashboard
+# pinned per-subsystem series (ISSUE 4/5/6 contracts): tests
+# counter-assert these — a rename must fail CI, not silently zero a
+# dashboard
 CACHE_GROUP_PREFIX = "juicefs_cache_group_"
 CACHE_GROUP_EXPECTED = {
     "juicefs_cache_group_peer_hits",
@@ -159,8 +159,8 @@ INDEX_EXPECTED = {
 META_WBATCH_PREFIX = "juicefs_meta_wbatch_"
 META_WBATCH_EXPECTED = {
     # checkpoint write plane (ISSUE 13, meta/wbatch.py): the
-    # batched/drained ratio is the group-commit amortization the
-    # BENCH_r11 acceptance counter-asserts
+    # batched/drained ratio is the group-commit amortization (the
+    # same two counts tests/test_wbatch.py holds through stats())
     "juicefs_meta_wbatch_batched",
     "juicefs_meta_wbatch_drained",
     "juicefs_meta_wbatch_barrier_flushes",
