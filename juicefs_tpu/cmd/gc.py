@@ -74,8 +74,10 @@ def run(args) -> int:
     trace_dir = getattr(args, "trace", "")
     with (_ScanTrace(trace_dir) if trace_dir
           else contextlib.nullcontext()) as trace:
-        with _TR.span("cmd", "gc", hist=_H_GC) as root:
-            stats = _gc(args, trace, root)
+        # what the invocation started and has to end whatever happens
+        with _TR.span("cmd", "gc", hist=_H_GC) as root, \
+                contextlib.ExitStack() as at_exit:
+            stats = _gc(args, trace, root, at_exit)
     # after the root has closed: its own row belongs in the table
     if stats is not None:
         if trace is not None:
@@ -133,32 +135,42 @@ class _ScanTrace:
         return False
 
 
-def _gc(args, trace: "_ScanTrace | None", root) -> dict | None:
+def _gc(args, trace: "_ScanTrace | None", root,
+        at_exit: contextlib.ExitStack) -> dict | None:
     """The invocation below its root span; returns the --dedup stats
     (which `run` prints), else None."""
     from . import build_store, open_meta
 
     with _TR.span("cmd", "gc", stage="open", hist=_H_OPEN):
         m, fmt = open_meta(args.meta_url)
+        bs = fmt.block_size * 1024
         # the flag's name and the volume's take the same road:
         # tpu/device.py resolves either, and `tpu` without a TPU fails
         # here — before the name diff has listed a single object
         backend = args.hash_backend or fmt.hash_backend
-        on_device = False
+        pipe = None
         if args.dedup:
-            from ..tpu.device import resolve_backend
             from ..utils.malloc import keep_freed_blocks
 
-            on_device = resolve_backend(backend) != "cpu"
             # this process is a bulk scan from here on: the allocator
             # recycles block-sized buffers (utils/malloc.py); before the
             # store is built, so before the first GET
             keep_freed_blocks()
+            # The scan's pipeline, here and not where the hashing starts:
+            # the sizes of its pack buffers are known now, and announcing
+            # the stream lets helper threads fault them in while this
+            # thread lists the volume (docs/ARCHITECTURE.md "The scan's
+            # host memory"). A scan that finds nothing to hash lets go of
+            # them unused; whatever happens, nothing is prepared past
+            # this invocation.
+            pipe = _scan_pipeline(backend, bs)
+            at_exit.callback(pipe.release)
+            pipe.prepare()
+        on_device = pipe is not None and pipe.device_backend
         # meta-attached store: dedup-scan reads of PUT-elided blocks
         # resolve through the content-ref plane (ISSUE 5). No indexer: gc
         # backfills digest rows itself through dedup_scan's own pipeline.
         store = build_store(fmt, args, meta=m, with_indexer=False)
-    bs = fmt.block_size * 1024
     if trace is not None and on_device:
         trace.start_profiler()
 
@@ -236,7 +248,7 @@ def _gc(args, trace: "_ScanTrace | None", root) -> dict | None:
     if not args.dedup:
         return None
     stats = dedup_scan(m, store, live, backend, args.dedup_index, bs,
-                       threads=args.threads)
+                       threads=args.threads, pipe=pipe)
     # offline complement of the inline ingest stage: repair refcounts
     # left by crash windows, register existing content so future
     # writes elide, and (with --delete) collapse duplicate objects
@@ -251,9 +263,24 @@ def _gc(args, trace: "_ScanTrace | None", root) -> dict | None:
     return stats
 
 
+def _scan_pipeline(backend: str, block_size: int):
+    """The hash pipeline of a scan over blocks of up to `block_size` bytes:
+    one program for the whole stream, every block padded to the volume's
+    block."""
+    from ..tpu.pipeline import HashPipeline, PipelineConfig
+
+    return HashPipeline(PipelineConfig(
+        backend=backend, pad_lanes=max(1, block_size // 65536)))
+
+
 def dedup_scan(meta, store, live: dict[str, int], backend: str,
-               index_path: str, block_size: int, threads: int = 8) -> dict:
+               index_path: str, block_size: int, threads: int = 8,
+               pipe=None) -> dict:
     """Content-dedup scan over all live blocks.
+
+    `pipe` is the `HashPipeline` to hash through, where the caller built
+    one ahead (`gc` does, to announce the stream before it lists the
+    volume); without it one is built here for `backend` and `block_size`.
 
     Incremental: digests recorded by the write path (meta content index,
     kv.py `B` keys) are trusted as-is; only blocks missing from the index
@@ -278,7 +305,6 @@ def dedup_scan(meta, store, live: dict[str, int], backend: str,
     from ..chunk.parallel import FetchStats, fetch_ordered
     from ..tpu.dedup import dedup_digests
     from ..tpu.jth256 import digest_hex
-    from ..tpu.pipeline import HashPipeline, PipelineConfig
 
     t0 = _time.perf_counter()
     # 1. load the persistent index; prune rows for dead slices
@@ -298,9 +324,8 @@ def dedup_scan(meta, store, live: dict[str, int], backend: str,
 
     # 2. hash only blocks the write path didn't index; backfill their rows
     missing = [k for k in live if k not in digest_by_key]
-    pipe = HashPipeline(
-        PipelineConfig(backend=backend, pad_lanes=max(1, block_size // 65536))
-    )
+    if pipe is None:
+        pipe = _scan_pipeline(backend, block_size)
     window = max(1, threads)
     # what hash_stream takes between two stretches of its own work
     ahead = pipe.config.batch_blocks

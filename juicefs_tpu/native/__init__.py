@@ -15,6 +15,7 @@ Exports:
     jth256(data) -> 32B digest     C++ JTH-256, byte-identical to the spec
     jth256_batch(blocks, threads)  multithreaded batch hash
     pack_rows(blocks, rows) -> bool  a hash batch's rows in one call
+    touch_pages(arr) -> bool       first-touch an array's pages, lock-free
     available() -> bool
 """
 
@@ -33,6 +34,7 @@ from ..utils import get_logger
 logger = get_logger("native")
 
 _DIR = os.path.dirname(__file__)
+_PAGE = os.sysconf("SC_PAGE_SIZE")
 _SRC = os.path.join(_DIR, "jfscore.cpp")
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
@@ -118,6 +120,11 @@ def _load() -> Optional[ctypes.CDLL]:
                 ctypes.c_size_t,
                 ctypes.c_void_p,
                 ctypes.c_size_t,
+            ]
+            lib.jfs_touch_pages.restype = ctypes.c_size_t
+            lib.jfs_touch_pages.argtypes = [
+                ctypes.c_void_p, ctypes.c_size_t, ctypes.c_size_t,
+                ctypes.POINTER(ctypes.c_int),
             ]
             if lib.jfs_abi_version() != 1:
                 raise OSError("jfscore ABI mismatch")
@@ -211,3 +218,19 @@ def pack_rows(blocks: Sequence[bytes], rows) -> bool:
         return False
     lib.jfs_pack_rows(arr, lens, len(blocks), rows.ctypes.data, rows.shape[1])
     return True
+
+
+def touch_pages(arr, stop: "ctypes.c_int | None" = None) -> Optional[int]:
+    """One store in every page of `arr` (a C-contiguous writeable numpy
+    array whose contents nobody needs), in ONE call outside the interpreter
+    lock: afterwards its memory is resident and a pack into it pays no page
+    fault. `stop` is a `ctypes.c_int` the call looks at once a MiB: whoever
+    sets its `value` to 1 ends the call early. Returns the bytes touched;
+    None, with nothing touched, when there is no library."""
+    if not (arr.flags.c_contiguous and arr.flags.writeable):
+        raise ValueError("touch_pages wants a C-contiguous writeable array")
+    lib = _load()
+    if lib is None:
+        return None
+    return lib.jfs_touch_pages(arr.ctypes.data, arr.nbytes, _PAGE,
+                               ctypes.byref(stop) if stop is not None else None)

@@ -32,6 +32,8 @@ void jfs_jth256_batch(const uint8_t *const *blocks, const size_t *lens,
                       size_t count, uint8_t *outs, int threads);
 void jfs_pack_rows(const uint8_t *const *blocks, const size_t *lens,
                    size_t count, uint8_t *rows, size_t row_bytes);
+size_t jfs_touch_pages(uint8_t *p, size_t n, size_t page,
+                       const volatile int *stop);
 int jfs_abi_version();
 }
 
@@ -226,4 +228,20 @@ void jfs_pack_rows(const uint8_t *const *blocks, const size_t *lens,
     memcpy(row, blocks[i], lens[i]);
     memset(row + lens[i], 0, row_bytes - lens[i]);
   }
+}
+
+// Make [p, p + n) resident before somebody packs into it: one store a
+// page, in one call outside the interpreter lock (a helper thread that came
+// back for the lock between slices would queue 5 ms behind a busy Python
+// thread every time). The bytes are the caller's to overwrite (pack_rows
+// writes every row whole), so what is stored is moot. `stop`, where given,
+// is looked at once a MiB: set, the call returns early. Returns how many
+// bytes from p on are resident now.
+size_t jfs_touch_pages(uint8_t *p, size_t n, size_t page,
+                       const volatile int *stop) {
+  for (size_t i = 0; i < n; i += page) {
+    if (stop && (i & ((1u << 20) - 1)) == 0 && *stop) return i;
+    p[i] = 0;
+  }
+  return n;
 }

@@ -11,6 +11,11 @@ Each batch is one `tpu.hash.dispatch` span with the children `pack` (here),
 `h2d` and `enqueue` (tpu/sharding.py, where the transfer and the jitted
 call live), then one `tpu.hash.drain` when its digests are read back.
 
+A caller that knows a stream is coming says so (`HashPipeline.prepare()`):
+helper threads then make the stream's pack buffers resident while the
+caller does something else, one `tpu.pack.prepare` span a buffer, and
+`hash_stream` packs into those instead of first-touching its own.
+
 Backend selection mirrors the reference's Compressor registry pattern
 (pkg/compress/compress.go:31-49): "cpu" (C++/numpy host hash), "xla",
 "pallas", and "tpu" (the xla program, on a TPU or not at all). Names are
@@ -20,7 +25,10 @@ resolved by tpu/device.py; a device backend that cannot initialise raises
 
 from __future__ import annotations
 
+import ctypes
 import inspect
+import os
+import threading
 import time
 from dataclasses import dataclass
 from typing import Iterable, Iterator, Optional
@@ -29,13 +37,18 @@ import numpy as np
 
 from ..metric import global_registry
 from ..metric.trace import global_tracer, stage_hist
+from ..utils import get_logger
 from .jth256 import (
     BLOCK_BYTES,
+    COLS,
     LANE_BYTES,
+    ROWS,
     digests_to_bytes,
     hash_packed_np,
     pack_blocks,
 )
+
+logger = get_logger("tpu.pipeline")
 
 _reg = global_registry()
 _BLOCKS_HASHED = _reg.counter(
@@ -51,8 +64,14 @@ _H2D_BYTES = _reg.counter(
 _PACK_FRESH_BYTES = _reg.counter(
     "juicefs_tpu_pack_fresh_bytes",
     "Packed bytes of hash batches written into a pack buffer on its first "
-    "use (hash_stream had just made it): the part of juicefs_tpu_h2d_bytes "
-    "whose pack paid first-touch page faults",
+    "use: the part of juicefs_tpu_h2d_bytes that no kept buffer took",
+)
+_PACK_UNREADY_BYTES = _reg.counter(
+    "juicefs_tpu_pack_unready_bytes",
+    "Packed bytes of hash batches whose pack buffer was not resident when "
+    "the pack came: nobody had announced the stream (prepare()), or the "
+    "pack waited for the preparer or took the buffer over; the part of "
+    "juicefs_tpu_pack_fresh_bytes whose pack still paid for its pages",
 )
 _BATCH_BLOCKS = _reg.histogram(
     "juicefs_tpu_batch_blocks", "Blocks per dispatched hash batch",
@@ -74,6 +93,133 @@ _TR = global_tracer()
 _H_DISPATCH = stage_hist("tpu", "hash", "dispatch")
 _H_PACK = stage_hist("tpu", "hash", "pack")
 _H_DRAIN = stage_hist("tpu", "hash", "drain")
+_H_PREPARE = stage_hist("tpu", "pack", "prepare")
+
+# Threads that fault one pack buffer in, each on its own part. On the chip
+# machine one thread takes 132 ms for 128 MiB and leaves pages that cost the
+# next writer 1.5 us each again; four take 59 ms and leave them whole
+# (tools/prefault_probe.py; PERF.md section 6), and two buffers in turn are
+# both there before a scan's listing ends.
+_PREPARE_THREADS = 4
+_PREPARE_SLICE = 4 << 20  # numpy's touch looks at the stop flag this often
+
+
+def _touch(part: np.ndarray, stop: ctypes.c_int) -> str:
+    """First-touch every page of `part` (uint8, contiguous) unless `stop`
+    is set on the way; says how."""
+    from .. import native
+
+    if native.touch_pages(part, stop) is not None:
+        return "native"
+    for at in range(0, part.size, _PREPARE_SLICE):
+        if stop.value:
+            break
+        part[at:at + _PREPARE_SLICE:4096] = 0
+    return "numpy"
+
+
+class _PreparedBuffers:
+    """The pack buffers of a stream that was announced before it began:
+    allocated and made resident by a thread of their own, one after the
+    other, and handed to `hash_stream` in that order. What a buffer holds
+    is whatever the touch left: `pack_blocks(out=)` writes every row whole.
+
+    A slot is `waiting` (not begun: the stream that wants it now takes it
+    over and packs fresh, as if nothing had been prepared), `touching`
+    (the stream waits for the remainder: the pages are faulted once) or
+    `ready`. `stop()` ends the preparing within a MiB's touch and lets go
+    of every buffer; nobody joins the thread."""
+
+    def __init__(self, shape: tuple, count: int, parent):
+        self.shape = shape
+        self._parent = parent  # the announcer's span: one trace tree
+        self._stop = ctypes.c_int(0)
+        self._lock = threading.Lock()
+        self._slots = [{"state": "waiting", "buf": None,
+                        "done": threading.Event()} for _ in range(count)]
+        threading.Thread(target=self._run, args=(list(self._slots),),
+                         name="jfs-pack-prepare", daemon=True).start()
+
+    def _run(self, slots) -> None:
+        try:
+            for slot in slots:
+                with self._lock:
+                    if self._stop.value:
+                        return
+                    if slot["state"] != "waiting":
+                        continue  # the stream came first and packed fresh
+                    slot["state"] = "touching"
+                with _TR.span("tpu", "pack", stage="prepare", hist=_H_PREPARE,
+                              parent=self._parent) as sp:
+                    buf = np.empty(self.shape, dtype="<u4")
+                    how = self._make_resident(buf)
+                    if sp.active:
+                        sp.set(bytes=buf.nbytes, how=how,
+                               stopped=int(bool(self._stop.value)))
+                with self._lock:
+                    if not self._stop.value:
+                        slot["buf"], slot["state"] = buf, "ready"
+                del buf
+                slot["done"].set()
+        except Exception:
+            # the stream packs fresh, as it would have without us
+            logger.exception("preparing pack buffers failed")
+        finally:
+            for slot in slots:  # nobody waits for a preparer that is gone
+                slot["done"].set()
+
+    def _make_resident(self, buf: np.ndarray) -> str:
+        """Every page of `buf` touched, by this thread and helpers of its
+        own on disjoint parts (plain threads for the length of one buffer:
+        this is no I/O for the scheduler's lanes to order)."""
+        flat = buf.reshape(-1).view(np.uint8)
+        n = max(1, min(_PREPARE_THREADS, os.cpu_count() or 1))
+        step = -(-flat.size // n // 4096) * 4096
+        parts = [flat[at:at + step] for at in range(0, flat.size, step)]
+        failed = []
+
+        def helper(part):
+            try:
+                _touch(part, self._stop)
+            except Exception as e:
+                failed.append(e)
+
+        helpers = [threading.Thread(target=helper, args=(part,),
+                                    name="jfs-pack-prepare", daemon=True)
+                   for part in parts[1:]]
+        for t in helpers:
+            t.start()
+        try:
+            how = _touch(parts[0], self._stop)
+        finally:
+            for t in helpers:
+                t.join()
+        if failed:
+            raise failed[0]
+        return how
+
+    def take(self) -> "tuple[np.ndarray, bool] | None":
+        """The next buffer and whether it was ready when asked for; None
+        once there is none to have (all taken, not begun, failed)."""
+        with self._lock:
+            if not self._slots:
+                return None
+            slot = self._slots.pop(0)
+            state = slot["state"]
+            if state == "waiting":
+                slot["state"] = "taken"
+                return None
+        if state == "touching":
+            slot["done"].wait()
+        buf = slot["buf"]
+        return None if buf is None else (buf, state == "ready")
+
+    def stop(self) -> None:
+        with self._lock:
+            self._stop.value = 1
+            for slot in self._slots:
+                slot["buf"] = None
+            self._slots = []
 
 
 @dataclass
@@ -104,6 +250,7 @@ class HashPipeline:
         self.config.backend = resolve_backend(self.requested)
         self._fn = None
         self._plane = None
+        self._prepared: _PreparedBuffers | None = None
         # wall time of the first device batch, dispatch to digests: it
         # carries the compilation, so consumers report it apart from rate
         self.first_batch_seconds: float | None = None
@@ -130,6 +277,26 @@ class HashPipeline:
             self.first_batch_seconds = time.perf_counter() - t0
             _FIRST_BATCH.set(self.first_batch_seconds)
 
+    def _buffer_shape(self) -> tuple:
+        return (self.config.batch_blocks, self.config.pad_lanes, ROWS, COLS)
+
+    def prepare(self) -> None:
+        """A stream is coming: have its pack buffers resident by the time
+        it packs, without holding the caller up (`_PreparedBuffers`). The
+        next `hash_stream` takes them; `release()` is for the caller whose
+        stream may never come. Nothing to prepare on the `cpu` backend,
+        which packs nothing."""
+        if self._fn is not None and self._prepared is None:
+            self._prepared = _PreparedBuffers(
+                self._buffer_shape(),
+                max(1, self.config.max_inflight_batches), _TR.current_ref())
+
+    def release(self) -> None:
+        """Stop preparing and let go of what was prepared and not taken."""
+        prepared, self._prepared = self._prepared, None
+        if prepared is not None:
+            prepared.stop()
+
     def hash_stream(
         self, items: Iterable[tuple[str, bytes]]
     ) -> Iterator[tuple[str, bytes]]:
@@ -143,22 +310,36 @@ class HashPipeline:
         # its batch's digests are here: until then the device may read the
         # host words (the CPU backend's device_put can alias them, a TPU's
         # may still be copying when it returns). At most
-        # max_inflight_batches of them, none outliving the stream.
+        # max_inflight_batches of them, none outliving the stream. Where
+        # the stream was announced (prepare()) its first packs take the
+        # buffers made ready for it instead of making their own.
         free: list[np.ndarray] = []
         # the module's name is a seam others stand functions in; under one
         # that takes no `out` every batch packs fresh, as before
         params = inspect.signature(pack_blocks).parameters.values()
         keeps = any(p.name == "out" or p.kind is p.VAR_KEYWORD for p in params)
+        prepared, self._prepared = self._prepared, None
+        if prepared is not None and not (
+                keeps and prepared.shape == self._buffer_shape()):
+            prepared.stop()
+            prepared = None
 
         def pack(blocks):
-            # a buffer has the rows of its first batch: only a stream's
-            # last batch is shorter than batch_blocks, and nothing follows
+            """-> packed, the buffer to keep, first use of it, was ready"""
+            # a buffer has the rows of its first batch (a prepared one
+            # batch_blocks): only a stream's last batch is shorter than
+            # batch_blocks, and nothing follows
             if free:
                 buf = free.pop()
                 return pack_blocks(blocks, pad_lanes=cfg.pad_lanes,
-                                   out=buf), buf, False
+                                   out=buf), buf, False, True
+            got = prepared.take() if prepared is not None else None
+            if got is not None:
+                buf, ready = got
+                return pack_blocks(blocks, pad_lanes=cfg.pad_lanes,
+                                   out=buf), buf, True, ready
             packed = pack_blocks(blocks, pad_lanes=cfg.pad_lanes)
-            return packed, packed[0] if keeps else None, True
+            return packed, packed[0] if keeps else None, True, False
 
         def dispatch():
             nonlocal keys, blocks
@@ -182,16 +363,21 @@ class HashPipeline:
                 else:
                     with _TR.span("tpu", "hash", stage="pack",
                                   hist=_H_PACK) as psp:
-                        (words, counts, lengths), buf, fresh = pack(blocks)
+                        # a wait for a buffer still being made ready is
+                        # the pack's own time: what this thread paid
+                        (words, counts, lengths), buf, fresh, ready = pack(
+                            blocks)
                         if psp.active:
                             psp.set(batch=len(blocks), bytes=nbytes,
                                     padded_bytes=words.nbytes,
-                                    fresh=int(fresh),
+                                    fresh=int(fresh), ready=int(ready),
                                     # which pack ran: libjfscore's one
                                     # call, or numpy row by row (0)
                                     native=int(native.available()))
                     if fresh:
                         _PACK_FRESH_BYTES.inc(words.nbytes)
+                    if not ready:
+                        _PACK_UNREADY_BYTES.inc(words.nbytes)
                     _H2D_BYTES.inc(words.nbytes)
                     pending.append(
                         (keys, self._fn(words, counts, lengths), t0, buf))
@@ -218,21 +404,29 @@ class HashPipeline:
                 self._note_first_batch(t0)
             return zip(bkeys, digests[: len(bkeys)])
 
-        for key, data in items:
-            if len(data) > cfg.pad_lanes * LANE_BYTES:
-                raise ValueError(f"block {key} larger than pipeline pad size")
-            keys.append(key)
-            blocks.append(data)
-            if len(blocks) >= cfg.batch_blocks:
-                dispatch()
-                # Async dispatch: the device hashes batch k while the host
-                # packs later ones; block only past the configured depth.
-                depth = max(1, cfg.max_inflight_batches)
-                while len(pending) >= depth:
-                    yield from drain(pending.pop(0))
-        dispatch()
-        while pending:
-            yield from drain(pending.pop(0))
+        try:
+            for key, data in items:
+                if len(data) > cfg.pad_lanes * LANE_BYTES:
+                    raise ValueError(
+                        f"block {key} larger than pipeline pad size")
+                keys.append(key)
+                blocks.append(data)
+                if len(blocks) >= cfg.batch_blocks:
+                    dispatch()
+                    # Async dispatch: the device hashes batch k while the
+                    # host packs later ones; block only past the
+                    # configured depth.
+                    depth = max(1, cfg.max_inflight_batches)
+                    while len(pending) >= depth:
+                        yield from drain(pending.pop(0))
+            dispatch()
+            while pending:
+                yield from drain(pending.pop(0))
+        finally:
+            # ended, closed early or failed: what was prepared and not
+            # taken goes, and nobody touches pages for a stream that is over
+            if prepared is not None:
+                prepared.stop()
 
     def hash_blocks(self, blocks: Iterable[bytes]) -> list[bytes]:
         return [d for _, d in self.hash_stream((str(i), b) for i, b in enumerate(blocks))]

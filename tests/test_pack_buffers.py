@@ -2,7 +2,9 @@
 batch into a buffer its caller keeps, byte for byte what the fresh call
 returns; one `hash_stream` keeps at most `max_inflight_batches` such buffers,
 gets one back only once its batch's digests were read, and holds nothing
-after it ends or is abandoned."""
+after it ends or is abandoned. Since ISSUE 34 a stream that was announced
+(`HashPipeline.prepare()`) takes those buffers from a preparer that faulted
+them in on threads of its own; tests/test_pack_prepare.py holds that."""
 
 import gc
 import weakref
@@ -174,19 +176,32 @@ def test_a_pack_stand_in_without_out_still_hashes_right(monkeypatch):
 
 
 @pytest.mark.parametrize("how", ["closed-early", "run-to-its-end"])
-def test_a_stream_holds_no_buffer_once_it_is_over(monkeypatch, how):
-    made = []
+@pytest.mark.parametrize("announced", [False, True],
+                         ids=["its-own-buffers", "prepared-buffers"])
+def test_a_stream_holds_no_buffer_once_it_is_over(monkeypatch, how, announced):
+    """Every buffer a batch was packed into, made by the pack itself or (an
+    announced stream's) by the preparer, is gone with the stream."""
+    from test_pack_prepare import no_preparer_runs, wait_ready
+
+    made, seen = [], set()
 
     def noting(blocks, pad_lanes=None, out=None):
         packed = pack_blocks(blocks, pad_lanes, out)
-        if out is None:
-            made.append(weakref.ref(packed[0]))
+        buf = packed[0] if out is None else out
+        if id(buf) not in seen:
+            seen.add(id(buf))
+            made.append(weakref.ref(buf))
         return packed
 
     monkeypatch.setattr(pipeline, "pack_blocks", noting)
     blocks = _ragged_stream()
     pipe = HashPipeline(PipelineConfig(
         backend="xla", batch_blocks=4, pad_lanes=2))
+    prepared, prepared_ids = [], set()
+    if announced:
+        pipe.prepare()
+        prepared = [weakref.ref(b) for b in wait_ready(pipe)]
+        prepared_ids = {id(ref()) for ref in prepared}
     stream = pipe.hash_stream((f"k{i}", b) for i, b in enumerate(blocks))
     if how == "closed-early":
         # batches 1 and 2 are out, one drained and its buffer free, one pending
@@ -195,8 +210,12 @@ def test_a_stream_holds_no_buffer_once_it_is_over(monkeypatch, how):
     else:
         assert len(list(stream)) == len(blocks)
     del stream
-    gc.collect()
+    assert no_preparer_runs() and pipe._prepared is None
+    gc.collect()  # (the CPU backend's arrays alias host words, in cycles)
     assert len(made) == 2 and all(ref() is None for ref in made)
+    # an announced stream's batches went into the buffers made for it
+    assert all(ref() is None for ref in prepared)
+    assert not announced or seen == prepared_ids
 
 
 @pytest.mark.parametrize("how", ["library", "no-library", "bytearray", "read-only"])

@@ -681,9 +681,11 @@ def test_gc_trace_sets_the_hook_for_the_profiler_session_alone(
     assert hook == [("start", None), ("stop", None)]
     assert tr.annotate is None and not tr.active
     # what opened while the session ran was annotated, pool threads' GETs
-    # too; the root and `open` came before the backend was known
+    # too; the root and `open` came before the backend was known, and the
+    # preparing of the first pack buffer begins inside `open` (ISSUE 34)
     assert set(stats["spans"]) - names <= {"jfs.cmd.gc", "jfs.cmd.gc.open",
-                                           "jfs.tpu.device.init"}
+                                           "jfs.tpu.device.init",
+                                           "jfs.tpu.pack.prepare"}
     assert {"jfs.tpu.hash.pack", "jfs.object.get",
             "jfs.chunk.fetch.wait"} <= names
     assert "jfs.cmd.gc" not in names and "jfs.cmd.gc.open" not in names
