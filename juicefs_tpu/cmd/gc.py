@@ -27,6 +27,7 @@ from ..metric.trace import global_tracer, span_summary, stage_hist
 from ..qos import IOClass
 from ..tpu.device import HASH_BACKENDS
 from ..utils import get_logger
+from .readhash import ReadHash, scan_pipeline
 
 logger = get_logger("cmd.gc")
 
@@ -163,7 +164,7 @@ def _gc(args, trace: "_ScanTrace | None", root,
             # host memory"). A scan that finds nothing to hash lets go of
             # them unused; whatever happens, nothing is prepared past
             # this invocation.
-            pipe = _scan_pipeline(backend, bs)
+            pipe = scan_pipeline(backend, bs)
             at_exit.callback(pipe.release)
             pipe.prepare()
         on_device = pipe is not None and pipe.device_backend
@@ -263,16 +264,6 @@ def _gc(args, trace: "_ScanTrace | None", root,
     return stats
 
 
-def _scan_pipeline(backend: str, block_size: int):
-    """The hash pipeline of a scan over blocks of up to `block_size` bytes:
-    one program for the whole stream, every block padded to the volume's
-    block."""
-    from ..tpu.pipeline import HashPipeline, PipelineConfig
-
-    return HashPipeline(PipelineConfig(
-        backend=backend, pad_lanes=max(1, block_size // 65536)))
-
-
 def dedup_scan(meta, store, live: dict[str, int], backend: str,
                index_path: str, block_size: int, threads: int = 8,
                pipe=None) -> dict:
@@ -288,21 +279,13 @@ def dedup_scan(meta, store, live: dict[str, int], backend: str,
     scan is O(new data). Index rows whose slice no longer exists are
     pruned here — the index is advisory and self-healing.
 
-    Object GETs run `threads` deep through the ordered parallel-fetch
-    stage (chunk/parallel.py), overlapping storage I/O with TPU hash
-    dispatch; results arrive in input order, so digests and index rows
-    are byte-identical to the old serial walk.  Never more than `threads`
-    GETs run at once; the stage fetches one hash batch ahead of them
-    (`batch_blocks` of the pipeline, 32), so that while this thread packs,
-    ships and drains batch k the pool is fetching batch k + 1.  Host
-    memory: at most `(threads + batch_blocks) x block_size` of fetched
-    blocks wait for the hash (42 x 4 MiB = 168 MiB at the defaults with
-    `--threads 10`), beside the batch being gathered.
+    The missing blocks are read and hashed by the stage `fsck
+    --verify-data` shares (cmd/readhash.py): `threads` GETs at once, one
+    hash batch fetched ahead of them, digests in input order.
     """
     import resource
     import time as _time
 
-    from ..chunk.parallel import FetchStats, fetch_ordered
     from ..tpu.dedup import dedup_digests
     from ..tpu.jth256 import digest_hex
 
@@ -325,35 +308,20 @@ def dedup_scan(meta, store, live: dict[str, int], backend: str,
     # 2. hash only blocks the write path didn't index; backfill their rows
     missing = [k for k in live if k not in digest_by_key]
     if pipe is None:
-        pipe = _scan_pipeline(backend, block_size)
-    window = max(1, threads)
-    # what hash_stream takes between two stretches of its own work
-    ahead = pipe.config.batch_blocks
-    fstats = FetchStats()
-
-    def blocks():
-        # windowed parallel GETs on the store's download pool, a batch
-        # ahead of the hash pipeline and yielded into it in input order; a
-        # bad block is skipped (and logged by the stage), never aborts the
-        # scan
-        yield from fetch_ordered(
-            missing,
-            lambda key: store._load_block(key, live[key], cache_after=False),
-            store._bulk_pool, window, on_error="skip", stats=fstats,
-            ahead=ahead,
-        )
+        pipe = scan_pipeline(backend, block_size)
+    stage = ReadHash(store, pipe, threads)
 
     backfill = []
     faults0 = resource.getrusage(resource.RUSAGE_SELF).ru_minflt
     with _TR.span("cmd", "gc", stage="readhash",
                   hist=_H_READHASH) as sp_readhash:
-        for key, digest in pipe.hash_stream(blocks()):
+        for key, digest in stage.digests(missing, live):
             digest_by_key[key] = digest
             sid, indx, bsize = parse_block_key(key)
             backfill.append((sid, indx, bsize, digest))
         if sp_readhash.active:
-            sp_readhash.set(blocks=len(backfill), window=window,
-                            ahead=ahead)
+            sp_readhash.set(blocks=len(backfill), window=stage.window,
+                            ahead=stage.ahead)
     _SCAN_MINOR_FAULTS.inc(
         resource.getrusage(resource.RUSAGE_SELF).ru_minflt - faults0)
     with _TR.span("cmd", "gc", stage="backfill",
@@ -385,6 +353,7 @@ def dedup_scan(meta, store, live: dict[str, int], backend: str,
     nbytes = sum(live.values())
     from ..object.resilient import resilience_snapshot
 
+    readhash = stage.stage_seconds(sp_readhash.dur)
     return {
         "blocks": len(keys),
         "bytes": nbytes,
@@ -396,13 +365,11 @@ def dedup_scan(meta, store, live: dict[str, int], backend: str,
         "dedup_groups": len(groups),
         # the backend that RAN (requested name is in device.requested)
         "backend": pipe.config.backend,
-        "fetch_window": window,
-        "fetch_ahead": ahead,
-        # stage breakdown (VERDICT r3 #2: the bottleneck must be explicit).
-        # `get` is WALL time the fetch stage had GETs in flight;
-        # `get_threads` is aggregate per-thread GET seconds — their ratio
-        # is the achieved I/O overlap factor (ISSUE 2), and `hash` is the
-        # read+hash wall (`readhash`) not hidden behind the fetch window.
+        "fetch_window": stage.window,
+        "fetch_ahead": stage.ahead,
+        # stage breakdown (VERDICT r3 #2: the bottleneck must be explicit);
+        # `get`, `get_threads`, `hash` and `readhash` are the shared
+        # stage's own (cmd/readhash.py).
         # The stages are their spans' durations, to the microsecond;
         # `seconds` is the scan's own wall, stages and what lies between.
         "seconds": round(total, 3),
@@ -410,12 +377,12 @@ def dedup_scan(meta, store, live: dict[str, int], backend: str,
         "blocks_per_s": round(len(keys) / total, 1) if total > 0 else 0.0,
         "stage_seconds": {
             "index_load": round(sp_index.dur, 6),
-            "get": round(fstats.wall, 6),
-            "get_threads": round(fstats.seconds, 6),
-            "hash": round(max(sp_readhash.dur - fstats.wall, 0.0), 6),
+            "get": readhash["get"],
+            "get_threads": readhash["get_threads"],
+            "hash": readhash["hash"],
             "meta_backfill": round(sp_backfill.dur, 6),
             "dup_group": round(sp_group.dur, 6),
-            "readhash": round(sp_readhash.dur, 6),
+            "readhash": readhash["readhash"],
         },
         # retry/hedge/breaker activity during the scan (the GETs run
         # through object/resilient.py): a scan that paid for fault

@@ -25,10 +25,14 @@ import threading
 import time
 from typing import Iterator, Optional
 
+from ..metric.trace import global_tracer, stage_hist
 from ..utils import get_logger, txnwatch
 from .tkv_client import ConflictError, KVTxn, TKVClient, next_key
 
 logger = get_logger("meta.redis_kv")
+
+_TR = global_tracer()
+_H_ROUNDTRIP = stage_hist("meta", "kv", "roundtrip")
 
 IDX_KEY = b"!idx"
 SCAN_PAGE = 2048
@@ -126,6 +130,31 @@ class RedisError(Exception):
     pass
 
 
+class _KVConnection(RespConnection):
+    """The KV client's connection: every request/response with the server
+    is one `meta.kv.roundtrip` span, whether it carries one command or a
+    pipeline of them (attr `cmd`: the command whose reply the caller came
+    for, the pipeline's last). The in-process engines never come here."""
+
+    def roundtrip(self, *cmds: tuple, sent=None) -> list:
+        """Send `cmds` as one pipeline and read a reply for each; `sent()`
+        is called between the two, for the caller to whom it matters
+        which side of the send an error fell on."""
+        with _TR.span("meta", "kv", stage="roundtrip",
+                      hist=_H_ROUNDTRIP) as sp:
+            if sp.active:
+                name = cmds[-1][0]
+                sp.set(cmd=name.decode() if isinstance(name, bytes) else name,
+                       cmds=len(cmds))
+            self.send(*cmds)
+            if sent is not None:
+                sent()
+            return [self.read_reply() for _ in cmds]
+
+    def execute(self, *args):
+        return self.roundtrip(args)[0]
+
+
 class _RedisTxn(KVTxn):
     """Snapshot-ish reads (WATCH+GET) with buffered writes (tkv.go kvTxn)."""
 
@@ -145,9 +174,7 @@ class _RedisTxn(KVTxn):
         if key in self._read_cache:
             return self._read_cache[key]
         # WATCH before read: any later concurrent write aborts our EXEC
-        self._conn.send((b"WATCH", key), (b"GET", key))
-        self._conn.read_reply()
-        val = self._conn.read_reply()
+        _, val = self._conn.roundtrip((b"WATCH", key), (b"GET", key))
         self._read_cache[key] = val
         return val
 
@@ -160,9 +187,8 @@ class _RedisTxn(KVTxn):
             if k not in self._writes and k not in self._read_cache
         ]
         if missing:
-            self._conn.send([b"WATCH"] + missing, [b"MGET"] + missing)
-            self._conn.read_reply()
-            vals = self._conn.read_reply()
+            _, vals = self._conn.roundtrip(
+                [b"WATCH"] + missing, [b"MGET"] + missing)
             for k, v in zip(missing, vals):
                 self._read_cache[k] = v
         return [
@@ -189,8 +215,7 @@ class _RedisTxn(KVTxn):
         names = self._client._range(self._conn, begin, end)
         merged: dict[bytes, Optional[bytes]] = {}
         if not keys_only and names:
-            self._conn.send([b"MGET"] + names)
-            vals = self._conn.read_reply()
+            vals = self._conn.execute(b"MGET", *names)
             for k, v in zip(names, vals):
                 merged[k] = v
         else:
@@ -254,9 +279,8 @@ class _ReadTxn(KVTxn):
             try:
                 conn = cl._replica_conn()
                 if first_cmd is not None:
-                    conn.send((b"GET", cl.EPOCH_KEY), first_cmd)
-                    raw = conn.read_reply()
-                    reply = conn.read_reply()
+                    raw, reply = conn.roundtrip(
+                        (b"GET", cl.EPOCH_KEY), first_cmd)
                 else:
                     raw = conn.execute(b"GET", cl.EPOCH_KEY)
                     reply = None
@@ -277,8 +301,7 @@ class _ReadTxn(KVTxn):
             self._conn = cl._conn()
         if first_cmd is None:
             return None
-        self._conn.send(first_cmd)
-        return self._conn.read_reply()
+        return self._conn.execute(*first_cmd)
 
     def get(self, key: bytes) -> Optional[bytes]:
         return self.gets(key)[0]
@@ -303,8 +326,7 @@ class _ReadTxn(KVTxn):
         names = self._client._range(conn, begin, end)
         vals: dict[bytes, bytes] = {}
         if not keys_only and names:
-            conn.send(tuple([b"MGET"] + names))
-            for k, v in zip(names, conn.read_reply()):
+            for k, v in zip(names, conn.execute(b"MGET", *names)):
                 vals[k] = v
         n = 0
         for k in names:
@@ -359,10 +381,10 @@ class RedisKV(TKVClient):
         self.execute(b"PING")  # fail fast on a bad address
 
     # -- connections (one per thread, like SqliteKV) -----------------------
-    def _conn(self) -> RespConnection:
+    def _conn(self) -> _KVConnection:
         conn = getattr(self._local, "conn", None)
         if conn is None:
-            conn = RespConnection(self.host, self.port, self.db)
+            conn = _KVConnection(self.host, self.port, self.db)
             self._local.conn = conn
         return conn
 
@@ -442,10 +464,10 @@ class RedisKV(TKVClient):
         except ValueError:
             return int.from_bytes(raw, "big", signed=True)
 
-    def _replica_conn(self) -> RespConnection:
+    def _replica_conn(self) -> _KVConnection:
         conn = getattr(self._local, "rconn", None)
         if conn is None:
-            conn = RespConnection(self.replica_host, self.replica_port, self.db)
+            conn = _KVConnection(self.replica_host, self.replica_port, self.db)
             self._local.rconn = conn
         return conn
 
@@ -585,12 +607,14 @@ class RedisKV(TKVClient):
                 # client's replica-read floor (read-your-own-writes)
                 cmds.append((b"INCRBY", self.EPOCH_KEY, b"1"))
                 cmds.append((b"EXEC",))
-                conn.send(*cmds)
                 # send() raising means EXEC (the pipeline tail) never fully
                 # reached the server, so that is still a safe retry; only
                 # after a complete send is the commit outcome ambiguous.
-                committing = True
-                replies = [conn.read_reply() for _ in cmds]
+                def sent():
+                    nonlocal committing
+                    committing = True
+
+                replies = conn.roundtrip(*cmds, sent=sent)
                 if replies[-1] is not None:
                     exec_replies = replies[-1]
                     if isinstance(exec_replies, list) and exec_replies \
@@ -636,14 +660,10 @@ class RedisKV(TKVClient):
     def scan(self, begin, end) -> Iterator[tuple[bytes, bytes]]:
         names = self._retry_io(lambda: self._range(self._conn(), begin, end))
 
-        def mget(chunk):
-            conn = self._conn()
-            conn.send([b"MGET"] + chunk)
-            return conn.read_reply()
-
         for i in range(0, len(names), SCAN_PAGE):
             chunk = names[i:i + SCAN_PAGE]
-            vals = self._retry_io(lambda: mget(chunk))
+            vals = self._retry_io(
+                lambda: self._conn().execute(b"MGET", *chunk))
             for k, v in zip(chunk, vals):
                 if v is not None:
                     yield (k, v)

@@ -8,8 +8,9 @@ k+1 on the host overlaps hashing batch k on the TPU; results are only
 blocked on one batch behind.
 
 Each batch is one `tpu.hash.dispatch` span with the children `pack` (here),
-`h2d` and `enqueue` (tpu/sharding.py, where the transfer and the jitted
-call live), then one `tpu.hash.drain` when its digests are read back.
+`h2d` and `enqueue` (the transfer and the jitted call: tpu/sharding.py on
+the plane, `_SingleDeviceHash` here for the kernel that runs on one
+device), then one `tpu.hash.drain` when its digests are read back.
 
 A caller that knows a stream is coming says so (`HashPipeline.prepare()`):
 helper threads then make the stream's pack buffers resident while the
@@ -94,6 +95,9 @@ _H_DISPATCH = stage_hist("tpu", "hash", "dispatch")
 _H_PACK = stage_hist("tpu", "hash", "pack")
 _H_DRAIN = stage_hist("tpu", "hash", "drain")
 _H_PREPARE = stage_hist("tpu", "pack", "prepare")
+# the same children tpu/sharding.py observes for the plane's batches
+_H_H2D = stage_hist("tpu", "hash", "h2d")
+_H_ENQUEUE = stage_hist("tpu", "hash", "enqueue")
 
 # Threads that fault one pack buffer in, each on its own part. On the chip
 # machine one thread takes 132 ms for 128 MiB and leaves pages that cost the
@@ -222,6 +226,39 @@ class _PreparedBuffers:
             self._slots = []
 
 
+class _SingleDeviceHash:
+    """A hash program that runs on one device and not on the sharding
+    plane (the Pallas kernel): its transfer and its jitted call as the two
+    steps the plane makes of them, under the same spans, so that a batch's
+    dispatch is pack + h2d + enqueue whichever kernel hashes it."""
+
+    def __init__(self, program):
+        self._program = program
+
+    def put_packed(self, words, lane_counts, lengths) -> tuple:
+        """The one host->device transfer of a packed batch."""
+        import jax
+
+        with _TR.span("tpu", "hash", stage="h2d", hist=_H_H2D) as sp:
+            if sp.active:
+                sp.set(bytes=int(words.nbytes), sharded=False)
+            return tuple(
+                jax.device_put(a) for a in (words, lane_counts, lengths))
+
+    def hash_async(self, words, lane_counts, lengths):
+        """Dispatch the program and return the (still-async) device array
+        of digests. Accepts host arrays (placed here) or arrays already
+        placed by `put_packed` (no second transfer)."""
+        import jax
+
+        if not isinstance(words, jax.Array):
+            words, lane_counts, lengths = self.put_packed(
+                words, lane_counts, lengths)
+        # the async call alone: a retrace or a recompile lands here
+        with _TR.span("tpu", "hash", stage="enqueue", hist=_H_ENQUEUE):
+            return self._program(words, lane_counts, lengths)
+
+
 @dataclass
 class PipelineConfig:
     backend: str = "xla"  # cpu | xla | pallas | tpu (tpu/device.py)
@@ -250,6 +287,7 @@ class HashPipeline:
         self.config.backend = resolve_backend(self.requested)
         self._fn = None
         self._plane = None
+        self._single: _SingleDeviceHash | None = None
         self._prepared: _PreparedBuffers | None = None
         # wall time of the first device batch, dispatch to digests: it
         # carries the compilation, so consumers report it apart from rate
@@ -265,7 +303,8 @@ class HashPipeline:
         elif self.config.backend == "pallas":
             from .hash_jax import make_hash_fn
 
-            self._fn = make_hash_fn("pallas")
+            self._single = _SingleDeviceHash(make_hash_fn("pallas"))
+            self._fn = self._single.hash_async
         # initialises the backend for xla/pallas (raising if it cannot)
         # and says which mode Pallas will run in
         report = self.device_report()
@@ -446,10 +485,8 @@ class HashPipeline:
         arrays hash on the host)."""
         if self._plane is not None:
             return self._plane.put_packed(*packed)
-        if self._fn is not None:  # single-device backend (pallas)
-            import jax
-
-            return tuple(jax.device_put(a) for a in packed)
+        if self._single is not None:  # single-device backend (pallas)
+            return self._single.put_packed(*packed)
         return packed
 
     def device_report(self) -> dict:

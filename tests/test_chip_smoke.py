@@ -124,3 +124,46 @@ def test_chip_smoke_alone_in_a_directory_fails(tmp_path):
                        capture_output=True, text=True, timeout=120)
     assert p.returncode != 0 and p.stdout.strip() == ""
     assert "FAIL at step checkout" in p.stderr
+
+
+def test_step_4_reads_fscks_output_with_the_stats_line_after_it(tmp_path, capsys):
+    """`fsck --verify-data` prints its three lines as it did, word for word,
+    and one JSON stats line last (ISSUE 35): step 4's regex and its
+    `device: ` reader find what they found, and the line they never asked
+    for is the last."""
+    import json
+    import re
+
+    from juicefs_tpu.cmd import build_store, main, open_meta
+    from juicefs_tpu.fs import FileSystem
+    from juicefs_tpu.vfs import VFS
+
+    meta_url = f"sqlite3://{tmp_path}/meta.db"
+    assert main(["format", meta_url, "smokevol", "--storage", "file",
+                 "--bucket", str(tmp_path / "blob") + "/",
+                 "--block-size", "64"]) == 0
+    m, fmt = open_meta(meta_url)
+    m.new_session()
+    store = build_store(fmt, None)
+    vfs = VFS(m, store, fmt=fmt)
+    with FileSystem(vfs).create("/a.bin") as f:
+        f.write(os.urandom(3 * 65536 + 11))
+    vfs.close()
+    store.close()
+    m.close_session()
+    assert main(["gc", meta_url, "--dedup", "--hash-backend", "cpu"]) == 0
+    capsys.readouterr()
+    assert main(["fsck", meta_url, "--verify-data", "--hash-index",
+                 str(tmp_path / "F.json"), "--hash-backend", "pallas"]) == 0
+    out = capsys.readouterr().out
+    found = re.search(r"verified (\d+) blocks \((\w+)\); (\d+) indexed, "
+                      r"(\d+) digest mismatches", out)
+    assert found.groups() == ("4", "pallas", "4", "0")
+    device = chip_smoke.last_json_line(out, "device: ")
+    assert device["backend"] == "pallas" and device["devices"] == 1
+    assert "first_batch_seconds" in device
+    lines = out.strip().splitlines()
+    assert lines[-2] == "checked 1 files / 4 blocks; 0 broken"
+    stats = json.loads(lines[-1])
+    assert stats == chip_smoke.last_json_line(out)
+    assert (stats["verified"], stats["hashed_now"], stats["device"]) == (4, 4, device)
