@@ -9,17 +9,24 @@ content groups and reclaimable bytes — content addressing the reference
 does not have (its gc diffs block *names* only, cmd/gc.go:253-296).
 
 One invocation is one trace (metric/trace.py): the root span `cmd.gc`, its
-stages `open`, `list`, `index_load`, `readhash`, `backfill`, `group`,
-`write_index`, `reconcile` below it, and below `readhash` the fetch stage's
-and the hash pipeline's own spans. Every stage feeds
+stages `open`, `live`, `list`, `index_load`, `readhash`, `backfill`, `group`,
+`write_index`, `list_wait`, `reconcile` below it, and below `readhash` the
+fetch stage's and the hash pipeline's own spans. Every stage feeds
 `juicefs_tpu_stage_seconds{layer="cmd",op="gc"}` whether anyone listens or
 not; `--trace DIR` attaches a reader and writes what it heard.
+
+With `--dedup` the store is listed (`list`) on a thread of its own,
+`jfs-gc-list`, beside the scan: the hash stage reads what the slices say is
+live and never what the store holds, so only the name diff waits for the
+listing (`list_wait`), after the scan (`_gc`).
 """
 
 from __future__ import annotations
 
 import contextlib
 import json
+import threading
+import time
 
 from ..chunk.cached_store import block_key, parse_block_key
 from ..metric import global_registry
@@ -34,7 +41,9 @@ logger = get_logger("cmd.gc")
 _TR = global_tracer()
 _H_GC = stage_hist("cmd", "gc")
 _H_OPEN = stage_hist("cmd", "gc", "open")
+_H_LIVE = stage_hist("cmd", "gc", "live")
 _H_LIST = stage_hist("cmd", "gc", "list")
+_H_LIST_WAIT = stage_hist("cmd", "gc", "list_wait")
 _H_INDEX_LOAD = stage_hist("cmd", "gc", "index_load")
 _H_READHASH = stage_hist("cmd", "gc", "readhash")
 _H_BACKFILL = stage_hist("cmd", "gc", "backfill")
@@ -136,10 +145,81 @@ class _ScanTrace:
         return False
 
 
+class _StoreListing:
+    """The store's half of the name diff: every block object under
+    `chunks/` with its size (`stored`) and those younger than `cutoff`
+    (`recent`), read in the span `cmd.gc.list`.
+
+    `run()` lists on the calling thread. `start()` lists on a thread of
+    its own, `jfs-gc-list`, whose span hangs off `parent`; `wait()` joins
+    it, raises what the listing raised and says how much of the listing
+    the caller did not wait for; `stop()` ends it early and joins, for the
+    invocation that is on its way out. The thread touches the object store
+    and no meta client."""
+
+    def __init__(self, storage, cutoff: float, parent=None):
+        self.storage = storage
+        self.cutoff = cutoff
+        self.parent = parent
+        self.stored: dict[str, int] = {}
+        self.recent: set[str] = set()
+        self.seconds = 0.0  # what the listing took, once it is done
+        self._stopped = False
+        self._error: BaseException | None = None
+        self._thread: threading.Thread | None = None
+
+    def run(self) -> None:
+        with _TR.span("cmd", "gc", stage="list", hist=_H_LIST,
+                      parent=self.parent) as sp:
+            for obj in self.storage.list_all("chunks/"):
+                if self._stopped:
+                    break
+                if parse_block_key(obj.key) is not None:
+                    self.stored[obj.key] = obj.size
+                    if obj.mtime > self.cutoff:
+                        self.recent.add(obj.key)
+        self.seconds = sp.dur
+
+    def start(self) -> None:
+        def listed():
+            try:
+                self.run()
+            except BaseException as e:  # raised again by wait()
+                self._error = e
+
+        self._thread = threading.Thread(target=listed, name="jfs-gc-list",
+                                        daemon=True)
+        self._thread.start()
+
+    def wait(self) -> float:
+        t0 = time.perf_counter()
+        self._thread.join()
+        waited = time.perf_counter() - t0
+        if self._error is not None:
+            raise self._error
+        return round(1.0 - min(waited / max(self.seconds, 1e-9), 1.0), 4)
+
+    def stop(self) -> None:
+        self._stopped = True
+        self._thread.join()
+
+
 def _gc(args, trace: "_ScanTrace | None", root,
         at_exit: contextlib.ExitStack) -> dict | None:
     """The invocation below its root span; returns the --dedup stats
-    (which `run` prints), else None."""
+    (which `run` prints), else None.
+
+    The order of a `--dedup` invocation, and which thread runs what:
+    `open` (this thread; the `jfs-pack-prepare` threads start faulting in
+    the pack buffers), then the listing of `chunks/` starts on
+    `jfs-gc-list` while this thread walks the slices (`live`) and runs
+    `dedup_scan` over what they name — index load, read and hash (the
+    store's pool threads fetch), backfill, grouping. Only then does it
+    join the lister (`list_wait`) and do what needs the store's listing:
+    the name diff, its `scanned:` line, the `--delete` sweep, `reconcile`.
+    Leaked objects are by definition not live, so the hash stage reads
+    nothing the sweep deletes. Without `--dedup` there is no scan to list
+    beside: the listing runs on this thread, after `live`."""
     from . import build_store, open_meta
 
     with _TR.span("cmd", "gc", stage="open", hist=_H_OPEN):
@@ -160,7 +240,7 @@ def _gc(args, trace: "_ScanTrace | None", root,
             # The scan's pipeline, here and not where the hashing starts:
             # the sizes of its pack buffers are known now, and announcing
             # the stream lets helper threads fault them in while this
-            # thread lists the volume (docs/ARCHITECTURE.md "The scan's
+            # thread walks the slices (docs/ARCHITECTURE.md "The scan's
             # host memory"). A scan that finds nothing to hash lets go of
             # them unused; whatever happens, nothing is prepared past
             # this invocation.
@@ -181,9 +261,17 @@ def _gc(args, trace: "_ScanTrace | None", root,
         n = compact_all(m, store)
         print(f"compacted {n} chunks")
 
-    import time as _time
+    # An object can be uploaded before its slice commits to meta (the write
+    # pipeline is async), so fresh objects are never "leaked" (reference gc
+    # skips recent blocks for the same reason). The cutoff is taken before
+    # anything is listed or read: a block uploaded during the scan is recent.
+    listing = _StoreListing(store.storage, time.time() - args.age,
+                            parent=root.ref())
+    if args.dedup:
+        at_exit.callback(listing.stop)
+        listing.start()
 
-    with _TR.span("cmd", "gc", stage="list", hist=_H_LIST):
+    with _TR.span("cmd", "gc", stage="live", hist=_H_LIVE):
         # live slice -> expected blocks
         slices = m.list_slices()
         live: dict[str, int] = {}
@@ -195,17 +283,6 @@ def _gc(args, trace: "_ScanTrace | None", root,
                 for i in range(n_blocks):
                     bsize = min(bs, s.size - i * bs)
                     live[block_key(s.id, i, bsize)] = bsize
-
-        # stored objects under chunks/
-        cutoff = _time.time() - args.age
-        stored = {}
-        recent = set()
-        for obj in store.storage.list_all("chunks/"):
-            parsed = parse_block_key(obj.key)
-            if parsed is not None:
-                stored[obj.key] = obj.size
-                if obj.mtime > cutoff:
-                    recent.add(obj.key)
 
         # Inline dedup (ISSUE 5): an elided block has no object of its own
         # — its bytes live under the canonical block of its content ref.
@@ -221,9 +298,17 @@ def _gc(args, trace: "_ScanTrace | None", root,
             logger.warning("content-ref scan unavailable: %s", e)
             aliases, protected = {}, set()
 
-    # An object can be uploaded before its slice commits to meta (the write
-    # pipeline is async), so fresh objects are never "leaked" (reference gc
-    # skips recent blocks for the same reason).
+    if args.dedup:
+        stats = dedup_scan(m, store, live, backend, args.dedup_index, bs,
+                           threads=args.threads, pipe=pipe)
+        with _TR.span("cmd", "gc", stage="list_wait",
+                      hist=_H_LIST_WAIT) as sp_wait:
+            # the share of the listing the scan hid
+            sp_wait.set(hidden=listing.wait())
+    else:
+        listing.run()
+    stored, recent = listing.stored, listing.recent
+
     leaked = [k for k in stored
               if k not in live and k not in recent and k not in protected]
     missing = [k for k in live
@@ -248,8 +333,6 @@ def _gc(args, trace: "_ScanTrace | None", root,
 
     if not args.dedup:
         return None
-    stats = dedup_scan(m, store, live, backend, args.dedup_index, bs,
-                       threads=args.threads, pipe=pipe)
     # offline complement of the inline ingest stage: repair refcounts
     # left by crash windows, register existing content so future
     # writes elide, and (with --delete) collapse duplicate objects
@@ -284,12 +367,11 @@ def dedup_scan(meta, store, live: dict[str, int], backend: str,
     hash batch fetched ahead of them, digests in input order.
     """
     import resource
-    import time as _time
 
     from ..tpu.dedup import dedup_digests
     from ..tpu.jth256 import digest_hex
 
-    t0 = _time.perf_counter()
+    t0 = time.perf_counter()
     # 1. load the persistent index; prune rows for dead slices
     with _TR.span("cmd", "gc", stage="index_load",
                   hist=_H_INDEX_LOAD) as sp_index:
@@ -349,7 +431,7 @@ def dedup_scan(meta, store, live: dict[str, int], backend: str,
                     f,
                     indent=1,
                 )
-    total = _time.perf_counter() - t0
+    total = time.perf_counter() - t0
     nbytes = sum(live.values())
     from ..object.resilient import resilience_snapshot
 
@@ -416,8 +498,6 @@ def reconcile_content_refs(meta, store, live: dict[str, int],
     Invariant after this runs: every alias row maps a live block to a
     ref row whose refcount equals its alias count — zero orphaned, zero
     dangling."""
-    import time as _time
-
     stats = {"orphaned_aliases_repaired": 0, "refcounts_fixed": 0,
              "dangling_content_refs": 0, "self_healed_aliases": 0,
              "registered": 0, "collapsed": 0, "collapsed_bytes": 0}
@@ -427,7 +507,7 @@ def reconcile_content_refs(meta, store, live: dict[str, int],
     # writer elides (alias committed) BEFORE its slice commits to meta,
     # so a fresh alias absent from `live` is an in-flight acked write,
     # not a crash orphan — repairing it would delete data mid-commit.
-    cutoff = _time.time() - age
+    cutoff = time.time() - age
     aliases = list(meta.scan_content_aliases())
     orphaned = [
         (sid, indx) for (sid, indx), _d, bsize, ts in aliases

@@ -371,10 +371,10 @@ def _names(evs):
 
 
 def test_gc_dedup_scan_is_one_trace_tree(tmp_path, capsys):
-    """cmd.gc -> {open, list, index_load, readhash -> {chunk.fetch.wait,
+    """cmd.gc -> {open, live, list, index_load, readhash -> {chunk.fetch.wait,
     chunk.load.fetch -> object.get, tpu.hash.dispatch -> {pack, h2d,
-    enqueue}, tpu.hash.drain}, backfill, group, reconcile}: one trace, the
-    pool threads' spans included."""
+    enqueue}, tpu.hash.drain}, backfill, group, list_wait, reconcile}: one
+    trace, the pool threads' spans and the lister's included."""
     meta_url = _scan_volume(tmp_path)
     with _reader() as r:
         stats = _gc(capsys, meta_url)
@@ -391,9 +391,22 @@ def test_gc_dedup_scan_is_one_trace_tree(tmp_path, capsys):
     assert (root["blocks"], root["hashed_now"]) == (9, 9)
     assert {e["trace"] for e in evs} == {root["trace"]}
     stages = {name_of[e["id"]] for e in evs if e["parent"] == root["id"]}
-    assert {"cmd.gc.open", "cmd.gc.list", "cmd.gc.index_load",
+    assert {"cmd.gc.open", "cmd.gc.live", "cmd.gc.list", "cmd.gc.index_load",
             "cmd.gc.readhash", "cmd.gc.backfill", "cmd.gc.group",
-            "cmd.gc.reconcile"} <= stages
+            "cmd.gc.list_wait", "cmd.gc.reconcile"} <= stages
+    # the store is listed once, on the lister's thread, and starts before
+    # the scan does; the slices are walked and the lister joined on the
+    # root's, the join after the scan
+    stage = {name_of[e["id"]]: e for e in evs if e["parent"] == root["id"]}
+    for name in ("cmd.gc.live", "cmd.gc.list", "cmd.gc.list_wait"):
+        assert _names(evs).count(name) == 1, name
+    assert stage["cmd.gc.list"]["tid"] != root["tid"]
+    assert stage["cmd.gc.live"]["tid"] == root["tid"]
+    assert stage["cmd.gc.list_wait"]["tid"] == root["tid"]
+    assert stage["cmd.gc.list"]["ts"] <= stage["cmd.gc.index_load"]["ts"]
+    assert stage["cmd.gc.list_wait"]["ts"] >= (
+        stage["cmd.gc.group"]["ts"] + stage["cmd.gc.group"]["dur"] - 1e-6)
+    assert 0.0 <= stage["cmd.gc.list_wait"]["hidden"] <= 1.0
     for e in evs:
         name = name_of[e["id"]]
         if name in ("chunk.fetch.wait", "chunk.load.fetch",
@@ -443,6 +456,32 @@ def test_stage_seconds_are_the_spans_durations(tmp_path, capsys):
     quiet = _gc(capsys, meta_url)["stage_seconds"]
     assert set(quiet) == set(ss) and quiet["index_load"] > 0
     assert "spans" not in stats
+
+
+@pytest.mark.parametrize("dedup", [True, False])
+def test_gc_lists_the_store_once_an_op_and_waits_only_behind_a_scan(
+        tmp_path, capsys, dedup):
+    """`list` is one observation an op with or without a scan to hide it
+    behind, `live` too; `list_wait` is the scan's alone."""
+    from juicefs_tpu.cmd import main
+
+    meta_url = _scan_volume(tmp_path)
+    seen = {stage: hist_count("juicefs_tpu_stage_seconds", "cmd", "gc", stage)
+            for stage in ("live", "list", "list_wait")}
+    with _reader() as r:
+        if dedup:
+            _gc(capsys, meta_url)
+        else:
+            assert main(["gc", meta_url]) == 0
+        evs = r.drain()
+    gained = {stage: hist_count("juicefs_tpu_stage_seconds", "cmd", "gc",
+                                stage) - n for stage, n in seen.items()}
+    assert gained == {"live": 1, "list": 1, "list_wait": int(dedup)}
+    by_name = dict(zip(_names(evs), evs))
+    root = by_name["cmd.gc"]
+    assert by_name["cmd.gc.list"]["parent"] == root["id"]
+    assert (by_name["cmd.gc.list"]["tid"] != root["tid"]) == dedup
+    assert ("cmd.gc.list_wait" in by_name) == dedup
 
 
 def test_fetch_waits_ready_plus_blocked_is_blocks_fetched(tmp_path, capsys):
@@ -546,8 +585,11 @@ def test_gc_trace_flag_writes_a_loadable_chrome_trace(tmp_path, capsys):
         assert e["ph"] == "X" and e["dur"] > 0 and e["pid"] == 1
     root = next(e for e in evs if e["cat"] == "cmd" and e["name"] == "gc")
     # a lane a thread: the stages nest inside the root on its lane, the
-    # GETs have lanes of their own
-    assert {e["tid"] for e in evs if e["cat"] == "cmd"} == {root["tid"]}
+    # store's listing and the GETs have lanes of their own
+    assert {e["tid"] for e in evs if e["cat"] == "cmd"
+            and e["name"] != "gc:list"} == {root["tid"]}
+    assert [e["tid"] != root["tid"] for e in evs if e["cat"] == "cmd"
+            and e["name"] == "gc:list"] == [True]
     assert any(e["tid"] != root["tid"] for e in evs if e["cat"] == "chunk"
                and e["name"] == "load:fetch")
     # the stats line gains per-span totals and self times
@@ -557,15 +599,20 @@ def test_gc_trace_flag_writes_a_loadable_chrome_trace(tmp_path, capsys):
         "jfs.tpu.hash.dispatch"]["total_s"]
     assert spans["jfs.cmd.gc.readhash"]["total_s"] == stats[
         "stage_seconds"]["readhash"]
-    # the root's children follow one another on its thread: the stages,
-    # and the plane coming up if this process's first scan is this one
+    # the root's children: the stages, which follow one another on its
+    # thread (and the plane coming up if this process's first scan is this
+    # one), and the listing beside them, which its self time counts once
     kids = [e for e in evs if e["args"]["parent_id"] == root["args"]["span_id"]]
     assert {e["name"] for e in kids} >= {
-        "gc:open", "gc:list", "gc:index_load", "gc:readhash", "gc:backfill",
-        "gc:group", "gc:reconcile"}
+        "gc:open", "gc:live", "gc:list", "gc:index_load", "gc:readhash",
+        "gc:backfill", "gc:group", "gc:list_wait", "gc:reconcile"}
+    covered, at = 0.0, 0.0
+    for e in sorted(kids, key=lambda e: e["ts"]):
+        lo, hi = max(e["ts"], at), e["ts"] + e["dur"]
+        if hi > lo:
+            covered, at = covered + hi - lo, hi
     assert spans["jfs.cmd.gc"]["self_s"] == pytest.approx(
-        spans["jfs.cmd.gc"]["total_s"] - sum(e["dur"] for e in kids) / 1e6,
-        abs=1e-4)
+        spans["jfs.cmd.gc"]["total_s"] - covered / 1e6, abs=1e-4)
     # the device backend's profiler trace lies beside it, same directory
     assert list(out.glob("plugins/profile/*/*.xplane.pb"))
 
