@@ -26,7 +26,7 @@ from ..metric import global_registry
 from ..metric.trace import global_tracer, stage_hist
 from ..tpu.device import HASH_BACKENDS
 from ..utils import get_logger
-from .readhash import ReadHash, scan_pipeline
+from .readhash import ReadHash, chunk_blocks, scan_pipeline
 
 logger = get_logger("cmd.fsck")
 
@@ -162,12 +162,13 @@ def _fsck(args, root, at_exit: contextlib.ExitStack) -> tuple[int, dict | None]:
         # reported missing above: nothing to read
         readable = [key for key in expected
                     if key in stored or key in aliases]
-        stage = ReadHash(store, pipe, args.threads, outlive_open=True)
+        stage = ReadHash(*chunk_blocks(store, expected), pipe, args.threads,
+                         outlive_open=True)
         bitrot = 0
         index = {}
         with _TR.span("cmd", "fsck", stage="verify",
                       hist=_H_VERIFY) as sp_verify:
-            for k, d in stage.digests(readable, expected):
+            for k, d in stage.digests(readable):
                 index[k] = digest_hex(d)
                 want = recorded.get(k)
                 if want is not None and want != d:

@@ -34,7 +34,7 @@ from ..metric.trace import global_tracer, span_summary, stage_hist
 from ..qos import IOClass
 from ..tpu.device import HASH_BACKENDS
 from ..utils import get_logger
-from .readhash import ReadHash, scan_pipeline
+from .readhash import ReadHash, chunk_blocks, scan_pipeline
 
 logger = get_logger("cmd.gc")
 
@@ -391,13 +391,13 @@ def dedup_scan(meta, store, live: dict[str, int], backend: str,
     missing = [k for k in live if k not in digest_by_key]
     if pipe is None:
         pipe = scan_pipeline(backend, block_size)
-    stage = ReadHash(store, pipe, threads)
+    stage = ReadHash(*chunk_blocks(store, live), pipe, threads)
 
     backfill = []
     faults0 = resource.getrusage(resource.RUSAGE_SELF).ru_minflt
     with _TR.span("cmd", "gc", stage="readhash",
                   hist=_H_READHASH) as sp_readhash:
-        for key, digest in stage.digests(missing, live):
+        for key, digest in stage.digests(missing):
             digest_by_key[key] = digest
             sid, indx, bsize = parse_block_key(key)
             backfill.append((sid, indx, bsize, digest))

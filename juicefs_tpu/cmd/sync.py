@@ -16,21 +16,64 @@ any number of `--worker --manager host:port` processes (launched by the
 operator or an external scheduler; the reference bootstraps them via ssh),
 which pull task batches, copy with their own store clients, and push
 stats back.
+
+With `--hash-backend` the content compare of a local pass is a digest
+compare on the hash pipeline: the 4 MiB ranges of both objects of every
+pair go, source then destination, through the scans' read-and-hash stage
+(cmd/readhash.py) as one stream, the GETs `--threads` at once on the pass's
+own `bulk` executor and a hash batch ahead, and a pair is equal iff every
+range's JTH-256 digests are (the diff has sent a size difference to `copy`
+before any compare). Both sides are read on every pass: nothing is
+answered from an index or a cache. Without the flag the compare is the
+ranged byte compare on the host that it was; cluster mode keeps that one.
+
+One local pass is one trace (metric/trace.py): the root span `cmd.sync`,
+its stages `open`, `list` (both listings and the diff; with a hash backend),
+`copy`, `check`, `report` below it, each feeding
+`juicefs_tpu_stage_seconds{layer="cmd",op="sync"}`. Without a hash backend
+the diff stays lazy and drives the worker pool: listing, copies, deletes and
+byte compares overlap inside `copy`.
 """
 
 from __future__ import annotations
 
+import contextlib
 import fnmatch
 import json
 import threading
 import time
 
+from ..metric import global_registry
+from ..metric.trace import global_tracer, stage_hist
 from ..object import create_storage
 from ..object.resilient import RetryPolicy, resilient
 from ..qos import IOClass, global_scheduler
+from ..tpu.device import HASH_BACKENDS
 from ..utils import get_logger
+from .readhash import ReadHash, scan_pipeline
 
 logger = get_logger("cmd.sync")
+
+_TR = global_tracer()
+_H_SYNC = stage_hist("cmd", "sync")
+_H_OPEN = stage_hist("cmd", "sync", "open")
+_H_LIST = stage_hist("cmd", "sync", "list")
+_H_COPY = stage_hist("cmd", "sync", "copy")
+_H_CHECK = stage_hist("cmd", "sync", "check")
+_H_REPORT = stage_hist("cmd", "sync", "report")
+_OBJECTS = global_registry().counter(
+    "juicefs_sync_objects",
+    "Objects of sync passes by what the pass did with them: content "
+    "compared with their counterpart, found to differ from it (each also "
+    "compared or copied), copied, given up on after an error",
+    ("result",),
+)
+_COUNTED = {r: _OBJECTS.labels(r)
+            for r in ("checked", "mismatch", "copied", "skipped")}
+_CHECKED_BYTES = global_registry().counter(
+    "juicefs_sync_checked_bytes",
+    "Source bytes of the object pairs sync passes content-compared",
+)
 
 
 def _open_store(uri: str):
@@ -48,6 +91,7 @@ def _open_store(uri: str):
                      hedge=False)
 
 CMP_CHUNK = 8 << 20  # streaming-compare window
+HASH_RANGE = 4 << 20  # the digest compare's range: one block of the pipeline
 
 
 def add_parser(sub):
@@ -64,6 +108,13 @@ def add_parser(sub):
                    help="content-compare objects copied this run")
     p.add_argument("--check-all", action="store_true",
                    help="content-compare every object pair")
+    p.add_argument("--hash-backend", default=None, choices=HASH_BACKENDS,
+                   help="compare contents by JTH-256 digest on this hash "
+                        "backend instead of byte by byte on the host (`tpu` "
+                        "fails unless JAX finds a TPU; not in cluster mode)")
+    p.add_argument("--hash-index", default="",
+                   help="with --hash-backend: write every compared object's "
+                        "range digests, both sides, as JSON here")
     p.add_argument("--delete-dst", action="store_true")
     p.add_argument("--delete-src", action="store_true")
     p.add_argument("--include", action="append", default=[])
@@ -214,8 +265,12 @@ def _copy_object(src, dst, obj, args, stats) -> None:
         raise
 
 
-def _make_executor(src, dst, args, stats):
-    """The per-task state machine shared by local and worker modes."""
+def _make_executor(src, dst, args, stats, verify_later: list | None = None):
+    """The per-task state machine shared by local and worker modes. With
+    `verify_later` the `--check-new` compare of a copied object, and the
+    `--delete-src` behind it, are the caller's: the object is appended
+    there once its copy has completed (the digest compare's pair stage
+    takes them when the copies are done)."""
     bucket = _TokenBucket(args.bwlimit) if getattr(args, "bwlimit", 0) else None
 
     def do(task):
@@ -229,13 +284,18 @@ def _make_executor(src, dst, args, stats):
                         bucket.take(s.size)
                     _copy_object(src, dst, s, args, stats)
                     stats.add("copied")
-                    if args.check_new and not _content_equal(
-                            src, dst, s.key, s.size):
-                        stats.add("mismatch")
-                        logger.error("verify failed after copy: %s", s.key)
-                    if args.delete_src:
-                        src.delete(s.key)
-                        stats.add("deleted")
+                    if args.check_new and verify_later is not None:
+                        verify_later.append(s)
+                    else:
+                        if args.check_new:
+                            _CHECKED_BYTES.inc(s.size)
+                            if not _content_equal(src, dst, s.key, s.size):
+                                stats.add("mismatch")
+                                logger.error(
+                                    "verify failed after copy: %s", s.key)
+                        if args.delete_src:
+                            src.delete(s.key)
+                            stats.add("deleted")
             elif op == "del-dst":
                 if not args.dry:
                     dst.delete(d.key)
@@ -246,6 +306,7 @@ def _make_executor(src, dst, args, stats):
                 stats.add("deleted")
             elif op == "check":
                 stats.add("checked")
+                _CHECKED_BYTES.inc(s.size)
                 if not _content_equal(src, dst, s.key, s.size):
                     stats.add("mismatch")
                     logger.error("content mismatch: %s", s.key)
@@ -272,6 +333,8 @@ class _Stats(dict):
     def add(self, key: str, n: int = 1) -> None:
         with self.lock:
             self[key] = self.get(key, 0) + n
+        if key in _COUNTED:
+            _COUNTED[key].inc(n)
 
 
 def _new_stats() -> _Stats:
@@ -281,13 +344,100 @@ def _new_stats() -> _Stats:
                    "mismatch": 0, "skipped": 0, "tasks_done": 0})
 
 
+def _offsets(size: int) -> range:
+    """Where the digest compare's ranges of an object start; an empty
+    object has one, empty, range."""
+    return range(0, max(size, 1), HASH_RANGE)
+
+
+def _ranges(key: str, size: int):
+    """The digest compare's items of one pair: each range of the source,
+    then the same range of the destination."""
+    for off in _offsets(size):
+        n = min(HASH_RANGE, size - off)  # 0: an empty object, read whole
+        yield ("src", key, off, n)
+        yield ("dst", key, off, n)
+
+
+def _check_pairs(src, pairs, copied, stage, args, stats) -> dict:
+    """The pair stage of a pass with a hash backend: `pairs` (the source
+    objects of the diff's `check` tasks) and `copied` (what `--check-new`
+    has to verify), one stream through `stage`, the read-and-hash stage
+    over `_ranges` items. Counts each pair's verdict into `stats` and
+    returns key -> both sides' range digests."""
+    objs = [*pairs, *copied]
+    index: dict[str, dict[str, list[bytes]]] = {}
+    for (side, key, _, _), digest in stage.digests(
+            item for o in objs for item in _ranges(o.key, o.size)):
+        index.setdefault(key, {"src": [], "dst": []})[side].append(digest)
+    if stage.stopped is not None:
+        # the fetch stage gave up on a store: what it had read is compared
+        # below, every pair it did not get to is counted skipped
+        logger.error("%s: the compare stopped early", stage.stopped)
+    for i, o in enumerate(objs):
+        new = i >= len(pairs)  # copied by this pass: no `check` task
+        if not new:
+            stats.add("checked")
+        got = index.get(o.key, {"src": [], "dst": []})
+        want = len(_offsets(o.size))
+        if len(got["src"]) != want or len(got["dst"]) != want:
+            why = next((stage.failed[item] for item in _ranges(o.key, o.size)
+                        if item in stage.failed), "not read")
+            logger.error("check %s: %s", o.key, why)
+            stats.add("skipped")
+        else:
+            stats.add("checked_bytes", o.size)
+            _CHECKED_BYTES.inc(o.size)
+            if got["src"] != got["dst"]:
+                stats.add("mismatch")
+                logger.error("verify failed after copy: %s" if new
+                             else "content mismatch: %s", o.key)
+            elif new and args.delete_src:
+                try:
+                    src.delete(o.key)
+                    stats.add("deleted")
+                except Exception as e:
+                    logger.error("del-src %s: %s", o.key, e)
+                    stats.add("skipped")
+        if not new:
+            stats.add("tasks_done")
+    return index
+
+
 def run(args) -> int:
+    if args.hash_backend and (args.worker or args.manager_listen):
+        logger.error("--hash-backend is for a local pass: cluster mode "
+                     "(--worker, --manager-listen) compares byte by byte")
+        return 2
     if args.worker:
         return run_worker(args)
+    # what the pass started and has to end whatever happens
+    with _TR.span("cmd", "sync", hist=_H_SYNC) as root, \
+            contextlib.ExitStack() as at_exit:
+        return _sync(args, root, at_exit)
 
-    src = _open_store(args.src)
-    dst = _open_store(args.dst)
-    dst.create()
+
+def _sync(args, root, at_exit: contextlib.ExitStack) -> int:
+    """The pass below its root span."""
+    with _TR.span("cmd", "sync", stage="open", hist=_H_OPEN):
+        pipe = None
+        if args.hash_backend and (args.check_all or args.check_new):
+            from ..utils.malloc import keep_freed_blocks
+
+            # a bulk scan of two stores from here on, as `gc --dedup` and
+            # `fsck --verify-data` are of one (utils/malloc.py); before a
+            # store is opened, so before the first GET
+            keep_freed_blocks()
+            # the compare's pipeline, here and not where the hashing
+            # starts: announcing the stream lets helper threads fault its
+            # pack buffers in while this thread lists both stores; `tpu`
+            # without a TPU fails here, before a single key is listed
+            pipe = scan_pipeline(args.hash_backend, HASH_RANGE)
+            at_exit.callback(pipe.release)
+            pipe.prepare()
+        src = _open_store(args.src)
+        dst = _open_store(args.dst)
+        dst.create()
 
     def filtered(store):
         for obj in store.list_all("", args.start):
@@ -303,17 +453,81 @@ def run(args) -> int:
         return run_manager(args, tasks)
 
     stats = _new_stats()
-    do = _make_executor(src, dst, args, stats)
+    index = None
     t0 = time.perf_counter()
     # BACKGROUND class (ISSUE 6): bulk replication yields to any
     # foreground traffic sharing the process and its bandwidth budget
     with global_scheduler().executor(
         "bulk", IOClass.BACKGROUND, width=args.threads
     ) as pool:
-        list(pool.map(do, tasks))
+        if pipe is None:
+            do = _make_executor(src, dst, args, stats)
+            with _TR.span("cmd", "sync", stage="copy", hist=_H_COPY):
+                list(pool.map(do, tasks))
+        else:
+            index = _hashed_pass(src, dst, tasks, pipe, pool, args, stats)
     stats["seconds"] = round(time.perf_counter() - t0, 3)
-    print(json.dumps(stats))
+    with _TR.span("cmd", "sync", stage="report", hist=_H_REPORT):
+        if index is not None and args.hash_index:
+            from ..tpu.jth256 import digest_hex
+
+            with open(args.hash_index, "w") as f:
+                json.dump({key: {side: [digest_hex(d) for d in digests]
+                                 for side, digests in sides.items()}
+                           for key, sides in index.items()}, f, indent=1)
+        if root.active:
+            root.set(backend=stats.get("backend", ""),
+                     checked=stats["checked"], mismatch=stats["mismatch"])
+        print(json.dumps(stats))
     return 1 if stats["mismatch"] else 0
+
+
+def _hashed_pass(src, dst, tasks, pipe, pool, args, stats) -> dict:
+    """A local pass with a hash backend, between `open` and `report`: the
+    diff drained, what it copies or deletes on the worker pool, then every
+    pair it compares through the pair stage. Fills `stats`; returns the
+    range digests of both sides of every object compared."""
+    with _TR.span("cmd", "sync", stage="list", hist=_H_LIST) as sp_list:
+        pairs, others = [], []
+        for task in tasks:
+            if task[0] == "check":
+                pairs.append(task[1])
+            else:
+                others.append(task)
+    copied: list = []
+    if others:
+        do = _make_executor(src, dst, args, stats, verify_later=copied)
+        with _TR.span("cmd", "sync", stage="copy", hist=_H_COPY):
+            list(pool.map(do, others))
+        copied.sort(key=lambda o: o.key)
+    stores = {"src": src, "dst": dst}
+
+    def load(item) -> bytes:
+        side, key, off, n = item
+        store = stores[side]
+        return bytes(store.get(key, off, n) if n else store.get(key))
+
+    stage = ReadHash(load, pool, pipe, args.threads, outlive_open=True)
+    with _TR.span("cmd", "sync", stage="check", hist=_H_CHECK) as sp_check:
+        stats["checked_bytes"] = 0
+        index = _check_pairs(src, pairs, copied, stage, args, stats)
+        hashed = sum(len(d) for sides in index.values()
+                     for d in sides.values())
+        if sp_check.active:
+            sp_check.set(pairs=len(pairs) + len(copied), blocks=hashed,
+                         window=stage.window, ahead=stage.ahead)
+    stats.update({
+        # blocks hashed, both sides; every one on every pass
+        "hashed_now": hashed,
+        # the backend that RAN (requested name: device.requested)
+        "backend": pipe.config.backend,
+        "device": pipe.device_report(),
+        "stage_seconds": {"list": round(sp_list.dur, 6),
+                          **stage.stage_seconds(sp_check.dur)},
+        "fetch_window": stage.window,
+        "fetch_ahead": stage.ahead,
+    })
+    return index
 
 
 # -- cluster mode ----------------------------------------------------------

@@ -15,7 +15,8 @@ accident of its batch sizes (PERF.md §6, PR 27). `keep_freed_blocks()`
 states that end state from the first block on.
 
 Process-wide and for good, so it belongs to the commands whose process *is*
-a scan — `gc --dedup`, `fsck --verify-data` — and is never called from a
+a scan — `gc --dedup`, `fsck --verify-data`, `sync` comparing by digest
+(`--hash-backend`) — and is never called from a
 library function, `mount` or the gateway: a long-lived server's memory
 profile is not a scan's. What it costs: freed blocks stay resident (bounded
 by the fetch window and one batch) plus up to 64 MiB of untrimmed heap top.

@@ -337,9 +337,9 @@ def test_gc_and_fsck_call_the_one_shared_stage(vol, capsys, tmp_path,
     callers = []
     digests = readhash.ReadHash.digests
 
-    def noted(self, keys, sizes):
+    def noted(self, items):
         callers.append(sys._getframe(1).f_globals["__name__"])
-        return digests(self, keys, sizes)
+        return digests(self, items)
 
     monkeypatch.setattr(readhash.ReadHash, "digests", noted)
     m, _ = open_meta(vol.meta_url)
@@ -348,7 +348,17 @@ def test_gc_and_fsck_call_the_one_shared_stage(vol, capsys, tmp_path,
     assert main(["gc", vol.meta_url, "--dedup", "--hash-backend", "cpu"]) == 0
     assert scrub(vol, "cpu", capsys, tmp_path)[0] == 0
     assert callers == ["juicefs_tpu.cmd.gc", "juicefs_tpu.cmd.fsck"]
-    # and neither keeps a copy of the stage's steps
+    # a pass of `sync --check-all --hash-backend` is the third caller
+    from juicefs_tpu.object import create_storage
+
+    for side in ("src", "dst"):
+        bucket = create_storage(f"file://{tmp_path}/{side}/")
+        bucket.create()
+        bucket.put("k", b"the same on both sides")
+    assert main(["sync", f"file://{tmp_path}/src/", f"file://{tmp_path}/dst/",
+                 "--check-all", "--hash-backend", "cpu"]) == 0
+    assert callers[2:] == ["juicefs_tpu.cmd.sync"]
+    # and none keeps a copy of the stage's steps (sync: tests/test_sync_hash.py)
     for mod in (gc, fsck):
         with open(mod.__file__) as f:
             source = f.read()

@@ -144,12 +144,14 @@ def test_gateway_start_up_does_not_ask_for_the_policy(vol, monkeypatch,
 
 def test_nothing_else_of_the_program_names_the_policy():
     """`mount`, the gateway and every library function: the module is
-    imported by the two scan commands and by nobody else."""
+    imported by the three commands whose process is a bulk scan — `gc`,
+    `fsck` and `sync` with a hash backend — and by nobody else."""
     pkg = REPO / "juicefs_tpu"
     naming = {str(p.relative_to(pkg)) for p in pkg.rglob("*.py")
               if any(word in p.read_text() for word in
                      ("utils.malloc", "keep_freed_blocks", "mallopt"))}
-    assert naming == {"utils/malloc.py", "cmd/gc.py", "cmd/fsck.py"}
+    assert naming == {"utils/malloc.py", "cmd/gc.py", "cmd/fsck.py",
+                      "cmd/sync.py"}
     for entry in ("chip_smoke.py", "benchmark/run.py",
                   "benchmark/drivers/scan.py"):
         assert "malloc" not in (REPO / entry).read_text()
