@@ -14,7 +14,14 @@ import time
 import uuid
 from typing import Iterator
 
+from ..metric import global_registry
 from .interface import MultipartUpload, NotFoundError, Obj, ObjectStorage, Part
+
+_LISTED = global_registry().counter(
+    "juicefs_file_list_objects",
+    "Objects file-store listings sized, each by a stat relative to the "
+    "directory it was read from",
+)
 
 
 class FileStorage(ObjectStorage):
@@ -117,26 +124,46 @@ class FileStorage(ObjectStorage):
         return Obj(key=key, size=st.st_size, mtime=st.st_mtime)
 
     def list_all(self, prefix: str = "", marker: str = "") -> Iterator[Obj]:
-        root = self.root
-        if not os.path.isdir(root):
+        """Every object under `prefix` after `marker`, in key order.
+
+        One directory at a time (`os.fwalk`): each entry is sized by a stat
+        relative to the directory it was read from, so no path is resolved
+        again from `/` per object (ISSUE 39). As `os.walk` did: a symlinked
+        directory is not descended, a symlinked file is sized by its target,
+        `.tmp.` files are skipped."""
+        parts = prefix.split("/")
+        rest = parts.pop()  # the beginning of a name, not a directory
+        try:
+            rootfd = os.open(self.root, os.O_RDONLY | os.O_DIRECTORY)
+        except OSError:
             return
-        keys: list[str] = []
-        for dirpath, dirnames, filenames in os.walk(root):
-            dirnames.sort()
-            for fn in filenames:
-                if fn.startswith(".tmp."):
+        found: list[tuple[str, int, float]] = []
+        try:
+            for dirpath, dirnames, filenames, dirfd in os.fwalk(".", dir_fd=rootfd):
+                pre = dirpath[2:] + "/" if dirpath != "." else ""
+                depth = pre.count("/")
+                if depth < len(parts):  # above the prefix: only its path
+                    want = parts[depth]
+                    dirnames[:] = [want] if want in dirnames else []
                     continue
-                rel = os.path.relpath(os.path.join(dirpath, fn), root)
-                key = rel.replace(os.sep, "/")
-                if key.startswith(prefix) and key > marker:
-                    keys.append(key)
-        keys.sort()
-        for key in keys:
-            try:
-                st = os.stat(self._path(key))
-            except FileNotFoundError:
-                continue
-            yield Obj(key=key, size=st.st_size, mtime=st.st_mtime)
+                if depth == len(parts):
+                    dirnames[:] = [d for d in dirnames if d.startswith(rest)]
+                    filenames = [f for f in filenames if f.startswith(rest)]
+                for name in filenames:
+                    key = pre + name
+                    if name.startswith(".tmp.") or key <= marker:
+                        continue
+                    try:
+                        st = os.stat(name, dir_fd=dirfd)
+                    except FileNotFoundError:
+                        continue  # removed since the directory was read
+                    found.append((key, st.st_size, st.st_mtime))
+        finally:
+            os.close(rootfd)
+        found.sort()
+        _LISTED.inc(len(found))
+        for key, size, mtime in found:
+            yield Obj(key=key, size=size, mtime=mtime)
 
     def create_multipart_upload(self, key: str):
         uid = uuid.uuid4().hex
