@@ -177,13 +177,6 @@ class Driver(scan.Driver):
         return {"rc": rc, "wall_s": wall, "stats": stats,
                 "forgot": list(self.block_of), "index_file": index_file}
 
-    # -- the window --------------------------------------------------------
-
-    def window(self, seconds: float) -> dict:
-        window = super().window(seconds)
-        window["work"]["ops"] = window["attempted"] - window["failed"]
-        return window
-
     def release(self) -> None:
         super().release()
         self._stop_server()
@@ -249,27 +242,6 @@ class Driver(scan.Driver):
                  f"reported {mismatches}")
         return 0 if found else 1
 
-    def _log_spans(self, window: dict, run: dict) -> None:
-        """The scrub's own spans over the window, on stderr: what a
-        per-layer metric would read once the manifest has an entry for it
-        (PERF.md section 7 (b))."""
-        ops = max(1, window["work"]["ops"])
-
-        def gained(series: str) -> float:
-            return (run["registry_after"].get(series, 0.0)
-                    - run["registry_before"].get(series, 0.0))
-
-        for layer, op, stage in (("cmd", "fsck", "open"), ("cmd", "fsck", "list"),
-                                 ("cmd", "fsck", "index_load"),
-                                 ("cmd", "fsck", "verify"), ("cmd", "fsck", "report"),
-                                 ("meta", "kv", "roundtrip")):
-            labels = f'{{layer="{layer}",op="{op}",stage="{stage}"}}'
-            n = gained("juicefs_tpu_stage_seconds_count" + labels)
-            if n:
-                mean = gained("juicefs_tpu_stage_seconds_sum" + labels) / n
-                self.log(f"span {layer}.{op}.{stage}: {n / ops:.1f} an op, "
-                         f"mean {mean * 1e3:.3f} ms")
-
     def check(self, window: dict, run: dict) -> dict:
         """Every answer of every op of the window against the plain
         reference: numpy JTH-256 over each distinct content of the plan.
@@ -277,7 +249,6 @@ class Driver(scan.Driver):
         from juicefs_tpu.chunk.cached_store import block_key
 
         t0 = time.perf_counter()
-        self._log_spans(window, run)
         ref = {}
         for b in self.block_of.values():
             if b.content not in ref:

@@ -191,6 +191,7 @@ class Driver:
         failed = sum(1 for op in ops if op["rc"] != 0 or op["stats"] is None)
         sizes = [self.block_of[k].size for op in ops for k in op["forgot"]]
         work = {
+            "ops": len(ops) - failed,  # ops that answered
             "hashed_blocks": len(sizes),
             "hashed_user_bytes": sum(sizes),
             # what the hash has to read at the least: whole 64 KiB lanes

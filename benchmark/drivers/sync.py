@@ -130,13 +130,6 @@ class Driver(scan.Driver):
                 "forgot": self.hashed_keys, "index_file": index_file,
                 "reported": reported}
 
-    # -- the window --------------------------------------------------------
-
-    def window(self, seconds: float) -> dict:
-        window = super().window(seconds)
-        window["work"]["ops"] = window["attempted"] - window["failed"]
-        return window
-
     # -- correct -----------------------------------------------------------
 
     def _mismatch_missed(self, want: dict) -> int:

@@ -10,7 +10,9 @@ accepted cells first and in order (ACCEPTED_CELLS), each accepted metric's
 fields, its list of cells *starting* with those — and never the count of
 cells or of metrics: a later PR appends. An assertion on a manifest list is
 relative to the manifest it started from (a slice by that manifest's length,
-a filter by name), never a literal list or length of the whole. How the
+a filter by name), never a literal list or length of the whole; a traced
+line is held to the names whose `workloads` hold its cell (`listing`), so an
+appended entry may list one cell or a few. How the
 program batches, how many programs or buffers it uses in a rehearsal op, is
 held by tests/test_pack_buffers.py and its like, which any PR may edit; here
 a metric is held to what it means (a share to its range, a part to its
@@ -27,10 +29,21 @@ NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
 ACCEPTED_CELLS = ["scan-cold", "scan-incr", "scan-cold-x4", "scan-cold-bench-mix"]
+# no device plane off the chip: a rehearsal leaves these out, never 0
+DEVICE_METRICS = {"kernel.hash_ms_per_batch", "jth256_roofline",
+                  "kernel.pallas_ms_per_batch", "jth256_pallas_roofline",
+                  "device.idle_share", "device.peak_bytes"}
 
 
 def manifest(root):
     return read_json(os.path.join(root, "BENCHMARK.json"))
+
+
+def listing(root, cell):
+    """The per-layer names whose `workloads` hold `cell`: what its traced
+    line carries on the chip (an entry without the key lists every cell)."""
+    return {e["name"] for e in manifest(root)["per_layer"]
+            if cell in e.get("workloads", [cell])}
 
 
 def bench_dir(root):

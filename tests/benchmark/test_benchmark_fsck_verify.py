@@ -20,7 +20,7 @@ import pytest
 import manifest_checks as checks
 from benchmark import control, run
 from benchmark.lib import plan
-from test_benchmark_grows import DEVICE_METRICS
+from test_benchmark_grows import manifest_root  # noqa: F401 (fixture)
 from test_benchmark_run import (  # noqa: F401 (fixtures)
     any_device, argv, last_line, make_root, over_limit, process_as_new)
 
@@ -33,8 +33,8 @@ COMPARED = {"ops_failed", "op_counts_wrong", "device_reports_wrong",
 # the cell: `gc`'s own spans; two that time a layer from outside and wait to
 # be retired; and four that would put the scrub's reading under another
 # program's or another span's name (the XLA hash's, `gc`'s index load, an op
-# less `dedup_scan`) -- the scrub's own entries wait for a `benchmark` PR
-# (PERF.md section 7 (b))
+# less `dedup_scan`); the scrub's own spans and its kernel come as entries of
+# their own (test_benchmark_stage_metrics.py)
 PARENT_METRICS = 31
 NOT_THE_SCRUBS = {"meta.backfill_ms_per_op", "entry.open_ms_per_op",
                   "entry.list_ms_per_op", "entry.reconcile_ms_per_op",
@@ -120,29 +120,29 @@ def test_the_volume_is_the_accepted_scan_cells_own(seed):
     assert divmod(len(p.blocks), 32) == (16, 5)
 
 
-def test_the_cell_is_appended_to_what_could_read_a_scrub_before_it():
+def test_the_cell_is_appended_to_what_could_read_a_scrub_before_it(manifest_root):
     """Of the entries the parent's manifest had: the cell comes straight
     after the accepted cells where the metric reads a scrub under its own
     name, and is not listed elsewhere. What a later PR appends -- an entry,
     or a cell's name behind this one -- is not this test's to hold."""
-    checks.check_all(REPO)
-    m = checks.manifest(REPO)
+    checks.check_all(manifest_root)
+    m = checks.manifest(manifest_root)
     n = len(checks.ACCEPTED_CELLS)
     assert m["workloads"][n]["name"] == CELL
     was = m["per_layer"][:PARENT_METRICS]
     assert NOT_THE_SCRUBS <= {e["name"] for e in was}
     for entry in was:
-        checks.check_accepted_metric_lists_its_cells(REPO, entry["name"])
+        checks.check_accepted_metric_lists_its_cells(manifest_root, entry["name"])
         if entry["name"] in NOT_THE_SCRUBS:
             assert CELL not in entry["workloads"], entry["name"]
         else:
             assert entry["workloads"][:n + 1] == checks.ACCEPTED_CELLS + [CELL]
 
 
-def test_what_was_accepted_is_entry_for_entry_what_it_was():
+def test_what_was_accepted_is_entry_for_entry_what_it_was(manifest_root):
     """Everything of the manifest that PR 34 left, each accepted list cut to
     the accepted cells: sha256 as the parent of PR 35 gives it (4630eb9)."""
-    m = checks.manifest(REPO)
+    m = checks.manifest(manifest_root)
     n = len(checks.ACCEPTED_CELLS)
     was = {"command": m["command"], "paths": m["paths"],
            "run_seconds": m["run_seconds"], "configs": m["configs"][:3],
@@ -172,15 +172,19 @@ def test_the_cell_runs_traced_with_every_host_metric_that_lists_it(
                     device_check=any_device) == 0
     line = last_line(capsys)
     assert over_limit(line) == {} and line["correct"] is True
-    listing = {e["name"] for e in checks.manifest(small_root)["per_layer"]
-               if CELL in e["workloads"]}
-    assert set(line["metrics"]) == listing - DEVICE_METRICS
+    assert set(line["metrics"]) == (checks.listing(small_root, CELL)
+                                    - checks.DEVICE_METRICS)
     m = {k: v["value"] for k, v in line["metrics"].items()}
     assert m["tpu.compiles_in_window"] == 0
     assert m["tpu.blocks_per_batch"] == BLOCKS
     # both steps of the single-device path are timed, as the plane's are
     assert m["tpu.h2d_ms_per_batch"] > 0 and m["tpu.enqueue_ms_per_batch"] > 0
     assert 0 <= m["tpu.pack_unready_share"] <= m["tpu.pack_fresh_share"]
+    # the scrub's own stages, each once an op, and its round trips
+    assert m["entry.fsck_list_ms_per_op"] > 0 and m["entry.fsck_index_load_ms_per_op"] > 0
+    assert m["meta.kv_roundtrip_ms"] > 0 and m["meta.kv_roundtrips_per_op"] >= 1
+    # every op lists chunks/ once: the volume's block objects, exactly
+    assert m["object.list_objects_per_op"] == BLOCKS
     assert NOT_THE_SCRUBS.isdisjoint(m)
     assert set(line["end_to_end_while_traced"]) == {
         "scan_gibs", "op_p50_ms", "setup_s"}

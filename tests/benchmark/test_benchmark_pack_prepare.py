@@ -9,8 +9,9 @@ PR that brought them) its reader gives nothing and does not raise."""
 import pytest
 
 import manifest_checks as checks
+from test_benchmark_grows import manifest_root  # noqa: F401 (fixture)
 from test_benchmark_program_spans import (  # noqa: F401 (fixtures)
-    REPO, reader, spec_of, traced_line)
+    reader, spec_of, traced_line)
 from test_benchmark_run import process_as_new  # noqa: F401 (fixture)
 
 LAYER = "tpu: pack (tpu/jth256.py:pack_blocks)"
@@ -36,9 +37,10 @@ METRICS = {
 
 
 @pytest.mark.parametrize("metric", METRICS)
-def test_manifest_entry_names_its_layer_and_the_accepted_cells(metric):
-    manifest = checks.manifest(REPO)
-    entry = checks.check_accepted_metric_lists_its_cells(REPO, metric)
+def test_manifest_entry_names_its_layer_and_the_accepted_cells(
+        manifest_root, metric):
+    manifest = checks.manifest(manifest_root)
+    entry = checks.check_accepted_metric_lists_its_cells(manifest_root, metric)
     assert entry == {
         "name": metric, "better": "lower", "layer": LAYER,
         "moves": "scan_gibs", "workloads": entry["workloads"],
@@ -46,8 +48,11 @@ def test_manifest_entry_names_its_layer_and_the_accepted_cells(metric):
     # the layer's name as the accepted benchmark already has it
     assert LAYER in {e["layer"] for e in manifest["per_layer"]
                      if e["name"] not in METRICS}
-    # appended: the last two entries, after everything that was there
-    assert [e["name"] for e in manifest["per_layer"][-2:]] == list(METRICS)
+    # appended after everything that was there, the two in this order;
+    # what a later PR appends comes after them
+    names = [e["name"] for e in manifest["per_layer"]]
+    at = names.index("tpu.blocks_per_batch") + 1
+    assert names[at:at + len(METRICS)] == list(METRICS)
 
 
 @pytest.mark.parametrize("metric", METRICS)

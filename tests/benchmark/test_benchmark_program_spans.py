@@ -74,8 +74,11 @@ def test_the_new_span_metrics_add_up_inside_the_old_ones(traced_line):
              + m["tpu.enqueue_ms_per_batch"])
     assert 0 < parts <= m["tpu.dispatch_ms_per_batch"]
     assert 0 <= m["chunk.fetch_ready_share"] <= 100
-    assert m["entry.open_ms_per_op"] + m["entry.list_ms_per_op"] + m[
-        "entry.reconcile_ms_per_op"] <= m["entry.listing_ms_per_op"]
+    # what the main thread does outside `dedup_scan`; the listing itself
+    # runs on a thread of its own, beside the scan, and is no part of it
+    assert (m["entry.open_ms_per_op"] + m["entry.live_ms_per_op"]
+            + m["entry.list_wait_ms_per_op"] + m["entry.reconcile_ms_per_op"]
+            <= m["entry.listing_ms_per_op"])
     # the test run keeps the persistent cache off: whatever set-up
     # compiled, the compiler built
     assert m["entry.programs_built"] >= 1 and m["entry.compile_s"] > 0

@@ -13,8 +13,10 @@ import shutil
 
 import pytest
 
+import manifest_checks as checks
 from benchmark import control, run
 from benchmark.lib import faults
+from benchmark.lib.plan import plan_of
 
 RESULT_KEYS = ["correct", "attempted", "failed", "metrics", "device"]
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
@@ -90,6 +92,12 @@ def manifest_of(root):
         return json.load(f)
 
 
+def blocks_of(root, config):
+    """Blocks of a root's configuration: every seed plans the same sizes."""
+    body = run.read_json(os.path.join(root, "benchmark", "configs", config + ".json"))
+    return len(plan_of(0, body["volume"]).blocks)
+
+
 def argv(workload, trace=0, seconds=0.5, seed=2**31 + 11):
     return ["--workload", workload, "--seed", str(seed),
             "--seconds", str(seconds), "--trace", str(trace)]
@@ -132,13 +140,15 @@ def test_traced_run_reports_per_layer_metrics_and_a_breakdown(
     assert list(line)[:5] == RESULT_KEYS and list(line)[-1] == "compared"
     assert {"busy_s", "window_s"} <= set(line["device"])
     assert set(line["breakdown"]) == {"device_ops", "idle_gaps"}
-    per_layer = {m["name"] for m in manifest_of(tiny_root)["per_layer"]}
-    # no device plane off the chip: the device's metrics are left out, not 0
-    device_metrics = {"kernel.hash_ms_per_batch", "jth256_roofline",
-                      "device.idle_share", "device.peak_bytes"}
-    assert set(line["metrics"]) == per_layer - device_metrics
+    # what lists the cell; no device plane off the chip: the device's
+    # metrics are left out, not 0
+    assert set(line["metrics"]) == (checks.listing(tiny_root, "scan-incr")
+                                    - checks.DEVICE_METRICS)
     assert line["metrics"]["tpu.compiles_in_window"]["value"] == 0
     assert line["metrics"]["tpu.pack_ms_per_batch"]["value"] > 0
+    # every op lists chunks/ once: the volume's block objects, exactly
+    assert line["metrics"]["object.list_objects_per_op"]["value"] == blocks_of(
+        tiny_root, "scan-sqlite-file-4m")
     assert over_limit(line) == {} and line["correct"] is True
     # the numbers compared are the last lines of stderr, each beside its limit
     tail = captured.err.strip().splitlines()[-len(line["compared"]):]

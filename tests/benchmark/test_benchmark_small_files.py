@@ -23,7 +23,7 @@ import pytest
 import manifest_checks as checks
 from benchmark import control, run
 from benchmark.lib import jth256_spec, plan
-from test_benchmark_grows import DEVICE_METRICS, new_root
+from test_benchmark_grows import new_root
 from test_benchmark_program_spans import reader, spec_of
 from test_benchmark_run import (  # noqa: F401 (fixtures)
     any_device, argv, last_line, over_limit, process_as_new)
@@ -265,9 +265,9 @@ def test_the_cell_runs_traced_to_a_correct_line_with_every_host_metric(
     line = last_line(capsys)
     assert over_limit(line) == {} and line["correct"] is True
     assert line["failed"] == 0 and line["attempted"] >= 1
-    per_layer = {m["name"] for m in checks.manifest(small_root)["per_layer"]}
-    assert METRIC in per_layer
-    assert set(line["metrics"]) == per_layer - DEVICE_METRICS
+    listing = checks.listing(small_root, CELL)
+    assert METRIC in listing
+    assert set(line["metrics"]) == listing - checks.DEVICE_METRICS
     assert set(line["compared"]) == COMPARED
     assert all(c["limit"] == 0 for c in line["compared"].values())
     m = {k: v["value"] for k, v in line["metrics"].items()}
@@ -277,6 +277,8 @@ def test_the_cell_runs_traced_to_a_correct_line_with_every_host_metric(
     # every short block ships a whole block's slot or less: padding, not loss
     assert m["tpu.h2d_bytes_per_user_byte"] >= 1
     assert m["tpu.compiles_in_window"] == 0
+    # every op lists chunks/ once: the volume's block objects, exactly
+    assert m["object.list_objects_per_op"] == 2 * 16 + FILES + 5
     assert set(line["end_to_end_while_traced"]) == {
         "scan_gibs", "op_p50_ms", "setup_s"}
     assert set(line["breakdown"]) == {"device_ops", "idle_gaps"}

@@ -21,7 +21,7 @@ import pytest
 import manifest_checks as checks
 from benchmark import control, run
 from benchmark.lib import mirror, pair_compare, plan
-from test_benchmark_grows import DEVICE_METRICS
+from test_benchmark_grows import manifest_root  # noqa: F401 (fixture)
 from test_benchmark_run import (  # noqa: F401 (fixtures)
     any_device, argv, last_line, make_root, over_limit, process_as_new)
 
@@ -40,6 +40,10 @@ NOT_THE_PASSES = {"entry.listing_ms_per_op", "meta.index_load_ms_per_op",
                   "meta.backfill_ms_per_op", "tpu.pack_ms_per_batch",
                   "entry.open_ms_per_op", "entry.list_ms_per_op",
                   "entry.reconcile_ms_per_op", "entry.scan_faults_per_block"}
+# the pass's own stages, each an entry that lists the cell first
+PASS_STAGES = {"entry.sync_open_ms_per_op": "open", "entry.sync_list_ms_per_op": "list",
+               "entry.sync_check_ms_per_op": "check",
+               "entry.sync_report_ms_per_op": "report"}
 PAIRS = 16 + 6 + 5  # make_root's cut to one big object and six files
 
 
@@ -116,19 +120,20 @@ def test_the_volume_is_the_bench_mix_cells_own(seed):
     assert divmod(2 * len(p.blocks), 32) == (22, 18)
 
 
-def test_the_cell_is_appended_to_what_can_read_a_pass():
+def test_the_cell_is_appended_to_what_can_read_a_pass(manifest_root):
     """Of the entries the parent's manifest had: the cell comes straight
     after the parent's cells where the metric reads a pass under its own
-    name, and is not listed elsewhere; no entry came or went with it."""
-    checks.check_all(REPO)
-    m = checks.manifest(REPO)
+    name, and is not listed elsewhere; no entry came or went with it. The
+    pass's own stages have entries of their own that list it first."""
+    checks.check_all(manifest_root)
+    m = checks.manifest(manifest_root)
     n = len(PARENT_CELLS)
     assert [w["name"] for w in m["workloads"][:n + 1]] == PARENT_CELLS + [CELL]
     was = m["per_layer"][:PARENT_METRICS]
     assert NOT_THE_PASSES <= {e["name"] for e in was}
     listing = []
     for entry in was:
-        checks.check_accepted_metric_lists_its_cells(REPO, entry["name"])
+        checks.check_accepted_metric_lists_its_cells(manifest_root, entry["name"])
         if entry["name"] in NOT_THE_PASSES:
             assert CELL not in entry["workloads"], entry["name"]
         else:
@@ -138,14 +143,15 @@ def test_the_cell_is_appended_to_what_can_read_a_pass():
     assert len(listing) == 23
     # the cell runs the XLA program: the kernel's two lines read it
     assert {"kernel.hash_ms_per_batch", "jth256_roofline"} <= set(listing)
-    # a metric of the pass's own spans waits for a `benchmark` PR
-    assert not [e["name"] for e in m["per_layer"] if "sync" in e["name"]]
+    for name in PASS_STAGES:
+        entry, = [e for e in m["per_layer"] if e["name"] == name]
+        assert entry["workloads"][:1] == [CELL] and entry["moves"] == "op_p50_ms"
 
 
-def test_what_was_accepted_is_entry_for_entry_what_it_was():
+def test_what_was_accepted_is_entry_for_entry_what_it_was(manifest_root):
     """Everything of the manifest that PR 37 left, each list cut back to the
     parent's cells: sha256 as the parent of PR 38 gives it (ae3265b)."""
-    m = checks.manifest(REPO)
+    m = checks.manifest(manifest_root)
     was = {"command": m["command"], "paths": m["paths"],
            "run_seconds": m["run_seconds"], "configs": m["configs"][:4],
            "workloads": m["workloads"][:len(PARENT_CELLS)],
@@ -197,9 +203,8 @@ def test_the_cell_runs_traced_with_every_host_metric_that_lists_it(
                     device_check=any_device) == 0
     line = last_line(capsys)
     assert over_limit(line) == {} and line["correct"] is True
-    listing = {e["name"] for e in checks.manifest(small_root)["per_layer"]
-               if CELL in e["workloads"]}
-    assert set(line["metrics"]) == listing - DEVICE_METRICS
+    assert set(line["metrics"]) == (checks.listing(small_root, CELL)
+                                    - checks.DEVICE_METRICS)
     m = {k: v["value"] for k, v in line["metrics"].items()}
     assert m["tpu.compiles_in_window"] == 0
     # both sides of every pair, every pass: 54 blocks, a batch of 32 and a
@@ -210,6 +215,11 @@ def test_the_cell_runs_traced_with_every_host_metric_that_lists_it(
     assert m["tpu.h2d_bytes_per_user_byte"] >= 1.0
     assert m["object.get_ms"] > 0 and m["chunk.get_wall_ms_per_op"] > 0
     assert 0 <= m["chunk.fetch_ready_share"] <= 100
+    # each stage once a pass, the pair stage the longest of them
+    stages = {stage: m[name] for name, stage in PASS_STAGES.items()}
+    assert min(stages.values()) > 0 and max(stages, key=stages.get) == "check"
+    # both stores listed once a pass: every pair's two objects, exactly
+    assert m["object.list_objects_per_op"] == 2 * PAIRS
     assert NOT_THE_PASSES.isdisjoint(m)
     assert set(line["end_to_end_while_traced"]) == {
         "scan_gibs", "op_p50_ms", "setup_s"}
