@@ -70,6 +70,10 @@ _WAIT_READY = _WAITS.labels("1")
 _WAIT_BLOCKED = _WAITS.labels("0")
 _TR = global_tracer()
 _H_WAIT = stage_hist("chunk", "fetch", "wait")
+# an item's wait on the pool's queue: handed to `pool.submit` until its
+# call starts on a pool thread, one observation an item that ran (a stamp,
+# not a span: it costs the same whether anyone listens or not)
+_H_QUEUE = stage_hist("chunk", "fetch", "queue")
 
 
 class FetchStats:
@@ -133,13 +137,14 @@ class _Slot:
     consumed.  `fut` is the stage's own: the consumer can wait on an item
     that no thread has submitted yet."""
 
-    __slots__ = ("item", "ref", "fut", "job")
+    __slots__ = ("item", "ref", "fut", "job", "queued")
 
     def __init__(self, item, ref) -> None:
         self.item = item
         self.ref = ref  # the consumer's span, for the pool thread
         self.fut: Future = Future()
         self.job: Optional[Future] = None  # the pool's, once submitted
+        self.queued = 0.0  # perf_counter when handed to the pool
 
 
 def fetch_ordered(
@@ -182,9 +187,10 @@ def fetch_ordered(
     window = max(1, int(window))
     depth = window + max(0, int(ahead))
 
-    def timed(item: T, ref) -> R:
+    def timed(item: T, ref, queued: float) -> R:
         _INFLIGHT.inc()
         start = time.perf_counter()
+        _H_QUEUE.observe(start - queued)
         if stats is not None:
             stats._begin(start)
         try:
@@ -229,6 +235,7 @@ def fetch_ordered(
 
     def submit(slot: _Slot) -> None:
         # outside the lock: a BACKGROUND submit may wait for queue space
+        slot.queued = time.perf_counter()
         job = pool.submit(run, slot)
         slot.job = job
         # the callback holds the slot's future, not the slot: slot -> job ->
@@ -257,7 +264,7 @@ def fetch_ordered(
 
     def run(slot: _Slot) -> None:
         try:
-            out, err = timed(slot.item, slot.ref), None
+            out, err = timed(slot.item, slot.ref, slot.queued), None
         except BaseException as e:
             out, err = None, e
         # the call's end is counted before the consumer can see its result:

@@ -18,10 +18,15 @@ import sys
 from ..chunk import CachedStore, ChunkConfig
 from ..meta import new_client
 from ..meta.types import Format
+from ..metric.trace import global_tracer, stage_hist
 from ..object import create_storage, sharded, with_prefix
 from ..utils import get_logger
 
 logger = get_logger("cmd")
+_TR = global_tracer()
+# building the parser of every command and parsing the arguments, one an
+# invocation of `main`
+_H_PARSE = stage_hist("cmd", "main", "parse")
 
 
 def open_meta(addr: str, **kw):
@@ -175,17 +180,18 @@ def main(argv: list[str] | None = None) -> int:
         warmup,
     )
 
-    parser = argparse.ArgumentParser(
-        prog="juicefs-tpu",
-        description="TPU-native JuiceFS-capability distributed file system",
-    )
-    sub = parser.add_subparsers(dest="command", required=True)
-    for mod in (
-        format_cmd, mount, bench, objbench, gc, fsck, sync, dump, warmup,
-        info, gateway, stats, quota, meta_server, config,
-    ):
-        mod.add_parser(sub)
-    args = parser.parse_args(argv)
+    with _TR.span("cmd", "main", stage="parse", hist=_H_PARSE):
+        parser = argparse.ArgumentParser(
+            prog="juicefs-tpu",
+            description="TPU-native JuiceFS-capability distributed file system",
+        )
+        sub = parser.add_subparsers(dest="command", required=True)
+        for mod in (
+            format_cmd, mount, bench, objbench, gc, fsck, sync, dump, warmup,
+            info, gateway, stats, quota, meta_server, config,
+        ):
+            mod.add_parser(sub)
+        args = parser.parse_args(argv)
     try:
         return args.func(args) or 0
     except KeyboardInterrupt:

@@ -46,6 +46,7 @@ import time
 from ..metric import global_registry
 from ..metric.trace import global_tracer, stage_hist
 from ..object import create_storage
+from ..object.metered import metered
 from ..object.resilient import RetryPolicy, resilient
 from ..qos import IOClass, global_scheduler
 from ..tpu.device import HASH_BACKENDS
@@ -79,13 +80,15 @@ _CHECKED_BYTES = global_registry().counter(
 def _open_store(uri: str):
     """Sync endpoints go through the resilience wrapper (ISSUE 3: no
     bare-store escapes): classified retries per object op, per-backend
-    breaker.  Hedging stays off — bulk copy already runs `--threads`
-    wide, and doubling GETs there is bandwidth, not tail latency.  The
+    breaker; beneath it the metering one, as on every stack
+    (object/metered.py), so each attempt is timed at the store.  Hedging
+    stays off — bulk copy already runs `--threads` wide, and doubling
+    GETs there is bandwidth, not tail latency.  The
     wall deadline is effectively unbounded: a multi-GiB part on a slow
     link may LEGITIMATELY take many minutes, and the wrapper cannot
     know object sizes — the pre-existing contract (ops run to
     completion, failed objects retry on later passes) stays intact."""
-    return resilient(create_storage(uri),
+    return resilient(metered(create_storage(uri)),
                      policy=RetryPolicy(deadline=7 * 86400.0,
                                         max_attempts=5),
                      hedge=False)

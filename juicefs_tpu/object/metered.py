@@ -11,6 +11,7 @@ wraps its storage with `metered()` automatically; wrapping is idempotent.
 from __future__ import annotations
 
 import errno as _errno
+import time
 
 from ..metric import global_registry
 from ..metric.trace import global_tracer
@@ -23,6 +24,16 @@ _reg = global_registry()
 _DUR = _reg.histogram(
     "juicefs_object_request_durations_histogram_seconds",
     "Object storage request latencies (reference cached_store.go:653-932)",
+    ("method", "backend"),
+)
+# beside the latency of a GET, the CPU time its thread spent in it: the
+# difference is time off the CPU (waiting for the interpreter lock, or
+# blocked in I/O). Where the kernel charges thread CPU time in ticks (10
+# ms on some sandboxed kernels), one GET reads 0 or a tick and only the
+# mean over many GETs is the CPU time
+_CPU = _reg.histogram(
+    "juicefs_object_request_cpu_seconds",
+    "Thread CPU time of object storage requests",
     ("method", "backend"),
 )
 _ERRORS = _reg.counter(
@@ -55,6 +66,7 @@ class MeteredStorage(ObjectStorage):
         self._h_put = _DUR.labels("PUT", backend)
         self._h_delete = _DUR.labels("DELETE", backend)
         self._h_head = _DUR.labels("HEAD", backend)
+        self._c_get = _CPU.labels("GET", backend)
         self._e_get = _ERRORS.labels("GET", backend)
         self._e_put = _ERRORS.labels("PUT", backend)
         self._e_delete = _ERRORS.labels("DELETE", backend)
@@ -68,6 +80,7 @@ class MeteredStorage(ObjectStorage):
     # -- metered data ops --------------------------------------------------
     def get(self, key: str, off: int = 0, limit: int = -1) -> bytes:
         with _TR.span("object", "get", hist=self._h_get) as sp:
+            cpu = time.thread_time()
             try:
                 data = self._inner.get(key, off, limit)
             except NotFoundError:
@@ -79,9 +92,12 @@ class MeteredStorage(ObjectStorage):
                 if sp.active:
                     sp.set(key=key, error=type(e).__name__)
                 raise
+            finally:
+                cpu = time.thread_time() - cpu
+                self._c_get.observe(cpu)
             self._b_get.inc(len(data))
             if sp.active:
-                sp.set(key=key, bytes=len(data), backend=self.backend)
+                sp.set(key=key, bytes=len(data), backend=self.backend, cpu=cpu)
             return data
 
     def put(self, key: str, data: bytes) -> None:

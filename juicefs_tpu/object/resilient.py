@@ -46,7 +46,7 @@ from enum import Enum, IntEnum
 from typing import Callable, Optional
 
 from ..metric import global_registry
-from ..metric.trace import global_tracer
+from ..metric.trace import global_tracer, stage_hist
 from ..qos import context as _qctx
 from ..utils import get_logger
 from .interface import NotFoundError, ObjectStorage, PermanentError, ThrottleError
@@ -100,6 +100,13 @@ _SHED_LIMIT = _reg.gauge(
     "Current concurrency limit of the throttle shed per backend",
     ("backend",),
 )
+# a GET's two hand-offs across the elastic pool, from stamps on its call
+# (not spans: they cost the same whether anyone listens or not): `queue`
+# from the submit until the call starts on a pool thread, `wake` from the
+# call's end until the caller has its result back; one each for every GET
+# attempt whose result came from the leg submitted first
+_H_GET_QUEUE = stage_hist("object", "get", "queue")
+_H_GET_WAKE = stage_hist("object", "get", "wake")
 
 
 class ErrorClass(Enum):
@@ -437,6 +444,13 @@ _POOL_IDLE_TTL = 5.0
 _STOP = object()
 
 
+class _Call(Future):
+    """The future of one call on the elastic pool, stamped (perf_counter)
+    when it was submitted, started and ended on its worker."""
+
+    __slots__ = ("t_submit", "t_start", "t_end")
+
+
 class _ElasticPool:
     """Daemon-thread pool whose workers may be ABANDONED mid-call.
 
@@ -462,8 +476,9 @@ class _ElasticPool:
         self._seq = 0
         self._closed = False
 
-    def submit(self, fn: Callable[[], object]) -> Future:
-        fut: Future = Future()
+    def submit(self, fn: Callable[[], object]) -> _Call:
+        fut = _Call()
+        fut.t_submit = time.perf_counter()
         with self._lock:
             if self._closed:
                 raise RuntimeError("resilience pool is closed")
@@ -501,10 +516,14 @@ class _ElasticPool:
             fut, fn = item
             if not fut.set_running_or_notify_cancel():
                 continue
+            fut.t_start = time.perf_counter()
             try:
-                fut.set_result(fn())
+                out = fn()
             except BaseException as e:  # noqa: BLE001 — future carries it
                 fut.set_exception(e)
+            else:
+                fut.t_end = time.perf_counter()
+                fut.set_result(out)
 
     def close(self) -> None:
         with self._lock:
@@ -574,6 +593,9 @@ class ResilientStorage(ObjectStorage):
         self.hedge_delay = hedge_delay
         self._pool = _ElasticPool(f"objio-{backend}")
         self._shed = _Shed(self.metric_backend)
+        # bound now, so that a store that never hedged reads 0, not nothing
+        self._hedges = _HEDGES.labels(self.metric_backend)
+        self._hedge_wins = _HEDGE_WINS.labels(self.metric_backend)
         self._get_hist = None  # lazily bound (metered may sit below us)
 
     def __getattr__(self, name):
@@ -705,16 +727,26 @@ class ResilientStorage(ObjectStorage):
             with _TR.carried(ref):
                 return fn()
 
+    @staticmethod
+    def _handed(fut: _Call) -> None:
+        """A GET's result is back on the caller's thread: its two
+        hand-offs across the pool."""
+        _H_GET_WAKE.observe(time.perf_counter() - fut.t_end)
+        _H_GET_QUEUE.observe(fut.t_start - fut.t_submit)
+
     def _bounded(self, method: str, fn: Callable[[], object], timeout: float):
         fut = self._submit(fn)
         try:
-            return fut.result(timeout=max(timeout, 0.001))
+            out = fut.result(timeout=max(timeout, 0.001))
         except _FutTimeout:
             fut.cancel()  # not started: dropped; started: abandoned
             _ABANDONED.labels(method).inc()
             raise DeadlineExceeded(
                 f"{method} {self.backend}: abandoned after {timeout:.3f}s"
             ) from None
+        if method == "GET":
+            self._handed(fut)
+        return out
 
     def _hedge_after(self) -> float:
         if self.hedge_delay is not None:
@@ -739,11 +771,14 @@ class ResilientStorage(ObjectStorage):
         t0 = time.monotonic()
         primary = self._submit(fn)
         try:
-            return primary.result(timeout=delay)
+            out = primary.result(timeout=delay)
         except _FutTimeout:
             pass  # primary is slow: hedge below
+        else:
+            self._handed(primary)
+            return out
         # (a fast primary *failure* raises here and _call classifies it)
-        _HEDGES.labels(self.metric_backend).inc()
+        self._hedges.inc()
         pending = {primary, self._submit(fn)}
         hedged = {f for f in pending if f is not primary}
         last_exc: Optional[BaseException] = None
@@ -770,7 +805,9 @@ class ResilientStorage(ObjectStorage):
                     last_exc = e
                     continue
                 if f in hedged:
-                    _HEDGE_WINS.labels(self.metric_backend).inc()
+                    self._hedge_wins.inc()
+                else:
+                    self._handed(f)
                 for p in pending:
                     p.cancel()
                 return result

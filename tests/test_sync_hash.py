@@ -340,9 +340,9 @@ def stage_count(stage):
 
 
 def test_a_pass_is_one_trace_tree(buckets, one_pass):
-    """cmd.sync -> {open, list, check -> {chunk.fetch.wait, tpu.hash.dispatch
-    -> {pack, h2d, enqueue}, tpu.hash.drain}, report}; over a complete mirror
-    nothing is copied, so no `copy`."""
+    """cmd.sync -> {open, list, check -> {chunk.fetch.wait, object.get,
+    tpu.hash.dispatch -> {pack, h2d, enqueue}, tpu.hash.drain}, report}; over
+    a complete mirror nothing is copied, so no `copy`."""
     from test_trace import _reader
 
     b = buckets.mirror()
@@ -360,7 +360,10 @@ def test_a_pass_is_one_trace_tree(buckets, one_pass):
     root = next(e for e in evs if name_of[e["id"]] == "cmd.sync")
     assert root["parent"] == 0 and root["backend"] == "xla"
     assert (root["checked"], root["mismatch"]) == (len(SIZES), 0)
-    assert {e["trace"] for e in evs} == {root["trace"]}
+    # `main`'s argument parsing is a trace of its own, before the pass's
+    parse, = [e for e in evs if name_of[e["id"]] == "cmd.main.parse"]
+    assert parse["parent"] == 0
+    assert {e["trace"] for e in evs if e is not parse} == {root["trace"]}
     below_root = sorted(name_of[e["id"]] for e in evs
                         if e["parent"] == root["id"])
     assert below_root == ["cmd.sync.check", "cmd.sync.list", "cmd.sync.open",
@@ -371,7 +374,8 @@ def test_a_pass_is_one_trace_tree(buckets, one_pass):
             check["ahead"]) == (len(SIZES), blocks, 4, 32)
     for e in evs:
         name = name_of[e["id"]]
-        if name in ("chunk.fetch.wait", "tpu.hash.dispatch", "tpu.hash.drain"):
+        if name in ("chunk.fetch.wait", "tpu.hash.dispatch", "tpu.hash.drain",
+                    "object.get"):
             assert name_of[e["parent"]] == "cmd.sync.check", name
         elif name in ("tpu.hash.pack", "tpu.hash.h2d", "tpu.hash.enqueue"):
             assert name_of[e["parent"]] == "tpu.hash.dispatch", name
@@ -379,6 +383,8 @@ def test_a_pass_is_one_trace_tree(buckets, one_pass):
             assert name_of[e["parent"]] == "cmd.sync.open"
     counts = {n: _names(evs).count(n) for n in set(_names(evs))}
     assert counts["chunk.fetch.wait"] == blocks
+    # both stores are metered: one `object.get` a range read, either side
+    assert counts["object.get"] == blocks
     assert (counts["tpu.hash.pack"] == counts["tpu.hash.h2d"]
             == counts["tpu.hash.enqueue"] == counts["tpu.hash.dispatch"]
             == counts["tpu.hash.drain"] == 1)

@@ -389,7 +389,12 @@ def test_gc_dedup_scan_is_one_trace_tree(tmp_path, capsys):
     root = next(e for e in evs if name_of[e["id"]] == "cmd.gc")
     assert root["parent"] == 0 and root["backend"] == "xla"
     assert (root["blocks"], root["hashed_now"]) == (9, 9)
-    assert {e["trace"] for e in evs} == {root["trace"]}
+    # `main` parses the arguments before the command's root opens: a trace
+    # of its own, ended before the command's begins
+    parse, = [e for e in evs if name_of[e["id"]] == "cmd.main.parse"]
+    assert parse["parent"] == 0 and parse["tid"] == root["tid"]
+    assert parse["ts"] + parse["dur"] <= root["ts"] + 1e-6
+    assert {e["trace"] for e in evs if e is not parse} == {root["trace"]}
     stages = {name_of[e["id"]] for e in evs if e["parent"] == root["id"]}
     assert {"cmd.gc.open", "cmd.gc.live", "cmd.gc.list", "cmd.gc.index_load",
             "cmd.gc.readhash", "cmd.gc.backfill", "cmd.gc.group",
