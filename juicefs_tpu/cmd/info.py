@@ -73,6 +73,7 @@ def _object_plane_status(fmt) -> dict:
                 "probe": probe,
                 "policy": h["policy"],
                 "hedge": h["hedge"],
+                "inline": h["inline"],
                 "live_state": "read <mountpoint>/.status on an active "
                               "mount for breaker/ladder state",
             }

@@ -540,6 +540,10 @@ def _hashed_pass(src, dst, tasks, pipe, pool, args, stats) -> dict:
 #   POST /stats {<stats>}  -> {}
 
 _BATCH = 256
+# how long the manager keeps answering after the queue drained and the last
+# request came: a worker started beside the one that drained it may
+# register only then, and is owed an empty batch, not a closed socket
+_LINGER = 3.0
 
 
 def _obj_wire(o):
@@ -723,6 +727,13 @@ def run_manager(args, tasks) -> int:
                 logger.error("every worker launch command failed")
                 timed_out = True
                 break
+    while not timed_out:
+        with lock:
+            quiet = time.monotonic() - state["last_activity"]
+        if quiet >= _LINGER or (
+                workers and all(p.poll() is not None for p in workers)):
+            break
+        time.sleep(0.05)
     httpd.shutdown()
     httpd.server_close()
     worker_failed = _reap_workers(workers)

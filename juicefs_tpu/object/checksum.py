@@ -53,6 +53,10 @@ class _Checksummed(ObjectStorage):
     def __init__(self, store: ObjectStorage):
         self._s = store
 
+    @property
+    def host_local(self) -> bool:
+        return getattr(self._s, "host_local", False)
+
     def string(self) -> str:
         return self._s.string()
 

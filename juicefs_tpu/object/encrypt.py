@@ -110,6 +110,10 @@ class _Encrypted(ObjectStorage):
         self._s = store
         self._e = enc
 
+    @property
+    def host_local(self) -> bool:
+        return getattr(self._s, "host_local", False)
+
     def string(self) -> str:
         return self._s.string()
 

@@ -59,6 +59,9 @@ class FaultyStore(ObjectStorage):
     """
 
     _KEEP = object()  # fault_config sentinel: leave the setting unchanged
+    # never host-local, whatever it wraps: its hangs are what the drills
+    # abandon on the resilience layer's pool
+    host_local = False
 
     def __init__(self, store: ObjectStorage, error_rate: float = 0.0,
                  get_error_rate: float | None = None,

@@ -25,6 +25,10 @@ _LISTED = global_registry().counter(
 
 
 class FileStorage(ObjectStorage):
+    # every call is a system call on this host's file system: the
+    # resilience layer runs its attempts on the caller (object/resilient.py)
+    host_local = True
+
     def __init__(self, root: str):
         # file:///abs/path arrives as "/abs/path"; relative allowed for tests
         self.root = root if root.endswith("/") else root + "/"

@@ -13,6 +13,10 @@ class _Prefixed(ObjectStorage):
         self._s = store
         self._p = prefix
 
+    @property
+    def host_local(self) -> bool:
+        return getattr(self._s, "host_local", False)
+
     def string(self) -> str:
         return self._s.string() + self._p
 

@@ -13,6 +13,10 @@ Barroso "The Tail at Scale"):
                    jitter)` budget per op.  Attempts run on an elastic
                    daemon pool and are ABANDONED at their bound — a hung
                    backend can never pin an upload/download pool worker.
+                   A store whose calls are system calls on this host
+                   (`host_local`, e.g. `file://`) runs each attempt on the
+                   caller instead, unhedged, unless `attempt_timeout` asks
+                   for abandonment — meta/resilient.py's rule.
   circuit breaker  per-backend closed → open on failure rate over a
                    sliding window; half-open via background probes;
                    `juicefs_object_breaker_state` gauge + trip/reset
@@ -100,11 +104,18 @@ _SHED_LIMIT = _reg.gauge(
     "Current concurrency limit of the throttle shed per backend",
     ("backend",),
 )
+_INLINE = _reg.counter(
+    "juicefs_object_inline_requests",
+    "Object request attempts run on the calling thread (a store whose "
+    "calls are system calls on this host, with no attempt bound)",
+    ("backend", "method"),
+)
 # a GET's two hand-offs across the elastic pool, from stamps on its call
 # (not spans: they cost the same whether anyone listens or not): `queue`
 # from the submit until the call starts on a pool thread, `wake` from the
 # call's end until the caller has its result back; one each for every GET
-# attempt whose result came from the leg submitted first
+# attempt whose result came from the leg submitted first.  An attempt run
+# on the caller stamps the same two stretches, each then a function call
 _H_GET_QUEUE = stage_hist("object", "get", "queue")
 _H_GET_WAKE = stage_hist("object", "get", "wake")
 
@@ -593,13 +604,25 @@ class ResilientStorage(ObjectStorage):
         self.hedge_delay = hedge_delay
         self._pool = _ElasticPool(f"objio-{backend}")
         self._shed = _Shed(self.metric_backend)
+        # the store's own declaration, through the wrappers below us
+        # (each reports its inner store's answer; FaultyStore says no)
+        self._host_local = bool(getattr(inner, "host_local", False))
         # bound now, so that a store that never hedged reads 0, not nothing
         self._hedges = _HEDGES.labels(self.metric_backend)
         self._hedge_wins = _HEDGE_WINS.labels(self.metric_backend)
+        # scheme-shaped, as the metered GET histogram: the mirror check's
+        # two stores share one series
+        self._inline_gets = _INLINE.labels(backend, "GET")
         self._get_hist = None  # lazily bound (metered may sit below us)
 
     def __getattr__(self, name):
         return getattr(self._s, name)
+
+    @property
+    def inline(self) -> bool:
+        """Attempts run on the caller: the store's calls are system calls
+        on this host and no attempt bound asks for abandonment."""
+        return self._host_local and self.policy.attempt_timeout is None
 
     # -- health / ladder hooks ---------------------------------------------
     @property
@@ -625,6 +648,7 @@ class ResilientStorage(ObjectStorage):
                 "delay": self.hedge_delay if self.hedge_delay is not None
                 else "auto(p95)",
             },
+            "inline": self.inline,
             "shed_limit": self._shed.limit,
         }
 
@@ -700,12 +724,33 @@ class ResilientStorage(ObjectStorage):
 
     def _attempt(self, method: str, fn: Callable[[], object],
                  remaining: float, hedge: bool):
+        if self.inline:
+            return self._on_caller(method, fn)
         timeout = remaining
         if self.policy.attempt_timeout is not None:
             timeout = min(self.policy.attempt_timeout, remaining)
         if hedge and self.hedge_enabled:
             return self._hedged_attempt(method, fn, timeout)
         return self._bounded(method, fn, timeout)
+
+    def _on_caller(self, method: str, fn: Callable[[], object]):
+        """One attempt of a host-local store, on the caller's thread.  A
+        second read of the same local file queues behind the first in the
+        same kernel, so a hedge buys nothing; the op deadline is still
+        checked before every attempt (`_call`).  A GET stamps the pool
+        path's two stretches, each now a function call."""
+        t_handed = time.perf_counter()
+        if method == "GET":
+            self._inline_gets.inc()
+        else:
+            _INLINE.labels(self.backend, method).inc()
+            return fn()
+        t_start = time.perf_counter()
+        out = fn()
+        t_end = time.perf_counter()
+        _H_GET_WAKE.observe(time.perf_counter() - t_end)
+        _H_GET_QUEUE.observe(t_start - t_handed)
+        return out
 
     def _submit(self, fn: Callable[[], object]) -> Future:
         # span context must survive the pool crossing: the metered wrapper
@@ -888,6 +933,7 @@ _SNAPSHOT_COUNTERS = (
     "juicefs_object_deadline_abandoned",
     "juicefs_object_hedged_requests",
     "juicefs_object_hedge_wins",
+    "juicefs_object_inline_requests",
     "juicefs_object_breaker_trips",
     "juicefs_object_breaker_resets",
 )

@@ -22,6 +22,10 @@ class _Sharded(ObjectStorage):
         self._stores = stores
         self._shard_ops = [_SHARD_OPS.labels(str(i)) for i in range(len(stores))]
 
+    @property
+    def host_local(self) -> bool:
+        return all(getattr(s, "host_local", False) for s in self._stores)
+
     def _pick(self, key: str) -> ObjectStorage:
         # stable fnv-ish hash by key, like the reference's hash-by-name
         h = zlib.crc32(key.encode()) & 0xFFFFFFFF

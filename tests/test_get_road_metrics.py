@@ -1,11 +1,13 @@
 """A block's GET measured from inside, where each part happens: the fetch
-stage's queue (chunk/parallel.py), the resilience pool's two hand-offs
-(object/resilient.py), the store's own read and its thread CPU time
+stage's queue (chunk/parallel.py), the resilience layer's two hand-offs
+(object/resilient.py; over `file://` each attempt runs on the caller and
+the two are function calls), the store's own read and its thread CPU time
 (object/metered.py) — one observation of each a GET, nested inside the
 `chunk.load.fetch` span and the fetch stage's own time of the call — and
 `main`'s argument parsing, one an invocation. Through `gc --dedup`,
 `fsck --verify-data` and `sync --check-all` over `file://`, and a hedged GET
-whose first leg answers or does not."""
+(on the pool, over a store that is not host-local) whose first leg answers
+or does not."""
 
 import json
 import threading
@@ -20,8 +22,7 @@ from juicefs_tpu.metric import global_registry
 from juicefs_tpu.object import MemStorage
 from juicefs_tpu.object.fault import FaultyStore
 from juicefs_tpu.object.metered import metered
-from juicefs_tpu.object.resilient import (
-    ResilientStorage, RetryPolicy, resilient)
+from juicefs_tpu.object.resilient import RetryPolicy, resilient
 
 STAGE = "juicefs_tpu_stage_seconds"
 DUR = "juicefs_object_request_durations_histogram_seconds"
@@ -37,6 +38,13 @@ PARTS = {
     "parse": (STAGE, ("cmd", "main", "parse")),
 }
 EPS = 1e-6  # the stamps' own order: a microsecond
+
+
+def inline_gets():
+    """GET attempts a `file://` store's resilience layer ran on the caller."""
+    child = global_registry()._metrics[
+        "juicefs_object_inline_requests"]._children.get(("file", "GET"))
+    return child.value if child is not None else 0
 
 
 def hist(name, labels):
@@ -60,13 +68,6 @@ def gained(before, parts=PARTS):
     return out
 
 
-@pytest.fixture
-def no_hedge_timer(monkeypatch):
-    """A hedge delay no local GET reaches: every GET takes the hedge's fast
-    path, whatever the machine's load, so every count is exact."""
-    monkeypatch.setattr(ResilientStorage, "_hedge_after", lambda self: 30.0)
-
-
 def _stats(capsys, argv):
     capsys.readouterr()
     assert main(argv) == 0
@@ -75,7 +76,7 @@ def _stats(capsys, argv):
 
 @pytest.mark.parametrize("command", ["gc", "fsck"])
 def test_every_part_is_one_observation_a_block_nested_in_the_load(
-        tmp_path, capsys, no_hedge_timer, command):
+        tmp_path, capsys, command):
     from test_trace import _scan_volume
 
     meta_url = _scan_volume(tmp_path, blocks=9)
@@ -87,11 +88,13 @@ def test_every_part_is_one_observation_a_block_nested_in_the_load(
                 "--threads", "4"]
     else:
         argv = gc_argv
-    before = snapshot()
+    before, inline0 = snapshot(), inline_gets()
     stats = _stats(capsys, argv)
     got = gained(before)
     blocks = stats["hashed_now"]
     assert blocks == 9
+    # every GET one attempt on the fetch thread: no pool between, no hedge
+    assert inline_gets() == inline0 + blocks
     assert got["parse"][0] == 1  # one `main`
     for part in PARTS:
         if part != "parse":
@@ -114,7 +117,7 @@ def test_the_mirror_checks_stores_are_metered(tmp_path, capsys):
     from test_sync_hash import SIZES, Buckets
 
     b = Buckets(tmp_path).mirror()
-    before = snapshot()
+    before, inline0 = snapshot(), inline_gets()
     stats = _stats(capsys, ["sync", b.src_url, b.dst_url, "--threads", "4",
                             "--check-all", "--hash-backend", "xla"])
     got = gained(before)
@@ -122,6 +125,7 @@ def test_the_mirror_checks_stores_are_metered(tmp_path, capsys):
     # one of 9 MiB + 5; both sides
     ranges = 2 * (len(SIZES) + 2)
     assert stats["checked"] == len(SIZES) and stats["hashed_now"] == ranges
+    assert inline_gets() == inline0 + ranges  # both stores on one series
     for part in ("fetch_queue", "resilient_queue", "resilient_wake", "store",
                  "store_cpu"):
         assert got[part][0] == ranges, part

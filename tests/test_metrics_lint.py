@@ -54,6 +54,22 @@ def test_ingest_registry_pinned():
     assert "rogue" in text                       # stray under prefix
 
 
+def test_object_plane_registry_pinned():
+    """The juicefs_object_* series — meters, shard routing, the resilience
+    family the benchmark's road metrics read — all exist; nothing squats
+    under the prefix."""
+    lint = _load_lint()
+    assert lint.lint_object() == []
+    from juicefs_tpu.metric import Registry
+
+    reg = Registry()
+    reg.counter("juicefs_object_rogue", "unreviewed")
+    problems = lint.lint_object(registry=reg)
+    text = "\n".join(problems)
+    assert "juicefs_object_inline_requests" in text  # missing expected
+    assert "rogue" in text                            # stray under prefix
+
+
 def test_ingest_seam_lint():
     """WSlice uploads must route through the ingest stage when present:
     the AST check passes on the real tree and bites on a bare upload."""

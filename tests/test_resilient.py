@@ -11,13 +11,20 @@ import time
 import pytest
 
 from juicefs_tpu.metric import global_registry
-from juicefs_tpu.object import create_storage
+from juicefs_tpu.object import (
+    FileStorage,
+    create_storage,
+    new_checksummed,
+    sharded,
+    with_prefix,
+)
 from juicefs_tpu.object.fault import FaultyStore, InjectedThrottle
 from juicefs_tpu.object.interface import (
     NotFoundError,
     PermanentError,
     ThrottleError,
 )
+from juicefs_tpu.object.metered import metered
 from juicefs_tpu.object.resilient import (
     BreakerOpenError,
     BreakerState,
@@ -616,5 +623,177 @@ def test_no_hedge_when_delay_equals_attempt_budget():
             rs.get("k")
         assert counter("juicefs_object_hedged_requests",
                        rs.metric_backend).value == h0, "pointless hedge issued"
+    finally:
+        rs.close()
+
+
+# -- a host-local store: each attempt on the caller ------------------------------
+
+class ThreadFile(FileStorage):
+    """A `file://` store noting the thread each call runs on; `fail`
+    raises a transient error on the next n GETs, `hang` blocks every GET
+    until set."""
+
+    def __init__(self, root):
+        super().__init__(root)
+        self.threads = []
+        self.fail = 0
+        self.hang = None
+
+    def get(self, key, off=0, limit=-1):
+        self.threads.append(threading.get_ident())
+        if self.hang is not None:
+            self.hang.wait(10.0)
+        if self.fail > 0:
+            self.fail -= 1
+            raise OSError("transient blip")
+        return super().get(key, off, limit)
+
+    def put(self, key, data):
+        self.threads.append(threading.get_ident())
+        super().put(key, data)
+
+    def head(self, key):
+        self.threads.append(threading.get_ident())
+        return super().head(key)
+
+    def delete(self, key):
+        self.threads.append(threading.get_ident())
+        super().delete(key)
+
+
+def local_store(tmp_path, **kw):
+    drv = ThreadFile(str(tmp_path))
+    drv.put("k", b"payload")
+    drv.threads.clear()
+    return drv, resilient(metered(drv), **kw)
+
+
+def inline_count(method):
+    return counter("juicefs_object_inline_requests", "file", method).value
+
+
+OPS = {
+    "GET": lambda rs: rs.get("k"),
+    "PUT": lambda rs: rs.put("k2", b"v"),
+    "HEAD": lambda rs: rs.head("k"),
+    "DELETE": lambda rs: rs.delete("k"),
+}
+
+
+@pytest.mark.parametrize("method", sorted(OPS))
+def test_a_local_stores_attempt_runs_on_the_calling_thread(tmp_path, method):
+    drv, rs = local_store(tmp_path)  # hedging on, as the chunk store has it
+    n0 = inline_count(method)
+    try:
+        assert rs.inline and rs.health()["inline"] is True
+        OPS[method](rs)
+        assert drv.threads == [threading.get_ident()]
+        assert rs._pool._seq == 0  # the pool started no thread
+        assert inline_count(method) == n0 + 1
+    finally:
+        rs.close()
+
+
+def test_an_attempt_bound_keeps_a_local_store_on_the_pool(tmp_path):
+    """`attempt_timeout` asks for abandonment: a local GET that outlives
+    it is abandoned on the pool, as a remote one is."""
+    drv, rs = local_store(tmp_path, policy=RetryPolicy(
+        deadline=5.0, max_attempts=1, attempt_timeout=0.1), hedge=False)
+    drv.hang = threading.Event()
+    a0 = counter("juicefs_object_deadline_abandoned", "GET").value
+    n0 = inline_count("GET")
+    try:
+        assert not rs.inline and rs.health()["inline"] is False
+        t0 = time.perf_counter()
+        with pytest.raises(DeadlineExceeded):
+            rs.get("k")
+        assert time.perf_counter() - t0 < 2.0
+        assert counter("juicefs_object_deadline_abandoned",
+                       "GET").value == a0 + 1
+        assert rs._pool._seq == 1
+        assert drv.threads and drv.threads[0] != threading.get_ident()
+        assert inline_count("GET") == n0
+    finally:
+        drv.hang.set()
+        rs.close()
+
+
+# case -> (transient failures before the answer, what the GET gives,
+# attempts, retries, breaker failures)
+LOCAL_OUTCOMES = {
+    "transient_twice_then_success": (2, b"payload", 3, 2, 2),
+    "not_found_is_permanent": (0, NotFoundError, 1, 0, 0),
+}
+
+
+@pytest.mark.parametrize("case", sorted(LOCAL_OUTCOMES))
+def test_a_local_stores_retries_and_breaker_are_the_pools(tmp_path, case):
+    fails, answer, attempts, retries, failures = LOCAL_OUTCOMES[case]
+    br = CircuitBreaker(backend=f"local-{case}", probe_interval=999.0)
+    drv, rs = local_store(tmp_path, breaker=br, policy=RetryPolicy(
+        max_attempts=8, base=0.001, jitter=0.0))
+    drv.fail = fails
+    r0 = counter("juicefs_object_request_retries", "GET").value
+    t0 = counter("juicefs_object_retries_by_class", "transient").value
+    n0 = inline_count("GET")
+    try:
+        if answer is NotFoundError:
+            with pytest.raises(NotFoundError):
+                rs.get("missing")
+        else:
+            assert rs.get("k") == answer
+        assert drv.threads == [threading.get_ident()] * attempts
+        assert rs._pool._seq == 0
+        assert inline_count("GET") == n0 + attempts
+        assert counter("juicefs_object_request_retries",
+                       "GET").value == r0 + retries
+        assert counter("juicefs_object_retries_by_class",
+                       "transient").value == t0 + retries
+        snap = br.snapshot()
+        assert snap["window_samples"] == failures + 1
+        assert snap["window_failure_rate"] == round(
+            failures / (failures + 1), 3)
+        assert br.state == BreakerState.CLOSED
+    finally:
+        rs.close()
+
+
+def sync_store(url):
+    from juicefs_tpu.cmd.sync import _open_store
+
+    return _open_store(url)
+
+
+# store -> whether the resilience layer runs its attempts on the caller
+WRAPPED = {
+    "file": (lambda d: FileStorage(d), True),
+    "prefixed": (lambda d: with_prefix(FileStorage(d), "vol/"), True),
+    "sharded": (lambda d: sharded([FileStorage(d + "/a"),
+                                   FileStorage(d + "/b")]), True),
+    "sharded_with_a_remote_shard": (
+        lambda d: sharded([FileStorage(d), create_storage("mem://")]), False),
+    "checksummed": (lambda d: new_checksummed(FileStorage(d)), True),
+    "metered": (lambda d: metered(with_prefix(FileStorage(d), "vol/")), True),
+    "faulty": (lambda d: FaultyStore(FileStorage(d)), False),
+    "mem": (lambda d: create_storage("mem://"), False),
+    # the mirror check's endpoint: already resilient(metered(...))
+    "sync_endpoint": (lambda d: sync_store(f"file://{d}/"), True),
+}
+
+
+@pytest.mark.parametrize("name", sorted(WRAPPED))
+def test_the_wrappers_report_their_inner_stores_answer(tmp_path, name):
+    """The decision is the store's own declaration, carried up through
+    each wrapper; FaultyStore keeps the pool path (its hangs are what the
+    drills abandon), and so does every store that is not host-local."""
+    make, local = WRAPPED[name]
+    store = make(str(tmp_path))
+    store.put("k", b"v")
+    rs = resilient(store, hedge=False)
+    try:
+        assert rs.inline is local
+        assert rs.get("k") == b"v"
+        assert (rs._pool._seq == 0) is local
     finally:
         rs.close()

@@ -156,6 +156,27 @@ INDEX_EXPECTED = {
     "juicefs_index_dropped_blocks",
     "juicefs_index_errors",
 }
+OBJECT_PREFIX = "juicefs_object_"
+OBJECT_EXPECTED = {
+    # the object plane: per-backend meters (object/metered.py), shard
+    # routing (object/sharding.py) and the resilience family
+    # (object/resilient.py) — the benchmark's road metrics read these
+    "juicefs_object_request_durations_histogram_seconds",
+    "juicefs_object_request_cpu_seconds",
+    "juicefs_object_request_errors",
+    "juicefs_object_request_data_bytes",
+    "juicefs_object_shard_ops",
+    "juicefs_object_request_retries",
+    "juicefs_object_retries_by_class",
+    "juicefs_object_deadline_abandoned",
+    "juicefs_object_hedged_requests",
+    "juicefs_object_hedge_wins",
+    "juicefs_object_inline_requests",
+    "juicefs_object_breaker_state",
+    "juicefs_object_breaker_trips",
+    "juicefs_object_breaker_resets",
+    "juicefs_object_shed_limit",
+}
 META_WBATCH_PREFIX = "juicefs_meta_wbatch_"
 META_WBATCH_EXPECTED = {
     # checkpoint write plane (ISSUE 13, meta/wbatch.py): the
@@ -266,6 +287,7 @@ def run(files: list[SourceFile]) -> list[Finding]:
         + lint_pinned(PREFETCH_PREFIX, PREFETCH_EXPECTED, "prefetch")
         + lint_pinned(READAHEAD_PREFIX, READAHEAD_EXPECTED, "readahead")
         + lint_pinned(GATEWAY_PREFIX, GATEWAY_EXPECTED, "gateway")
+        + lint_pinned(OBJECT_PREFIX, OBJECT_EXPECTED, "object-plane")
     )
     return [Finding("", 0, "metric-registry", p) for p in problems]
 

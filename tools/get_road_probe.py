@@ -8,8 +8,8 @@ ops, and then reads the metrics that split `object.get_ms` from them with
 the benchmark's own metric files and readers — the same numbers a traced
 line carries, without the tracing's cost in them. It prints the run's own
 result line, then one line `GET_ROAD {...}`: each metric (ms, or % for the
-hedge share; a metric whose series the program lacks is left out) and the
-parts no series times, by subtraction:
+hedge and inline shares; a metric whose series the program lacks is left
+out) and the parts no series times, by subtraction:
 
   `load_block_own_ms`  `object.get_ms` - `chunk.load_fetch_ms`: the
                        singleflight, cache and staged lookups of
@@ -40,7 +40,8 @@ HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 METRICS = ("object.get_ms", "chunk.fetch_queue_ms", "chunk.load_fetch_ms",
            "object.resilient_queue_ms", "object.resilient_wake_ms",
            "object.store_get_ms", "object.store_get_cpu_ms",
-           "object.hedged_share", "entry.parse_ms_per_op")
+           "object.hedged_share", "entry.parse_ms_per_op",
+           "object.inline_get_share")
 
 
 def parts(m: dict) -> dict:

@@ -36,6 +36,8 @@ GATEWAY_PREFIX = _metrics.GATEWAY_PREFIX
 GATEWAY_EXPECTED = _metrics.GATEWAY_EXPECTED
 INDEX_PREFIX = _metrics.INDEX_PREFIX
 INDEX_EXPECTED = _metrics.INDEX_EXPECTED
+OBJECT_PREFIX = _metrics.OBJECT_PREFIX
+OBJECT_EXPECTED = _metrics.OBJECT_EXPECTED
 
 _PKG_ROOT = os.path.join(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "juicefs_tpu"
@@ -83,6 +85,11 @@ def lint_index(registry=None) -> list[str]:
                                 "index", registry)
 
 
+def lint_object(registry=None) -> list[str]:
+    return _metrics.lint_pinned(OBJECT_PREFIX, OBJECT_EXPECTED,
+                                "object-plane", registry)
+
+
 def lint_compress_seam(root: str | None = None) -> list[str]:
     """No-bare-compress check (ISSUE 8), framework-backed."""
     files = load_files(root or _PKG_ROOT)
@@ -114,7 +121,8 @@ def main() -> int:
                 + lint_ingest_seam() + lint_resilience()
                 + lint_qos() + lint_qos_seam()
                 + lint_compress() + lint_compress_seam()
-                + lint_wbatch() + lint_gateway() + lint_index())
+                + lint_wbatch() + lint_gateway() + lint_index()
+                + lint_object())
     if problems:
         for p in problems:
             print(f"lint_metrics: {p}", file=sys.stderr)
