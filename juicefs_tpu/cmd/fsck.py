@@ -13,6 +13,11 @@ One invocation is one trace (metric/trace.py): the root span `cmd.fsck`,
 its stages `open`, `list`, `index_load`, `verify`, `report` below it, and
 below `verify` the fetch stage's and the hash pipeline's own spans. Every
 stage feeds `juicefs_tpu_stage_seconds{layer="cmd",op="fsck"}`.
+
+A block the scrub could not read is reported by why: `corrupt` where the
+object's envelope does not open (object/encrypt.py: the object as stored is
+wrong — restore the block), `unreadable` where the object is gone or the
+store gave up (wait, and scrub again).
 """
 
 from __future__ import annotations
@@ -41,7 +46,7 @@ _BLOCKS = global_registry().counter(
     "juicefs_fsck_blocks",
     "Blocks fsck expected, by what it found: read back and hashed without "
     "disagreeing with the content index, digest mismatch, no object, "
-    "object unreadable",
+    "object unreadable, envelope that does not open (corrupt)",
     ("result",),
 )
 
@@ -176,9 +181,13 @@ def _fsck(args, root, at_exit: contextlib.ExitStack) -> tuple[int, dict | None]:
                     broken.append(k)
                     bitrot += 1
             unreadable = [key for key in readable if key not in index]
+            corrupt = 0
             for key in unreadable:
                 why = stage.failed.get(key)
-                if why is not None or stage.stopped is None:
+                if key in stage.corrupt:
+                    logger.error("block %s corrupt: %s", key, stage.corrupt[key])
+                    corrupt += 1
+                elif why is not None or stage.stopped is None:
                     logger.error("block %s unreadable: %s", key,
                                  why or "not read")
                 broken.append(key)
@@ -193,7 +202,8 @@ def _fsck(args, root, at_exit: contextlib.ExitStack) -> tuple[int, dict | None]:
                               ahead=stage.ahead)
         _BLOCKS.labels("verified").inc(len(index) - bitrot)
         _BLOCKS.labels("mismatch").inc(bitrot)
-        _BLOCKS.labels("unreadable").inc(len(unreadable))
+        _BLOCKS.labels("unreadable").inc(len(unreadable) - corrupt)
+        _BLOCKS.labels("corrupt").inc(corrupt)
         total = time.perf_counter() - t0
 
     with _TR.span("cmd", "fsck", stage="report", hist=_H_REPORT):
@@ -218,6 +228,8 @@ def _fsck(args, root, at_exit: contextlib.ExitStack) -> tuple[int, dict | None]:
                 "indexed": len(recorded),
                 "mismatches": bitrot,
                 "broken": len(broken),
+                # blocks whose envelope does not open: in `broken` too
+                "corrupt": corrupt,
                 "bytes": sum(expected[k] for k in index),
                 # index load and verify, and what lies between
                 "seconds": round(total, 3),

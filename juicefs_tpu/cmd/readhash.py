@@ -29,6 +29,7 @@ from __future__ import annotations
 from typing import Callable, Hashable, Iterable, Iterator, TypeVar
 
 from ..chunk.parallel import FetchStats, fetch_ordered
+from ..object import CorruptObjectError
 from ..object.resilient import BreakerOpenError
 
 T = TypeVar("T", bound=Hashable)
@@ -61,7 +62,10 @@ class ReadHash:
     `window` GETs at once, `ahead` fetched past them (what `hash_stream`
     takes between two stretches of its own work); `fetched` is the fetch
     stage's own account of its time (`FetchStats`); `failed` maps each
-    item whose GET raised to what the error said.
+    item whose GET raised to what the error said, and `corrupt` each item
+    whose object is corrupt (`CorruptObjectError`) to the check that
+    failed. Both keep text alone: an error's traceback holds the frames
+    that fetched the object, and with them its bytes.
 
     An open circuit at the store ends the fetch stage (chunk/parallel.py).
     With `outlive_open` the stream then ends there instead of raising:
@@ -78,6 +82,7 @@ class ReadHash:
         self.ahead = pipe.config.batch_blocks
         self.fetched = FetchStats()
         self.failed: dict[T, str] = {}
+        self.corrupt: dict[T, str] = {}
 
     def digests(self, items: Iterable[T]) -> Iterator[tuple[T, bytes]]:
         """(item, digest) of every item that could be read, in input
@@ -87,6 +92,8 @@ class ReadHash:
                 return self.load(item)
             except Exception as e:
                 self.failed[item] = str(e)
+                if isinstance(e, CorruptObjectError):
+                    self.corrupt[item] = e.reason
                 raise
 
         def blocks():

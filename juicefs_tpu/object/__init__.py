@@ -14,6 +14,7 @@ from __future__ import annotations
 from typing import Callable
 
 from .interface import (
+    CorruptObjectError,
     Obj,
     ObjectStorage,
     NotFoundError,
@@ -112,6 +113,7 @@ __all__ = [
     "ObjectStorage",
     "NotFoundError",
     "PermanentError",
+    "CorruptObjectError",
     "ThrottleError",
     "FileStorage",
     "MemStorage",

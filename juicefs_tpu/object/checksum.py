@@ -32,6 +32,15 @@ def _make_table() -> list[int]:
 _TABLE = _make_table()
 
 
+class ChecksumMismatchError(IOError):
+    """A full-object GET whose body does not match its stored CRC32C;
+    `got` is the length of the body that arrived."""
+
+    def __init__(self, key: str, got: int):
+        super().__init__(f"checksum mismatch for {key}")
+        self.got = got
+
+
 def crc32c_py(data: bytes, crc: int = 0) -> int:
     """Pure-Python CRC32C (Castagnoli) — the spec/fallback implementation,
     byte-identical to the reference's hash (checksum.go crc32.Castagnoli)."""
@@ -74,7 +83,7 @@ class _Checksummed(ObjectStorage):
                 if magic == _MAGIC:
                     body = raw[_HDR.size:]
                     if crc32c(body) != crc:
-                        raise IOError(f"checksum mismatch for {key}")
+                        raise ChecksumMismatchError(key, len(body))
                     return body
             return raw  # legacy/unwrapped object
         # ranged read: shift past header, skip verification (reference behavior)

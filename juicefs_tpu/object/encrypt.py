@@ -8,15 +8,28 @@ Scheme (same shape as the reference):
 
 The RSA key pair is the volume's master key (PEM, optionally password
 protected — reference encrypt.go:66-123 ParseRsaPrivateKeyFromPem).
+
+An envelope that does not open — a length header that does not fit the
+object, a data key that does not unwrap, a body that fails its tag (for the
+CTR body: its CRC32C) — raises `CorruptObjectError` where what arrived is the
+whole object as its store sizes it: the object as stored is wrong, so the
+resilience layer never retries it. Where another number of bytes arrived,
+the transfer was cut: a plain IOError, retried. Each open is the span
+`object.decrypt` (`juicefs_tpu_stage_seconds{layer="object",op="get",
+stage="decrypt"}`), the unwrap alone a stamp (`stage="unwrap"`), its thread
+CPU time `juicefs_object_decrypt_cpu_seconds{algo}`; an envelope that did not
+open counts in `juicefs_object_corrupt{backend,reason}`.
 """
 
 from __future__ import annotations
 
 import os
 import struct
+import time
 from typing import Iterator
 
 try:
+    from cryptography.exceptions import InvalidTag
     from cryptography.hazmat.primitives import hashes, serialization
     from cryptography.hazmat.primitives.asymmetric import padding, rsa
     from cryptography.hazmat.primitives.ciphers.aead import AESGCM
@@ -27,7 +40,35 @@ except ImportError:  # gated: encrypted volumes error lazily, everything
     HAVE_CRYPTOGRAPHY = False
     hashes = serialization = padding = rsa = AESGCM = None
 
-from .interface import Obj, ObjectStorage
+from ..metric import global_registry
+from ..metric.trace import global_tracer, stage_hist
+from .checksum import ChecksumMismatchError
+from .interface import CorruptObjectError, Obj, ObjectStorage
+
+_TR = global_tracer()
+_reg = global_registry()
+_H_DECRYPT = stage_hist("object", "get", "decrypt")
+_H_UNWRAP = stage_hist("object", "get", "unwrap")
+# beside the open's wall time, its thread CPU time: the difference is the
+# wait for the interpreter lock (the cipher's own calls hold it)
+_DECRYPT_CPU = _reg.histogram(
+    "juicefs_object_decrypt_cpu_seconds",
+    "Thread CPU time of opening one object's envelope",
+    ("algo",),
+)
+_CORRUPT = _reg.counter(
+    "juicefs_object_corrupt",
+    "Objects whose envelope did not open, by the check that failed",
+    ("backend", "reason"),
+)
+
+
+class _Unopened(Exception):
+    """An envelope that does not open; `reason` as CorruptObjectError's."""
+
+    def __init__(self, reason: str):
+        super().__init__(reason)
+        self.reason = reason
 
 
 def _require_cryptography() -> None:
@@ -53,6 +94,8 @@ def generate_rsa_key_pem(bits: int = 2048, password: bytes | None = None) -> byt
 
 class RSAEncryptor:
     """Key encryptor: wraps per-object data keys (reference encrypt.go:125-145)."""
+
+    name = "rsa"
 
     def __init__(self, pem: bytes, password: bytes | None = None,
                  key=None):
@@ -80,6 +123,8 @@ class AESGCMDataEncryptor:
     """Per-object AES-256-GCM (reference encrypt.go:147-216 dataEncryptor)."""
 
     NONCE = 12
+    TAIL = 16  # the GCM tag: the body is never shorter
+    body = "aes256gcm"
 
     def __init__(self, key_encryptor: RSAEncryptor):
         self._ke = key_encryptor
@@ -91,24 +136,46 @@ class AESGCMDataEncryptor:
         wrapped = self._ke.encrypt(dk)
         return struct.pack(">I", len(wrapped)) + wrapped + nonce + body
 
-    def decrypt(self, blob: bytes) -> bytes:
-        (klen,) = struct.unpack_from(">I", blob)
+    @property
+    def algo(self) -> str:
+        return f"{self.body}-{self._ke.name}"
+
+    def _unwrap(self, blob: bytes) -> tuple[bytes, bytes, bytes]:
+        """-> (data key, nonce, body) of an envelope; raises _Unopened."""
+        klen = self._ke.wrapped_len
+        if (len(blob) < 4 + klen + self.NONCE + self.TAIL
+                or struct.unpack_from(">I", blob)[0] != klen):
+            raise _Unopened("header")
         wrapped = blob[4 : 4 + klen]
         nonce = blob[4 + klen : 4 + klen + self.NONCE]
         body = blob[4 + klen + self.NONCE :]
-        dk = self._ke.decrypt(wrapped)
-        return AESGCM(dk).decrypt(nonce, body, None)
+        t = time.perf_counter()
+        try:
+            dk = self._ke.decrypt(wrapped)
+        except (ValueError, InvalidTag):
+            raise _Unopened("key") from None
+        _H_UNWRAP.observe(time.perf_counter() - t)
+        return dk, nonce, body
+
+    def decrypt(self, blob: bytes) -> bytes:
+        dk, nonce, body = self._unwrap(blob)
+        try:
+            return AESGCM(dk).decrypt(nonce, body, None)
+        except InvalidTag:
+            raise _Unopened("tag") from None
 
     @property
     def overhead(self) -> int:
         # length header + wrapped key + nonce + GCM tag: fixed per volume key
-        return 4 + self._ke.wrapped_len + self.NONCE + 16
+        return 4 + self._ke.wrapped_len + self.NONCE + self.TAIL
 
 
 class _Encrypted(ObjectStorage):
     def __init__(self, store: ObjectStorage, enc: AESGCMDataEncryptor):
         self._s = store
         self._e = enc
+        self._backend = store.string().split("://", 1)[0] or type(store).__name__
+        self._cpu = _DECRYPT_CPU.labels(enc.algo)
 
     @property
     def host_local(self) -> bool:
@@ -123,10 +190,40 @@ class _Encrypted(ObjectStorage):
     def put(self, key, data):
         self._s.put(key, self._e.encrypt(data))
 
+    def _unopened(self, key: str, reason: str, got: int) -> IOError:
+        """The error of an envelope that did not open. Corrupt where the
+        `got` bytes that arrived are the whole object as stored; a transfer
+        cut otherwise, which a second GET may get whole (transient,
+        as the chunk layer's TornDataError)."""
+        size = self._s.head(key).size
+        if got != size:
+            return IOError(f"object {key}: {got} bytes arrived of its {size}")
+        _CORRUPT.labels(self._backend, reason).inc()
+        return CorruptObjectError(key, reason)
+
     def get(self, key, off=0, limit=-1):
         # ciphertext is not seekable: fetch whole object, slice after decrypt
-        # (reference encrypt.go Get does the same)
-        data = self._e.decrypt(self._s.get(key))
+        # (reference encrypt.go Get does the same). The error is raised
+        # outside the handlers, so it drags no frame that held the blob.
+        failed = None
+        try:
+            blob = self._s.get(key)
+        except ChecksumMismatchError as e:  # the CTR body's integrity check
+            failed = ("tag", e.got)
+        else:
+            with _TR.span("object", "decrypt", hist=_H_DECRYPT) as sp:
+                cpu = time.thread_time()
+                try:
+                    data = self._e.decrypt(blob)
+                except _Unopened as e:
+                    failed = (e.reason, len(blob))
+                finally:
+                    self._cpu.observe(time.thread_time() - cpu)
+                if sp.active and failed is None:
+                    sp.set(bytes=len(data), algo=self._e.algo)
+            del blob
+        if failed is not None:
+            raise self._unopened(key, *failed)
         if limit < 0:
             return data[off:]
         return data[off : off + limit]
@@ -153,6 +250,7 @@ class ECIESEncryptor:
     """
 
     _NONCE = 12
+    name = "ecies"
 
     def __init__(self, pem: bytes, password: bytes | None = None,
                  key=None):
@@ -230,11 +328,15 @@ class AESCTRDataEncryptor(AESGCMDataEncryptor):
     operators needing cryptographic tamper resistance must use the GCM
     default."""
 
+    NONCE = 16  # full CTR counter block
+    TAIL = 0
+    body = "aes256ctr"
+
     def encrypt(self, plaintext: bytes) -> bytes:
         from cryptography.hazmat.primitives.ciphers import Cipher, algorithms, modes
 
         dk = os.urandom(32)
-        nonce = os.urandom(16)  # full CTR counter block
+        nonce = os.urandom(self.NONCE)
         enc = Cipher(algorithms.AES(dk), modes.CTR(nonce)).encryptor()
         body = enc.update(plaintext) + enc.finalize()
         wrapped = self._ke.encrypt(dk)
@@ -243,17 +345,9 @@ class AESCTRDataEncryptor(AESGCMDataEncryptor):
     def decrypt(self, blob: bytes) -> bytes:
         from cryptography.hazmat.primitives.ciphers import Cipher, algorithms, modes
 
-        (klen,) = struct.unpack_from(">I", blob)
-        wrapped = blob[4:4 + klen]
-        nonce = blob[4 + klen:4 + klen + 16]
-        body = blob[4 + klen + 16:]
-        dk = self._ke.decrypt(wrapped)
+        dk, nonce, body = self._unwrap(blob)
         dec = Cipher(algorithms.AES(dk), modes.CTR(nonce)).decryptor()
         return dec.update(body) + dec.finalize()
-
-    @property
-    def overhead(self) -> int:
-        return 4 + self._ke.wrapped_len + 16  # header + key + counter block
 
 
 def _key_encryptor(pem: bytes, password: bytes | None):

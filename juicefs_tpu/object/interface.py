@@ -25,6 +25,20 @@ class PermanentError(IOError):
     a request that is wrong, not unlucky."""
 
 
+class CorruptObjectError(PermanentError):
+    """The object as stored is wrong: its envelope does not open
+    (object/encrypt.py). `reason` says which check failed — `header` (the
+    length header does not fit the object), `key` (the wrapped data key
+    does not unwrap under the volume's key) or `tag` (the body fails its
+    authentication). Reading the same bytes again gives the same answer,
+    so it is permanent: never retried, never charged to the breaker."""
+
+    def __init__(self, key: str, reason: str):
+        super().__init__(f"object {key}: envelope does not open ({reason})")
+        self.key = key
+        self.reason = reason
+
+
 class ThrottleError(IOError):
     """Backend throttling (429 / 503 SlowDown analogs): retryable, but the
     resilience layer backs off longer and sheds concurrency instead of

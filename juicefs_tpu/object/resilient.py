@@ -5,8 +5,9 @@ loop in the chunk store, nothing anywhere else.  This wrapper centralizes
 the contract (reference cached_store.go:394-410, generalized along Dean &
 Barroso "The Tail at Scale"):
 
-  classification   PERMANENT errors (NotFound, auth/4xx analogs) are never
-                   retried; TRANSIENT errors get jittered exponential
+  classification   PERMANENT errors (NotFound, auth/4xx analogs, an object
+                   whose envelope does not open) are never retried;
+                   TRANSIENT errors get jittered exponential
                    backoff; THROTTLE errors (429/503 analogs) back off from
                    a higher floor AND halve the concurrency shed limit.
   deadlines        a `RetryPolicy(deadline, max_attempts, base, cap,

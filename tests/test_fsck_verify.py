@@ -187,7 +187,8 @@ def test_a_removed_object_is_reported_missing(vol, backend, capsys, tmp_path,
     assert gained == {'juicefs_fsck_blocks{result="missing"}': 1.0,
                       'juicefs_fsck_blocks{result="verified"}': len(vol.want) - 1,
                       'juicefs_fsck_blocks{result="mismatch"}': 0.0,
-                      'juicefs_fsck_blocks{result="unreadable"}': 0.0}
+                      'juicefs_fsck_blocks{result="unreadable"}': 0.0,
+                      'juicefs_fsck_blocks{result="corrupt"}': 0.0}
 
 
 def gained_blocks(before):
@@ -219,7 +220,8 @@ def test_an_object_that_fails_to_read_is_reported_with_why(
     assert (stats["verified"], stats["mismatches"], stats["broken"]) == (
         n - 1, 0, 1)
     assert gained_blocks(before) == {
-        "missing": 0, "verified": n - 1, "mismatch": 0, "unreadable": 1}
+        "missing": 0, "verified": n - 1, "mismatch": 0, "unreadable": 1,
+        "corrupt": 0}
 
 
 def test_a_store_whose_circuit_opens_still_gets_its_report(
@@ -250,7 +252,8 @@ def test_a_store_whose_circuit_opens_still_gets_its_report(
     rotten = read[2]
     assert rc == 1 and set(index) == set(read) <= set(keys)
     assert gained_blocks(before) == {
-        "missing": 0, "verified": 4, "mismatch": 1, "unreadable": n - 5}
+        "missing": 0, "verified": 4, "mismatch": 1, "unreadable": n - 5,
+        "corrupt": 0}
     assert (stats["verified"], stats["mismatches"], stats["broken"]) == (
         5, 1, n - 4)
     said = [r.getMessage() for r in caplog.records]

@@ -159,8 +159,9 @@ INDEX_EXPECTED = {
 OBJECT_PREFIX = "juicefs_object_"
 OBJECT_EXPECTED = {
     # the object plane: per-backend meters (object/metered.py), shard
-    # routing (object/sharding.py) and the resilience family
-    # (object/resilient.py) — the benchmark's road metrics read these
+    # routing (object/sharding.py), the resilience family
+    # (object/resilient.py) and the envelope's (object/encrypt.py) — the
+    # benchmark's road metrics read these
     "juicefs_object_request_durations_histogram_seconds",
     "juicefs_object_request_cpu_seconds",
     "juicefs_object_request_errors",
@@ -176,6 +177,8 @@ OBJECT_EXPECTED = {
     "juicefs_object_breaker_trips",
     "juicefs_object_breaker_resets",
     "juicefs_object_shed_limit",
+    "juicefs_object_decrypt_cpu_seconds",
+    "juicefs_object_corrupt",
 }
 META_WBATCH_PREFIX = "juicefs_meta_wbatch_"
 META_WBATCH_EXPECTED = {
