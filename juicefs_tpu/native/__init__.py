@@ -17,6 +17,11 @@ Exports:
     pack_rows(blocks, rows) -> bool  a hash batch's rows in one call
     touch_pages(arr) -> bool       first-touch an array's pages, lock-free
     available() -> bool
+    crypto_available() -> bool     OpenSSL's libcrypto found at run time
+    envelope_key(der) -> EnvelopeKey | None
+                                   an RSA key held natively; its
+                                   .open(blob, out_len) opens an RSA-OAEP +
+                                   AES-256-GCM envelope lock-free
 """
 
 from __future__ import annotations
@@ -27,6 +32,7 @@ import hashlib
 import os
 import subprocess
 import threading
+import weakref
 from typing import Optional, Sequence
 
 from ..utils import get_logger
@@ -39,6 +45,8 @@ _SRC = os.path.join(_DIR, "jfscore.cpp")
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
+_LIBCRYPTO = "libcrypto.so.3"  # OpenSSL 3, as CPython's _hashlib links it
+_crypto: Optional[bool] = None
 
 
 def _so_path() -> str:
@@ -56,7 +64,7 @@ def _build(so: str) -> bool:
     tmp = f"{so}.{os.getpid()}.tmp"
     cmd = [
         "g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-pthread",
-        _SRC, "-o", tmp,
+        _SRC, "-o", tmp, "-ldl",
     ]
     try:
         proc = subprocess.run(cmd, capture_output=True, timeout=120)
@@ -81,59 +89,78 @@ def _build(so: str) -> bool:
 
 
 def _load() -> Optional[ctypes.CDLL]:
+    """The library, built and loaded by the first caller; a caller that
+    comes while that is under way waits for its answer (one that took
+    "none yet" for "none" would fall back for good: `crypto_available`
+    keeps its first answer)."""
     global _lib, _tried
-    if _lib is not None or _tried:
+    if _tried:
         return _lib
     with _lock:
-        if _lib is not None or _tried:
-            return _lib
-        _tried = True
-        try:
-            so = _so_path()
-        except OSError as e:
-            logger.warning("native source unreadable: %s", e)
-            return None
-        if not os.path.exists(so) and not _build(so):
-            return None
-        try:
-            lib = ctypes.CDLL(so)
-            lib.jfs_crc32c.restype = ctypes.c_uint32
-            lib.jfs_crc32c.argtypes = [
-                ctypes.c_char_p, ctypes.c_size_t, ctypes.c_uint32,
-            ]
-            lib.jfs_jth256.restype = None
-            lib.jfs_jth256.argtypes = [
-                ctypes.c_char_p, ctypes.c_size_t, ctypes.c_char_p,
-            ]
-            lib.jfs_jth256_batch.restype = None
-            lib.jfs_jth256_batch.argtypes = [
-                ctypes.POINTER(ctypes.c_char_p),
-                ctypes.POINTER(ctypes.c_size_t),
-                ctypes.c_size_t,
-                ctypes.c_char_p,
-                ctypes.c_int,
-            ]
-            lib.jfs_pack_rows.restype = None
-            lib.jfs_pack_rows.argtypes = [
-                ctypes.POINTER(ctypes.c_char_p),
-                ctypes.POINTER(ctypes.c_size_t),
-                ctypes.c_size_t,
-                ctypes.c_void_p,
-                ctypes.c_size_t,
-            ]
-            lib.jfs_touch_pages.restype = ctypes.c_size_t
-            lib.jfs_touch_pages.argtypes = [
-                ctypes.c_void_p, ctypes.c_size_t, ctypes.c_size_t,
-                ctypes.POINTER(ctypes.c_int),
-            ]
-            if lib.jfs_abi_version() != 1:
-                raise OSError("jfscore ABI mismatch")
-            _lib = lib
-        except (OSError, AttributeError) as e:
-            # AttributeError: stale .so missing a symbol — fall back too.
-            logger.warning("libjfscore load failed: %s", e)
-            _lib = None
-        return _lib
+        if not _tried:
+            _lib = _open()
+            _tried = True
+    return _lib
+
+
+def _open() -> Optional[ctypes.CDLL]:
+    try:
+        so = _so_path()
+    except OSError as e:
+        logger.warning("native source unreadable: %s", e)
+        return None
+    if not os.path.exists(so) and not _build(so):
+        return None
+    try:
+        lib = ctypes.CDLL(so)
+        lib.jfs_crc32c.restype = ctypes.c_uint32
+        lib.jfs_crc32c.argtypes = [
+            ctypes.c_char_p, ctypes.c_size_t, ctypes.c_uint32,
+        ]
+        lib.jfs_jth256.restype = None
+        lib.jfs_jth256.argtypes = [
+            ctypes.c_char_p, ctypes.c_size_t, ctypes.c_char_p,
+        ]
+        lib.jfs_jth256_batch.restype = None
+        lib.jfs_jth256_batch.argtypes = [
+            ctypes.POINTER(ctypes.c_char_p),
+            ctypes.POINTER(ctypes.c_size_t),
+            ctypes.c_size_t,
+            ctypes.c_char_p,
+            ctypes.c_int,
+        ]
+        lib.jfs_pack_rows.restype = None
+        lib.jfs_pack_rows.argtypes = [
+            ctypes.POINTER(ctypes.c_char_p),
+            ctypes.POINTER(ctypes.c_size_t),
+            ctypes.c_size_t,
+            ctypes.c_void_p,
+            ctypes.c_size_t,
+        ]
+        lib.jfs_touch_pages.restype = ctypes.c_size_t
+        lib.jfs_touch_pages.argtypes = [
+            ctypes.c_void_p, ctypes.c_size_t, ctypes.c_size_t,
+            ctypes.POINTER(ctypes.c_int),
+        ]
+        lib.jfs_crypto_available.restype = ctypes.c_int
+        lib.jfs_crypto_available.argtypes = [ctypes.c_char_p]
+        lib.jfs_rsa_key_load.restype = ctypes.c_void_p
+        lib.jfs_rsa_key_load.argtypes = [ctypes.c_char_p, ctypes.c_size_t]
+        lib.jfs_rsa_key_free.restype = None
+        lib.jfs_rsa_key_free.argtypes = [ctypes.c_void_p]
+        lib.jfs_envelope_open.restype = ctypes.c_int
+        lib.jfs_envelope_open.argtypes = [
+            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_size_t,
+            ctypes.c_void_p, ctypes.c_size_t,
+            ctypes.POINTER(ctypes.c_uint64),
+        ]
+        if lib.jfs_abi_version() != 2:
+            raise OSError("jfscore ABI mismatch")
+        return lib
+    except (OSError, AttributeError) as e:
+        # AttributeError: stale .so missing a symbol — fall back too.
+        logger.warning("libjfscore load failed: %s", e)
+        return None
 
 
 def available() -> bool:
@@ -234,3 +261,56 @@ def touch_pages(arr, stop: "ctypes.c_int | None" = None) -> Optional[int]:
         return None
     return lib.jfs_touch_pages(arr.ctypes.data, arr.nbytes, _PAGE,
                                ctypes.byref(stop) if stop is not None else None)
+
+
+def crypto_available() -> bool:
+    """True where libjfscore loaded and found OpenSSL's libcrypto with every
+    entry point the envelope needs (the copy the process already has
+    first). Looked up once a process; never raises."""
+    global _crypto
+    if _crypto is None:
+        lib = _load()
+        _crypto = lib is not None and \
+            lib.jfs_crypto_available(_LIBCRYPTO.encode()) == 1
+    return _crypto
+
+
+# PyBytes_FromStringAndSize(NULL, n): a bytes object of n bytes nobody has
+# seen yet, for the native call to write before it is handed out
+_new_bytes = ctypes.pythonapi.PyBytes_FromStringAndSize
+_new_bytes.restype = ctypes.py_object
+_new_bytes.argtypes = [ctypes.c_void_p, ctypes.c_ssize_t]
+
+
+class EnvelopeKey:
+    """A private RSA key held by libjfscore, freed with this object. `open`
+    opens the reference's envelope — be32 length || RSA-OAEP(SHA-256,
+    MGF1-SHA-256) of a 32-byte data key || 12-byte nonce || AES-256-GCM
+    body || 16-byte tag — in one call outside the interpreter lock."""
+
+    def __init__(self, handle: int):
+        self._handle = handle
+        weakref.finalize(self, _lib.jfs_rsa_key_free, handle)
+
+    def open(self, blob: bytes, out_len: int):
+        """-> (code, plaintext, unwrap seconds), code 0 opened (plaintext
+        the fresh `bytes` of `out_len` the call wrote), 1 the length header,
+        2 the data key, 3 the tag (plaintext None); None where the call
+        refused it (its lengths do not agree): the caller opens it another
+        way."""
+        out = _new_bytes(None, max(out_len, 0))
+        ns = ctypes.c_uint64()
+        rc = _lib.jfs_envelope_open(self._handle, blob, len(blob), out,
+                                    len(out), ctypes.byref(ns))
+        if rc < 0:
+            return None
+        return rc, out if rc == 0 else None, ns.value / 1e9
+
+
+def envelope_key(der: bytes) -> Optional[EnvelopeKey]:
+    """An RSA private key (DER, PKCS#8 or PKCS#1) held natively; None where
+    there is no library or libcrypto, or it does not load the key."""
+    if not crypto_available():
+        return None
+    handle = _lib.jfs_rsa_key_load(der, len(der))
+    return EnvelopeKey(handle) if handle else None

@@ -5,18 +5,26 @@
 // hardware-accelerated hash/crc32). This library is the rebuild's
 // equivalent: hardware CRC32C and the JTH-256 content hash in C++,
 // exposed through a plain C ABI consumed with ctypes (and reusable from
-// any language, like the reference's libjfs C ABI in sdk/java).
+// any language, like the reference's libjfs C ABI in sdk/java). It also
+// opens the reference's object envelope (RSA-OAEP data key, AES-256-GCM
+// body) through the host's OpenSSL, found at run time: no OpenSSL headers
+// or link flags are needed to build it.
 //
 // JTH-256 here MUST stay byte-identical to the normative numpy spec in
 // juicefs_tpu/tpu/jth256.py (BASELINE.md acceptance bar); the test suite
 // cross-checks all implementations. Little-endian hosts assumed (x86-64,
 // aarch64) — the word view and digest serialization are uint32-LE.
 //
-// Build: g++ -O3 -shared -fPIC -std=c++17 -pthread jfscore.cpp -o libjfscore.so
+// Build: g++ -O3 -shared -fPIC -std=c++17 -pthread jfscore.cpp -o libjfscore.so -ldl
+
+#include <dlfcn.h>
+#include <time.h>
 
 #include <atomic>
+#include <climits>
 #include <cstdint>
 #include <cstring>
+#include <mutex>
 #include <thread>
 #include <vector>
 
@@ -34,10 +42,15 @@ void jfs_pack_rows(const uint8_t *const *blocks, const size_t *lens,
                    size_t count, uint8_t *rows, size_t row_bytes);
 size_t jfs_touch_pages(uint8_t *p, size_t n, size_t page,
                        const volatile int *stop);
+int jfs_crypto_available(const char *soname);
+void *jfs_rsa_key_load(const uint8_t *der, size_t n);
+void jfs_rsa_key_free(void *key);
+int jfs_envelope_open(void *key, const uint8_t *blob, size_t n, uint8_t *out,
+                      size_t out_len, uint64_t *unwrap_ns);
 int jfs_abi_version();
 }
 
-int jfs_abi_version() { return 1; }
+int jfs_abi_version() { return 2; }
 
 // ---------------------------------------------------------------- CRC32C --
 
@@ -244,4 +257,214 @@ size_t jfs_touch_pages(uint8_t *p, size_t n, size_t page,
     p[i] = 0;
   }
   return n;
+}
+
+// --------------------------------------------------------------- envelope --
+
+// The reference's envelope (pkg/object/encrypt.go): be32 len || RSA-OAEP
+// (SHA-256, MGF1-SHA-256) of a 32-byte data key || 12-byte nonce ||
+// AES-256-GCM body || 16-byte tag. Opened here in one call that holds no
+// interpreter lock, so GET threads open side by side. OpenSSL 3's libcrypto
+// is found with dlopen (first the copy the process already has) and the
+// few entry points are declared by hand with opaque pointers.
+
+namespace {
+
+struct Crypto {
+  const void *(*aes_256_gcm)();
+  const void *(*sha256)();
+  void *(*cipher_ctx_new)();
+  void (*cipher_ctx_free)(void *);
+  int (*cipher_ctx_ctrl)(void *, int, int, void *);
+  int (*decrypt_init)(void *, const void *, void *, const uint8_t *,
+                      const uint8_t *);
+  int (*decrypt_update)(void *, uint8_t *, int *, const uint8_t *, int);
+  int (*decrypt_final)(void *, uint8_t *, int *);
+  void *(*d2i_auto_private_key)(void **, const uint8_t **, long);
+  void (*pkey_free)(void *);
+  int (*pkey_get_size)(const void *);
+  void *(*pkey_ctx_new)(void *, void *);
+  void (*pkey_ctx_free)(void *);
+  int (*pkey_decrypt_init)(void *);
+  int (*pkey_decrypt)(void *, uint8_t *, size_t *, const uint8_t *, size_t);
+  int (*set_rsa_padding)(void *, int);
+  int (*set_rsa_oaep_md)(void *, const void *);
+  int (*set_rsa_mgf1_md)(void *, const void *);
+  void (*err_clear)();
+};
+
+const int kRsaOaepPadding = 4;     // RSA_PKCS1_OAEP_PADDING
+const int kCtrlGcmSetTag = 0x11;   // EVP_CTRL_GCM_SET_TAG
+const size_t kNonce = 12, kTag = 16, kDataKey = 32;
+const size_t kMaxModulus = 2048;   // bytes: RSA-16384; a larger key stays out
+
+std::mutex crypto_mu;
+std::atomic<const Crypto *> crypto{nullptr};
+
+template <typename F>
+bool resolve(void *lib, const char *name, F &fn) {
+  fn = reinterpret_cast<F>(dlsym(lib, name));
+  return fn != nullptr;
+}
+
+bool resolve_all(void *lib, Crypto &c) {
+  return resolve(lib, "EVP_aes_256_gcm", c.aes_256_gcm) &&
+         resolve(lib, "EVP_sha256", c.sha256) &&
+         resolve(lib, "EVP_CIPHER_CTX_new", c.cipher_ctx_new) &&
+         resolve(lib, "EVP_CIPHER_CTX_free", c.cipher_ctx_free) &&
+         resolve(lib, "EVP_CIPHER_CTX_ctrl", c.cipher_ctx_ctrl) &&
+         resolve(lib, "EVP_DecryptInit_ex", c.decrypt_init) &&
+         resolve(lib, "EVP_DecryptUpdate", c.decrypt_update) &&
+         resolve(lib, "EVP_DecryptFinal_ex", c.decrypt_final) &&
+         resolve(lib, "d2i_AutoPrivateKey", c.d2i_auto_private_key) &&
+         resolve(lib, "EVP_PKEY_free", c.pkey_free) &&
+         resolve(lib, "EVP_PKEY_get_size", c.pkey_get_size) &&
+         resolve(lib, "EVP_PKEY_CTX_new", c.pkey_ctx_new) &&
+         resolve(lib, "EVP_PKEY_CTX_free", c.pkey_ctx_free) &&
+         resolve(lib, "EVP_PKEY_decrypt_init", c.pkey_decrypt_init) &&
+         resolve(lib, "EVP_PKEY_decrypt", c.pkey_decrypt) &&
+         resolve(lib, "EVP_PKEY_CTX_set_rsa_padding", c.set_rsa_padding) &&
+         resolve(lib, "EVP_PKEY_CTX_set_rsa_oaep_md", c.set_rsa_oaep_md) &&
+         resolve(lib, "EVP_PKEY_CTX_set_rsa_mgf1_md", c.set_rsa_mgf1_md) &&
+         resolve(lib, "ERR_clear_error", c.err_clear);
+}
+
+struct RsaKey {
+  void *pkey;
+  size_t size;  // the modulus in bytes: the wrapped key's length
+};
+
+// Overwrites what the compiler may not prove dead.
+void wipe(uint8_t *p, size_t n) {
+  volatile uint8_t *v = p;
+  while (n--) *v++ = 0;
+}
+
+uint64_t now_ns() {
+  timespec ts;
+  clock_gettime(CLOCK_MONOTONIC, &ts);
+  return (uint64_t)ts.tv_sec * 1000000000u + (uint64_t)ts.tv_nsec;
+}
+
+// The data key of an envelope into dk; false where it does not unwrap.
+// One EVP_PKEY_CTX a call: the EVP_PKEY itself is only read, which OpenSSL
+// 3 allows from many threads at once.
+bool unwrap(const Crypto &c, const RsaKey &k, const uint8_t *wrapped,
+            uint8_t *dk, size_t dk_cap, size_t *dk_len) {
+  void *ctx = c.pkey_ctx_new(k.pkey, nullptr);
+  if (!ctx) return false;
+  const void *md = c.sha256();
+  bool ok = c.pkey_decrypt_init(ctx) > 0 &&
+            c.set_rsa_padding(ctx, kRsaOaepPadding) > 0 &&
+            c.set_rsa_oaep_md(ctx, md) > 0 && c.set_rsa_mgf1_md(ctx, md) > 0;
+  *dk_len = dk_cap;
+  ok = ok && c.pkey_decrypt(ctx, dk, dk_len, wrapped, k.size) > 0;
+  c.pkey_ctx_free(ctx);
+  return ok;
+}
+
+// The body opened into out (len bytes) and the tag checked by
+// EVP_DecryptFinal_ex; false where anything fails.
+bool open_body(const Crypto &c, const uint8_t *dk, const uint8_t *nonce,
+               const uint8_t *body, size_t len, const uint8_t *tag,
+               uint8_t *out) {
+  void *ctx = c.cipher_ctx_new();
+  if (!ctx) return false;
+  bool ok = c.decrypt_init(ctx, c.aes_256_gcm(), nullptr, dk, nonce) > 0;
+  const size_t step = (size_t)1 << 30;  // EVP lengths are ints
+  for (size_t off = 0; ok && off < len; off += step) {
+    int n = (int)(len - off < step ? len - off : step), got = 0;
+    ok = c.decrypt_update(ctx, out + off, &got, body + off, n) > 0 && got == n;
+  }
+  int tail = 0;
+  ok = ok &&
+       c.cipher_ctx_ctrl(ctx, kCtrlGcmSetTag, (int)kTag,
+                         const_cast<uint8_t *>(tag)) > 0 &&
+       c.decrypt_final(ctx, out + len, &tail) > 0 && tail == 0;
+  c.cipher_ctx_free(ctx);  // cleanses the key schedule
+  return ok;
+}
+
+}  // namespace
+
+// 1 where libcrypto (soname: e.g. "libcrypto.so.3") has every entry point
+// the envelope needs; the first such library found serves every later
+// open. 0, and nothing changed, otherwise.
+int jfs_crypto_available(const char *soname) {
+  void *lib = dlopen(soname, RTLD_NOW | RTLD_NOLOAD);
+  if (!lib) lib = dlopen(soname, RTLD_NOW);
+  if (!lib) return 0;
+  Crypto c;
+  if (!resolve_all(lib, c)) {
+    dlclose(lib);
+    return 0;
+  }
+  std::lock_guard<std::mutex> hold(crypto_mu);
+  if (!crypto.load(std::memory_order_acquire))
+    crypto.store(new Crypto(c), std::memory_order_release);  // never freed
+  else
+    dlclose(lib);  // one reference is enough
+  return 1;
+}
+
+// A private RSA key from its DER (PKCS#8 or PKCS#1) -> handle, or null.
+void *jfs_rsa_key_load(const uint8_t *der, size_t n) {
+  const Crypto *c = crypto.load(std::memory_order_acquire);
+  if (!c || n > LONG_MAX) return nullptr;
+  const uint8_t *p = der;
+  void *pkey = c->d2i_auto_private_key(nullptr, &p, (long)n);
+  if (!pkey) {
+    c->err_clear();
+    return nullptr;
+  }
+  int size = c->pkey_get_size(pkey);
+  if (size <= 0 || (size_t)size > kMaxModulus) {
+    c->pkey_free(pkey);
+    return nullptr;
+  }
+  return new RsaKey{pkey, (size_t)size};
+}
+
+void jfs_rsa_key_free(void *key) {
+  const Crypto *c = crypto.load(std::memory_order_acquire);
+  RsaKey *k = static_cast<RsaKey *>(key);
+  if (!k) return;
+  if (c) c->pkey_free(k->pkey);
+  delete k;
+}
+
+// Opens blob[0, n) (untrusted: every length is checked before it is read)
+// into out[0, out_len), out_len being n less the envelope's overhead.
+// 0 opened; 1 `header` (the length does not fit the key or the object),
+// 2 `key` (the data key does not unwrap to 32 bytes), 3 `tag` (the body
+// fails its tag); -1 the call itself is wrong (no libcrypto, out_len).
+// unwrap_ns gets the RSA unwrap's time alone.
+int jfs_envelope_open(void *key, const uint8_t *blob, size_t n, uint8_t *out,
+                      size_t out_len, uint64_t *unwrap_ns) {
+  const Crypto *c = crypto.load(std::memory_order_acquire);
+  const RsaKey *k = static_cast<const RsaKey *>(key);
+  *unwrap_ns = 0;
+  if (!c || !k) return -1;
+  const size_t head = 4 + k->size + kNonce;
+  if (n < head + kTag) return 1;
+  const uint32_t klen = (uint32_t)blob[0] << 24 | (uint32_t)blob[1] << 16 |
+                        (uint32_t)blob[2] << 8 | (uint32_t)blob[3];
+  if (klen != k->size) return 1;
+  if (out_len != n - head - kTag) return -1;
+  uint8_t dk[kMaxModulus];  // an unwrap yields less than the modulus
+  size_t dk_len = 0;
+  const uint64_t t = now_ns();
+  bool ok = unwrap(*c, *k, blob + 4, dk, sizeof dk, &dk_len) &&
+            dk_len == kDataKey;
+  *unwrap_ns = now_ns() - t;
+  int rc = 2;
+  if (ok) {
+    rc = open_body(*c, dk, blob + 4 + k->size, blob + head, out_len,
+                   blob + n - kTag, out)
+             ? 0
+             : 3;
+  }
+  wipe(dk, sizeof dk);
+  if (rc) c->err_clear();
+  return rc;
 }

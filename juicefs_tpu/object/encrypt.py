@@ -19,6 +19,12 @@ the transfer was cut: a plain IOError, retried. Each open is the span
 stage="decrypt"}`), the unwrap alone a stamp (`stage="unwrap"`), its thread
 CPU time `juicefs_object_decrypt_cpu_seconds{algo}`; an envelope that did not
 open counts in `juicefs_object_corrupt{backend,reason}`.
+
+Where libjfscore found OpenSSL's libcrypto, a GCM body under an RSA key opens
+in one native call outside the interpreter lock (`native.EnvelopeKey`), so
+GET threads open side by side; the `cryptography` package opens the rest (the
+CTR body, the ECIES key, a host without the library) and writes every
+envelope. Each open counts in `juicefs_object_decrypt_opens{algo,how}`.
 """
 
 from __future__ import annotations
@@ -40,6 +46,7 @@ except ImportError:  # gated: encrypted volumes error lazily, everything
     HAVE_CRYPTOGRAPHY = False
     hashes = serialization = padding = rsa = AESGCM = None
 
+from .. import native
 from ..metric import global_registry
 from ..metric.trace import global_tracer, stage_hist
 from .checksum import ChecksumMismatchError
@@ -56,6 +63,16 @@ _DECRYPT_CPU = _reg.histogram(
     "Thread CPU time of opening one object's envelope",
     ("algo",),
 )
+_OPENS = _reg.counter(
+    "juicefs_object_decrypt_opens",
+    "Envelopes opened, by algorithm and by how: native (libjfscore, outside "
+    "the interpreter lock) or python (the cryptography package)",
+    ("algo", "how"),
+)
+for _how in ("native", "python"):  # read 0, not missing, before any open
+    _OPENS.labels("aes256gcm-rsa", _how)
+# jfs_envelope_open's codes for an envelope that did not open
+_NATIVE_REASONS = {1: "header", 2: "key", 3: "tag"}
 _CORRUPT = _reg.counter(
     "juicefs_object_corrupt",
     "Objects whose envelope did not open, by the check that failed",
@@ -102,6 +119,11 @@ class RSAEncryptor:
         _require_cryptography()
         self._key = key if key is not None else \
             serialization.load_pem_private_key(pem, password)
+        # the key held by libjfscore too, for the lock-free open; None
+        # where the library or libcrypto is missing
+        self.native = native.envelope_key(self._key.private_bytes(
+            serialization.Encoding.DER, serialization.PrivateFormat.PKCS8,
+            serialization.NoEncryption())) if native.crypto_available() else None
         self._pad = padding.OAEP(
             mgf=padding.MGF1(algorithm=hashes.SHA256()),
             algorithm=hashes.SHA256(),
@@ -128,6 +150,10 @@ class AESGCMDataEncryptor:
 
     def __init__(self, key_encryptor: RSAEncryptor):
         self._ke = key_encryptor
+        self._native = (getattr(key_encryptor, "native", None)
+                        if self.body == "aes256gcm" else None)
+        self._opens = {how: _OPENS.labels(self.algo, how)
+                       for how in ("native", "python")}
 
     def encrypt(self, plaintext: bytes) -> bytes:
         dk = os.urandom(32)
@@ -148,16 +174,29 @@ class AESGCMDataEncryptor:
             raise _Unopened("header")
         wrapped = blob[4 : 4 + klen]
         nonce = blob[4 + klen : 4 + klen + self.NONCE]
-        body = blob[4 + klen + self.NONCE :]
+        body = memoryview(blob)[4 + klen + self.NONCE :]  # no copy
         t = time.perf_counter()
         try:
             dk = self._ke.decrypt(wrapped)
         except (ValueError, InvalidTag):
             raise _Unopened("key") from None
+        if len(dk) != 32:
+            raise _Unopened("key")
         _H_UNWRAP.observe(time.perf_counter() - t)
         return dk, nonce, body
 
     def decrypt(self, blob: bytes) -> bytes:
+        if self._native is not None:
+            opened = self._native.open(blob, len(blob) - self.overhead)
+            if opened is not None:
+                self._opens["native"].inc()
+                code, data, unwrap_s = opened
+                if code in (0, 3):  # the key unwrapped
+                    _H_UNWRAP.observe(unwrap_s)
+                if code:
+                    raise _Unopened(_NATIVE_REASONS[code])
+                return data
+        self._opens["python"].inc()
         dk, nonce, body = self._unwrap(blob)
         try:
             return AESGCM(dk).decrypt(nonce, body, None)
@@ -345,6 +384,7 @@ class AESCTRDataEncryptor(AESGCMDataEncryptor):
     def decrypt(self, blob: bytes) -> bytes:
         from cryptography.hazmat.primitives.ciphers import Cipher, algorithms, modes
 
+        self._opens["python"].inc()
         dk, nonce, body = self._unwrap(blob)
         dec = Cipher(algorithms.AES(dk), modes.CTR(nonce)).decryptor()
         return dec.update(body) + dec.finalize()

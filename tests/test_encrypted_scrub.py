@@ -6,7 +6,9 @@ plaintext and `gc --dedup`'s over the same plan unencrypted. An envelope that
 does not open (`object/encrypt.py`) is a `CorruptObjectError`: one GET
 attempt, no retry, nothing charged to the breaker, and the scrub reports that
 block as `corrupt` and no other. The decrypt's span and counters read where
-an envelope was opened and nowhere else."""
+an envelope was opened and nowhere else. The GCM body under an RSA key opens
+in libjfscore where the host has it; each of its cases runs once more with
+that open made unavailable, on the `cryptography` package's."""
 
 import gc
 import json
@@ -18,6 +20,7 @@ import pytest
 
 from benchmark.lib import jth256_spec, volume, volume_encrypted
 from benchmark.lib.plan import block_bytes, make_plan
+from juicefs_tpu import native
 from juicefs_tpu.cmd import main
 from juicefs_tpu.metric import global_registry
 from juicefs_tpu.metric.trace import global_tracer
@@ -30,6 +33,10 @@ BLOCK = 64 << 10
 DEPLOYMENT = {"storage": "file", "block_bytes": BLOCK, "compression": "none"}
 # (format's --encrypt-algo, the key's kind)
 CIPHERS = [("aes256gcm-rsa", "rsa"), ("aes256ctr-rsa", "rsa"), ("aes256gcm-rsa", "ec")]
+# and the GCM body under an RSA key opened by `cryptography` (a third item
+# "python": libjfscore's open made unavailable)
+OPENS = CIPHERS + [("aes256gcm-rsa", "rsa", "python")]
+HAVE_OPEN = native.crypto_available()
 KEYS = {"rsa": lambda: generate_rsa_key_pem(2048), "ec": generate_ec_key_pem}
 DECRYPT_SERIES = (
     'juicefs_tpu_stage_seconds_count{layer="object",op="get",stage="decrypt"}',
@@ -58,6 +65,23 @@ def decrypts(before):
                      if k.startswith(s)) for s in DECRYPT_SERIES)
 
 
+def how_it_opens(monkeypatch, cipher):
+    """-> how `cipher`'s envelopes open from here on: natively for a GCM
+    body under an RSA key where the host can, unless the cipher says
+    "python" (then the native open is made unavailable); by `cryptography`
+    otherwise."""
+    if cipher[2:] == ("python",):
+        monkeypatch.setattr(native, "crypto_available", lambda: False)
+        return "python"
+    return "native" if cipher[:2] == ("aes256gcm-rsa", "rsa") and HAVE_OPEN else "python"
+
+
+def opened_how(before):
+    """The `how` of every open since `before`."""
+    return {k.split('how="')[1].split('"')[0]
+            for k in gained(before, "juicefs_object_decrypt_opens")}
+
+
 def the_plan():
     """13 blocks of up to 64 KiB: two objects of four, duplicates from a
     pool of two, and the ragged handful."""
@@ -67,8 +91,8 @@ def the_plan():
 
 
 class Volume:
-    def __init__(self, workdir, meta_url, plan):
-        self.workdir, self.meta_url = workdir, meta_url
+    def __init__(self, workdir, meta_url, plan, cipher=()):
+        self.workdir, self.meta_url, self.cipher = workdir, meta_url, cipher
         _, self.block_of = volume.wait(_Done(), workdir, plan)
         ref = {b.content: jth256_spec.jth256(block_bytes(SEED, b)).hex()
                for b in self.block_of.values()}
@@ -88,17 +112,17 @@ class _Done:
         return 0
 
 
-@pytest.fixture(scope="module", params=CIPHERS, ids=lambda c: "-".join(c))
+@pytest.fixture(scope="module", params=OPENS, ids="-".join)
 def encrypted(request, tmp_path_factory):
-    algo, kind = request.param
-    workdir = str(tmp_path_factory.mktemp("enc-" + algo + "-" + kind))
+    algo, kind = request.param[:2]
+    workdir = str(tmp_path_factory.mktemp("enc-" + "-".join(request.param)))
     key_file = os.path.join(workdir, "key.pem")
     with open(key_file, "wb") as f:
         f.write(KEYS[kind]())
     meta_url = f"sqlite3://{workdir}/meta.db"
     volume_encrypted.build(workdir, the_plan(), dict(DEPLOYMENT, encryption=algo),
                            meta_url, key_file)
-    return Volume(workdir, meta_url, the_plan())
+    return Volume(workdir, meta_url, the_plan(), request.param)
 
 
 @pytest.fixture(scope="module")
@@ -128,7 +152,7 @@ def dedup_index(vol, tmp_path, name):
 
 
 def test_the_scrub_hashes_the_plaintext_and_opens_every_envelope(
-        encrypted, plain, capsys, tmp_path):
+        encrypted, plain, capsys, tmp_path, monkeypatch):
     stored = [open(encrypted.path(k), "rb").read() for k in sorted(encrypted.want)]
     planned = [block_bytes(SEED, encrypted.block_of[k]) for k in sorted(encrypted.want)]
     assert all(raw != want and (len(want) < 4096 or want[:4096] not in raw)
@@ -139,9 +163,11 @@ def test_the_scrub_hashes_the_plaintext_and_opens_every_envelope(
     assert encrypted.by_content(enc) == plain.by_content(
         dedup_index(plain, tmp_path, "plain.json"))
 
+    how = how_it_opens(monkeypatch, encrypted.cipher)
     before = series("juicefs_")
     rc, stats, index = scrub(encrypted, capsys, tmp_path)
     n = len(encrypted.want)
+    assert opened_how(before) == {how}
     assert rc == 0 and index == encrypted.want
     assert (stats["verified"], stats["mismatches"], stats["broken"],
             stats["corrupt"]) == (n, 0, 0, 0)
@@ -160,8 +186,9 @@ def test_an_unencrypted_scrub_opens_no_envelope(plain, capsys, tmp_path):
 
 
 def test_the_tampered_block_alone_is_reported_corrupt(
-        encrypted, capsys, tmp_path, caplog):
+        encrypted, capsys, tmp_path, caplog, monkeypatch):
     dedup_index(encrypted, tmp_path, "enc.json")
+    how = how_it_opens(monkeypatch, encrypted.cipher)
     key = sorted(k for k, b in encrypted.block_of.items() if b.size > 1000)[3]
     path = encrypted.path(key)
     with open(path, "rb") as f:
@@ -178,6 +205,7 @@ def test_the_tampered_block_alone_is_reported_corrupt(
         with open(path, "wb") as f:
             f.write(sound)
     n = len(encrypted.want)
+    assert opened_how(before) == {how}
     assert rc == 1
     assert (stats["verified"], stats["mismatches"], stats["broken"],
             stats["corrupt"]) == (n - 1, 0, 1, 1)
@@ -215,12 +243,15 @@ def _region_at(algo, kind, size, region):
 
 
 @pytest.mark.parametrize("region", ["header", "key", "nonce", "body", "tag", "cut"])
-@pytest.mark.parametrize("algo,kind", CIPHERS, ids=lambda c: c)
-def test_an_envelope_that_does_not_open_costs_one_attempt(tmp_path, algo, kind, region):
+@pytest.mark.parametrize("cipher", OPENS, ids="-".join)
+def test_an_envelope_that_does_not_open_costs_one_attempt(tmp_path, cipher, region,
+                                                          monkeypatch):
     """Each part of the envelope altered, or the object as stored cut short:
     one GET attempt, a CorruptObjectError naming the key and the check that
     failed, no retry and no failure for the breaker. A CTR body has no tag
     of its own: its CRC32C fails first, whatever was altered."""
+    algo, kind = cipher[:2]
+    how = how_it_opens(monkeypatch, cipher)
     inner = _Counted(str(tmp_path / "store"))
     store = resilient(new_encrypted(inner, KEYS[kind](), algo=algo.split("-")[0]))
     data = os.urandom(50_000)
@@ -236,9 +267,10 @@ def test_an_envelope_that_does_not_open_costs_one_attempt(tmp_path, algo, kind, 
     with open(path, "wb") as f:
         f.write(raw)
     inner.gets = 0
-    before = series("juicefs_object_request_retries")
+    before = series("juicefs_object_")
     with pytest.raises(CorruptObjectError) as raised:
         store.get("k")
+    assert opened_how(before) == (set() if "ctr" in algo else {how})
     want = "tag" if "ctr" in algo else {"header": "header", "cut": "header",
                                         "key": "key"}.get(region, "tag")
     assert (raised.value.key, raised.value.reason) == ("k", want)
@@ -261,11 +293,13 @@ class _ShortOnce(_Counted):
 
 
 @pytest.mark.parametrize("cut", ["in_header", "in_body"])
-@pytest.mark.parametrize("algo,kind", CIPHERS, ids=lambda c: c)
-def test_a_transfer_cut_short_is_retried_not_corrupt(tmp_path, algo, kind, cut):
+@pytest.mark.parametrize("cipher", OPENS, ids="-".join)
+def test_a_transfer_cut_short_is_retried_not_corrupt(tmp_path, cipher, cut, monkeypatch):
     """A sound object whose first GET arrives cut short fails to open, but
     fewer bytes arrived than the store holds: a transient fault, retried
     once, and the second GET opens; nothing counts as corrupt."""
+    algo, kind = cipher[:2]
+    how = how_it_opens(monkeypatch, cipher)
     keep = {"in_header": lambda n: 40, "in_body": lambda n: n - 100}[cut]
     inner = _ShortOnce(str(tmp_path / "store"), keep)
     store = resilient(new_encrypted(inner, KEYS[kind](), algo=algo.split("-")[0]))
@@ -276,6 +310,7 @@ def test_a_transfer_cut_short_is_retried_not_corrupt(tmp_path, algo, kind, cut):
     assert inner.gets == 2
     assert sum(gained(before, "juicefs_object_request_retries").values()) == 1
     assert gained(before, "juicefs_object_corrupt") == {}
+    assert opened_how(before) == {how}
     store.close()
 
 
@@ -286,11 +321,20 @@ class _Kept(MemStorage):
         return self._data[key][0]
 
 
-def test_a_scrub_keeps_no_bytes_of_a_corrupt_object(tmp_path):
+def test_a_scrub_keeps_no_bytes_of_a_corrupt_object(monkeypatch):
     """After the stage is drained, what it says of a corrupt object is text:
     no error, no traceback, nothing that holds the fetched bytes."""
+    _keeps_no_bytes_of_a_corrupt_object(monkeypatch, CIPHERS[0])
+
+
+def test_a_scrub_keeps_no_bytes_of_a_corrupt_object_opened_by_cryptography(monkeypatch):
+    _keeps_no_bytes_of_a_corrupt_object(monkeypatch, OPENS[-1])
+
+
+def _keeps_no_bytes_of_a_corrupt_object(monkeypatch, cipher):
     from juicefs_tpu.cmd.readhash import ReadHash, scan_pipeline
 
+    how = how_it_opens(monkeypatch, cipher)
     inner = _Kept()
     store = new_encrypted(inner, KEYS["rsa"]())
     for key in ("a", "b", "c"):
@@ -301,6 +345,7 @@ def test_a_scrub_keeps_no_bytes_of_a_corrupt_object(tmp_path):
     blob = bytes(rotten)
     inner._data["b"] = (blob, mtime)
     held = sys.getrefcount(blob)
+    before = series("juicefs_object_decrypt_opens")
     with ThreadPoolExecutor(2) as pool:
         stage = ReadHash(store.get, pool, scan_pipeline("xla", 8192), threads=2)
         got = dict(stage.digests(["a", "b", "c"]))
@@ -309,13 +354,25 @@ def test_a_scrub_keeps_no_bytes_of_a_corrupt_object(tmp_path):
     assert stage.corrupt == {"b": "tag"}
     assert all(type(v) is str for v in stage.failed.values())
     assert sys.getrefcount(blob) == held
+    assert opened_how(before) == {how}
 
 
-def test_the_open_is_a_span_with_its_bytes_and_algorithm(tmp_path):
+def test_the_open_is_a_span_with_its_bytes_and_algorithm(tmp_path, monkeypatch):
+    _the_open_is_a_span(tmp_path, monkeypatch, CIPHERS[0])
+
+
+def test_the_open_is_a_span_with_its_bytes_and_algorithm_by_cryptography(
+        tmp_path, monkeypatch):
+    _the_open_is_a_span(tmp_path, monkeypatch, OPENS[-1])
+
+
+def _the_open_is_a_span(tmp_path, monkeypatch, cipher):
+    how = how_it_opens(monkeypatch, cipher)
     store = new_encrypted(FileStorage(str(tmp_path / "store")), KEYS["rsa"]())
     store.put("k", b"x" * 5000)
     tr, reader = global_tracer(), ("test", "decrypt")
     tr.open_reader(reader)
+    before = series("juicefs_object_decrypt_opens")
     try:
         assert store.get("k") == b"x" * 5000
         events = [json.loads(line) for line in
@@ -324,3 +381,4 @@ def test_the_open_is_a_span_with_its_bytes_and_algorithm(tmp_path):
         tr.close_reader(reader)
     ev, = [e for e in events if (e["layer"], e["op"]) == ("object", "decrypt")]
     assert (ev["bytes"], ev["algo"]) == (5000, "aes256gcm-rsa")
+    assert opened_how(before) == {how}

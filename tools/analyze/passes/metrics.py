@@ -178,6 +178,7 @@ OBJECT_EXPECTED = {
     "juicefs_object_breaker_resets",
     "juicefs_object_shed_limit",
     "juicefs_object_decrypt_cpu_seconds",
+    "juicefs_object_decrypt_opens",
     "juicefs_object_corrupt",
 }
 META_WBATCH_PREFIX = "juicefs_meta_wbatch_"
